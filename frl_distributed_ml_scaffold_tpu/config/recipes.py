@@ -144,7 +144,7 @@ def gpt2_medium_adafactor() -> ExperimentConfig:
     """Flagship LM on Adafactor: the measured-throughput variant of
     ``gpt2_medium_zero1``.
 
-    Round-4 on-chip sweep (evidence_r4/perf_sweep2.log, TPU v5e, mb4
+    Round-4 on-chip sweep (2026-07-30, TPU v5e, mb4
     remat=none): adafactor 31.7 vs adamw 30.3 samples/sec/chip (+4.6%),
     lion 31.6; and the factored second moment drops optimizer state from
     8 to ~4 bytes/param — on a 345M-param model that frees ~1.4 GB of
@@ -260,7 +260,8 @@ def gpt2_medium_fsdp_tp_overlap() -> ExperimentConfig:
     in tests/test_schedule.py (numerics vs the all-GSPMD fsdp x model
     path, program identity vs the explicit declaration string); the
     on-chip A/B rides ``tools/perf_sweep.py gpt2_fsdp_tp_overlap``
-    (BACKLOG relay window, next to R6-1/R7-1)."""
+    (ROADMAP A4); its first run on real links is
+    ``chip_smoke.py --chips 4``'s second arm."""
     base = gpt2_medium_zero1()
     return base.replace(
         name="gpt2_medium_fsdp_tp_overlap",

@@ -1,7 +1,10 @@
 """ctypes bindings for the native data-loader core (SURVEY C16).
 
-Loads ``native/libfrl_data.so`` (building it from ``native/frl_data.cpp``
-with g++ on first use, cached by source mtime). Every entry point has a
+Builds ``native/frl_data.cpp`` with g++ on first use into a binary whose
+name carries the host's microarchitecture and a hash of the SOURCE'S
+CONTENT, so a copy of the tree (which keeps no mtimes) and an edited
+source each find — or build — exactly their own library; the binary is
+never committed. Every entry point has a
 pure-numpy fallback with identical semantics, so environments without a
 toolchain degrade gracefully — ``native_available()`` reports which path is
 live, and the parity tests assert C++ == numpy bit-for-bit where the
@@ -15,6 +18,7 @@ import hashlib
 import os
 import platform
 import subprocess
+import tempfile
 import threading
 
 import numpy as np
@@ -25,15 +29,23 @@ _NATIVE_DIR = os.path.join(os.path.dirname(os.path.dirname(__file__)), "native")
 _SRC = os.path.join(_NATIVE_DIR, "frl_data.cpp")
 
 
-def _host_arch_tag() -> str:
-    """Host/microarch tag for the cached .so filename.
+def _cache_key() -> str | None:
+    """Tag for the cached .so filename: machine arch + a hash of the CPU
+    feature flags + a hash of the source text; None without the source.
 
     The library is built with ``-march=native`` and cached next to the
     source; on a shared filesystem a multi-host launch could otherwise load
-    a lib built for a different CPU and die with SIGILL. Tag = machine arch
-    + a hash of the CPU feature flags, so each distinct microarchitecture
-    builds (and loads) its own copy.
+    a lib built for a different CPU and die with SIGILL, so each distinct
+    microarchitecture builds (and loads) its own copy. The source hash
+    makes a stale binary impossible to load: an edited source has another
+    name, and an mtime (which a copy of the tree does not preserve) is
+    never consulted.
     """
+    try:
+        with open(_SRC, "rb") as fh:
+            src = hashlib.sha256(fh.read()).hexdigest()[:12]
+    except OSError:
+        return None
     flags = ""
     try:
         with open("/proc/cpuinfo") as fh:
@@ -44,10 +56,8 @@ def _host_arch_tag() -> str:
     except OSError:
         pass
     h = hashlib.sha256(flags.encode()).hexdigest()[:8]
-    return f"{platform.machine()}-{h}"
+    return f"{platform.machine()}-{h}.{src}"
 
-
-_LIB = os.path.join(_NATIVE_DIR, f"libfrl_data.{_host_arch_tag()}.so")
 
 _lock = threading.Lock()
 _lib: ctypes.CDLL | None = None
@@ -57,28 +67,31 @@ _tried = False
 _done = threading.Event()
 
 
-def _build() -> bool:
-    # Compile to a process-unique temp path and rename into place: rename is
+def _build(lib_path: str) -> bool:
+    # Compile to a unique temp path and rename into place: rename is
     # atomic on POSIX, so concurrent first-use builds (multi-process launch,
-    # shared filesystem) can never load a torn .so.
-    tmp = f"{_LIB}.{os.getpid()}.tmp"
+    # pytest workers, shared filesystem) can never load a torn .so. The
+    # temp file is removed on every exit path.
+    fd, tmp = tempfile.mkstemp(prefix="build-", suffix=".so", dir=_NATIVE_DIR)
+    os.close(fd)
     cmd = [
         "g++", "-O3", "-march=native", "-std=c++17", "-shared", "-fPIC",
         "-pthread", _SRC, "-o", tmp,
     ]
     try:
         subprocess.run(cmd, check=True, capture_output=True, timeout=120)
-        os.replace(tmp, _LIB)
+        os.replace(tmp, lib_path)
         return True
     except (subprocess.SubprocessError, FileNotFoundError, OSError) as e:
         get_logger().warning(
             "native data core build failed (%s); using numpy fallback", e
         )
+        return False
+    finally:
         try:
             os.unlink(tmp)
         except OSError:
             pass
-        return False
 
 
 def _load() -> ctypes.CDLL | None:
@@ -89,7 +102,7 @@ def _load() -> ctypes.CDLL | None:
     # (data/native.py _load -> _build -> subprocess.run): every data
     # thread's first native call would queue behind one compile.
     # Concurrent builds are already safe without the lock — _build
-    # compiles to a pid-unique temp path and os.replace is atomic.
+    # compiles to a unique temp path and os.replace is atomic.
     with _lock:
         claimed = not _tried
         _tried = True
@@ -109,40 +122,24 @@ def _load_uncached() -> ctypes.CDLL | None:
     """Build/bind the library (no caching, no locks held)."""
     if os.environ.get("FRL_TPU_NO_NATIVE"):
         return None
-    # A lib shipped without its source is simply trusted (no mtime to
-    # compare against) — graceful degradation must not raise.
-    stale = not os.path.exists(_LIB) or (
-        os.path.exists(_SRC)
-        and os.path.getmtime(_LIB) < os.path.getmtime(_SRC)
-    )
-    if stale and not _build():
+    key = _cache_key()
+    if key is None:
+        get_logger().warning(
+            "native data core source %s not found; using numpy fallback",
+            _SRC,
+        )
+        return None
+    lib_path = os.path.join(_NATIVE_DIR, f"libfrl_data.{key}.so")
+    if not os.path.exists(lib_path) and not _build(lib_path):
         return None
     try:
-        lib = ctypes.CDLL(_LIB)
+        lib = ctypes.CDLL(lib_path)
     except OSError as e:
         get_logger().warning("native data core load failed (%s)", e)
         return None
     try:
         lib.frl_version.restype = ctypes.c_int
         version = lib.frl_version()
-        if version < 3 and os.path.exists(_SRC):
-            # Stale binary the mtime check missed (checkout ordering,
-            # clock skew) but the source is right here — rebuild once.
-            del lib
-            if _build():
-                lib = ctypes.CDLL(_LIB)
-                lib.frl_version.restype = ctypes.c_int
-                version = lib.frl_version()
-        if version < 3:
-            # A prebuilt .so shipped without source can predate newer
-            # entry points; binding them would raise mid-training.
-            # Degrade, don't crash.
-            get_logger().warning(
-                "native data core is v%d (< v3, missing gather_windows);"
-                " using numpy fallback — rebuild from frl_data.cpp",
-                version,
-            )
-            return None
         f64 = ctypes.POINTER(ctypes.c_float)
         i64 = ctypes.POINTER(ctypes.c_int64)
         u8 = ctypes.POINTER(ctypes.c_uint8)

@@ -103,16 +103,9 @@ def axis_index(axis: str):
 
 
 def axis_size(axis: str):
-    """Size of the mesh axis (reference: group world size).
-
-    ``lax.axis_size`` only exists on newer jax; older releases statically
-    fold ``psum(1, axis)`` of a Python literal to the same int — the
-    classic idiom, kept as the fallback so ring/Ulysses hop counts stay
-    compile-time constants on both.
-    """
-    if hasattr(lax, "axis_size"):
-        return lax.axis_size(axis)
-    return lax.psum(1, axis)
+    """Size of the mesh axis (reference: group world size) — a
+    compile-time constant, so ring/Ulysses hop counts stay static."""
+    return lax.axis_size(axis)
 
 
 # ------------------------------ host tier ---------------------------------

@@ -77,17 +77,8 @@ def _enable_cpu_collectives() -> None:
     unconditionally for multi-process topologies: it only configures the
     CPU backend's cross-process transport, so on TPU pods it is inert
     (platform sniffing here is a trap — probing the backend would
-    initialize it prematurely, and the config flags differ across jax
-    releases). No-op on jax builds without the knob."""
-    try:
-        jax.config.update("jax_cpu_collectives_implementation", "gloo")
-    except Exception as e:  # older/newer jax without the option: leave default
-        import logging
-
-        logging.getLogger(__name__).debug(
-            "jax_cpu_collectives_implementation unavailable (%s); "
-            "keeping the backend default", e,
-        )
+    initialize it prematurely)."""
+    jax.config.update("jax_cpu_collectives_implementation", "gloo")
 
 
 def process_count() -> int:

@@ -234,23 +234,13 @@ class mesh_context:
         return False
 
 
-def shard_map_compat(fn, *, mesh: Mesh, in_specs, out_specs):
-    """``shard_map`` across the jax API move: new jax exposes
-    ``jax.shard_map(..., check_vma=)``, older releases only
-    ``jax.experimental.shard_map.shard_map(..., check_rep=)``. Every
-    manual-collective op routes through here so the repo runs on both.
-    Replication checking is disabled either way: callers' out_specs declare
+def shard_map_unchecked(fn, *, mesh: Mesh, in_specs, out_specs):
+    """``jax.shard_map`` with replication checking off — every
+    manual-collective op routes through here: callers' out_specs declare
     intent (psum'd outputs are replicated by construction)."""
-    if hasattr(jax, "shard_map"):
-        return jax.shard_map(
-            fn, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-            check_vma=False,
-        )
-    from jax.experimental.shard_map import shard_map
-
-    return shard_map(
+    return jax.shard_map(
         fn, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-        check_rep=False,
+        check_vma=False,
     )
 
 

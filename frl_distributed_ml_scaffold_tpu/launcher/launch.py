@@ -8,8 +8,9 @@ Usage::
 Reference stack (a): torchrun forks N workers, each joins an NCCL process
 group. Here: one process per host; ``--device=tpu`` brings up the pod slice
 via ``initialize_distributed`` (autodetected on Cloud TPU, FRL_TPU_* env
-overrides for manual clusters); ``--device=cpu --sim-devices=8`` gives the
-simulated multi-chip CPU mesh used by the test tier (SURVEY C20).
+overrides for manual clusters) and exits non-zero when JAX finds no TPU;
+``--device=cpu --sim-devices=8`` gives the simulated multi-chip CPU mesh
+used by the test tier (SURVEY C20).
 """
 
 from __future__ import annotations
@@ -94,25 +95,43 @@ def _configure_platform(args) -> None:
     enable_compile_cache()
 
 
-def enable_compile_cache(cache_dir: str | None = None) -> None:
-    """Persistent XLA compilation cache (shared by launcher, bench, tests).
+def enable_compile_cache() -> None:
+    """Persistent XLA compilation cache — the ONE place this repo places it
+    (launcher, bench, chip_smoke and tests all call this).
 
-    First TPU compiles run ~20-40s; repeat runs of the same config hit the
-    cache instead. Off only when FRL_TPU_NO_COMPILE_CACHE is set; cache
-    write failures are non-fatal inside jax.
+    Where ``JAX_COMPILATION_CACHE_DIR`` is set, JAX reads it itself and no
+    directory is set in code, so the cache can be placed from outside.
+    Otherwise it lives at the fixed ``<checkout>/.jax_cache`` (git-ignored):
+    the path is part of the cache key, so a directory that moves between
+    runs would never hit. Cache write failures are non-fatal inside jax.
     """
-    if os.environ.get("FRL_TPU_NO_COMPILE_CACHE"):
-        return
     import jax
 
-    if cache_dir is None:
-        cache_dir = os.environ.get(
-            "FRL_TPU_COMPILE_CACHE",
+    if not os.environ.get("JAX_COMPILATION_CACHE_DIR"):
+        jax.config.update(
+            "jax_compilation_cache_dir",
             os.path.join(os.path.dirname(os.path.dirname(
                 os.path.dirname(os.path.abspath(__file__)))), ".jax_cache"),
         )
-    jax.config.update("jax_compilation_cache_dir", cache_dir)
     jax.config.update("jax_persistent_cache_min_compile_time_secs", 1.0)
+
+
+def require_tpu_backend() -> None:
+    """``--device=tpu`` means the chip: with none attached JAX would
+    quietly train on the CPU, so a run that asked for the TPU and did not
+    get it stops here. Initializes the backend — call it only in the
+    process that trains (never in the elastic supervisor, whose child
+    needs the chip)."""
+    import jax
+
+    backend = jax.default_backend()
+    if backend != "tpu":
+        raise SystemExit(
+            f"--device=tpu but JAX's default backend is {backend!r} "
+            f"(devices: {jax.devices()}): no TPU is attached or visible "
+            "to this process. Pass --device=cpu [--sim-devices=N] to run "
+            "on the CPU on purpose."
+        )
 
 
 def run_experiment(cfg, *, check_imports: bool = True):
@@ -254,6 +273,8 @@ def main(argv=None) -> int:
     from frl_distributed_ml_scaffold_tpu.utils.logging import get_logger
 
     initialize_distributed(args.coordinator, args.num_processes, args.process_id)
+    if args.device == "tpu":
+        require_tpu_backend()
     from frl_distributed_ml_scaffold_tpu.utils.debugging import sanitize_from_env
 
     sanitize_from_env()  # FRL_TPU_SANITIZE=nans,infs,leaks (SURVEY §5)

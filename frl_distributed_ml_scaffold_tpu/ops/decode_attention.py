@@ -67,8 +67,9 @@ Speculative verify tile (ISSUE 11): speculative decoding proposes k
 draft tokens per row and the TARGET model scores all k+1 positions in
 one batched forward — the whole point is that the pool read (the
 bandwidth bill decode pays) is amortized over k+1 query positions
-instead of one. ``paged_verify_attention`` extends the paged kernel
-from q_len=1 to a small q TILE ``[B, T, H, D]`` with causal masking
+instead of one. ``paged_verify_attention`` runs THE paged kernel over a
+small q TILE ``[B, T, H, D]`` (single-token paged decode is its T=1
+tile: Mosaic refuses a q_len=1 kernel's mat-vec) with causal masking
 inside the chunk loop: query position t of a row whose total occupancy
 (tile included) is ``kv_len`` attends logical positions
 ``< kv_len - T + 1 + t`` — position 0 sees exactly what a single-token
@@ -345,8 +346,11 @@ def dense_paged_verify_attention(
 
 def _decode_kernel(len_ref, q_ref, k_ref, v_ref, o_ref, m_ref, l_ref,
                    acc_ref, *, block_k, scale):
-    """One (batch row, KV chunk) program: all H heads at once, so the
-    sublane dimension of every tile is H (scores are [H, block_k])."""
+    """One (batch row, KV chunk) program: all H heads at once. The query
+    keeps its row dimension of size 1 (scores are [H, 1, block_k]): Mosaic
+    refuses a batched mat-vec whose left operand has no free dimension
+    (``lhs_non_contracting_dims`` empty), so the dots are batched
+    [1, D] x [D, block_k] matmuls, as in the verify tile."""
     b_, j = pl.program_id(0), pl.program_id(1)
     n_k = pl.num_programs(1)
     length = len_ref[b_]
@@ -361,16 +365,16 @@ def _decode_kernel(len_ref, q_ref, k_ref, v_ref, o_ref, m_ref, l_ref,
     # DMA is clamped to the last live chunk by the index map below).
     @pl.when(j * block_k < length)
     def _step():
-        q = q_ref[0, :, 0, :]  # (H, D)
+        q = q_ref[0]  # (H, 1, D)
         k_blk = k_ref[0]  # (H, Bk, D)
         v_blk = v_ref[0]
-        # Batched-over-heads matvec on the MXU: (H, D) x (H, Bk, D) -> (H, Bk).
+        # (H, 1, D) x (H, Bk, D) -> (H, 1, Bk): batch over H, contract D.
         s = lax.dot_general(
             q, k_blk,
-            dimension_numbers=(((1,), (2,)), ((0,), (0,))),
+            dimension_numbers=(((2,), (2,)), ((0,), (0,))),
             preferred_element_type=jnp.float32,
         ) * scale
-        kpos = j * block_k + lax.broadcasted_iota(jnp.int32, s.shape, 1)
+        kpos = j * block_k + lax.broadcasted_iota(jnp.int32, s.shape, 2)
         s = jnp.where(kpos < length, s, _NEG_INF)
         m = m_ref[:]
         m_new = jnp.maximum(m, s.max(axis=-1, keepdims=True))
@@ -378,17 +382,17 @@ def _decode_kernel(len_ref, q_ref, k_ref, v_ref, o_ref, m_ref, l_ref,
         alpha = jnp.exp(m - m_new)
         m_ref[:] = m_new
         l_ref[:] = l_ref[:] * alpha + p.sum(axis=-1, keepdims=True)
-        # (H, Bk) x (H, Bk, D) -> (H, D), batched over H.
+        # (H, 1, Bk) x (H, Bk, D) -> (H, 1, D): batch over H, contract Bk.
         acc_ref[:] = acc_ref[:] * alpha + lax.dot_general(
             p.astype(v_blk.dtype), v_blk,
-            dimension_numbers=(((1,), (1,)), ((0,), (0,))),
+            dimension_numbers=(((2,), (1,)), ((0,), (0,))),
             preferred_element_type=jnp.float32,
         )
 
     @pl.when(j == n_k - 1)
     def _finish():
         l_safe = jnp.maximum(l_ref[:], 1e-30)
-        o_ref[0, :, 0, :] = (acc_ref[:] / l_safe).astype(o_ref.dtype)
+        o_ref[0] = (acc_ref[:] / l_safe).astype(o_ref.dtype)
 
 
 def _decode_kernel_quant(len_ref, q_ref, k_ref, ks_ref, v_ref, vs_ref,
@@ -411,17 +415,17 @@ def _decode_kernel_quant(len_ref, q_ref, k_ref, ks_ref, v_ref, vs_ref,
 
     @pl.when(j * block_k < length)
     def _step():
-        q = q_ref[0, :, 0, :].astype(jnp.float32)  # (H, D)
+        q = q_ref[0].astype(jnp.float32)  # (H, 1, D)
         k_blk = k_ref[0].astype(jnp.float32)  # (H, Bk, D) — VMEM upcast
         v_blk = v_ref[0].astype(jnp.float32)
-        k_s = ks_ref[0]  # (H, Bk) fp32 scales
-        v_s = vs_ref[0]
+        k_s = ks_ref[0][:, None, :]  # (H, 1, Bk) fp32 scales
+        v_s = vs_ref[0][:, None, :]
         s = lax.dot_general(
             q, k_blk,
-            dimension_numbers=(((1,), (2,)), ((0,), (0,))),
+            dimension_numbers=(((2,), (2,)), ((0,), (0,))),
             preferred_element_type=jnp.float32,
         ) * k_s * scale
-        kpos = j * block_k + lax.broadcasted_iota(jnp.int32, s.shape, 1)
+        kpos = j * block_k + lax.broadcasted_iota(jnp.int32, s.shape, 2)
         s = jnp.where(kpos < length, s, _NEG_INF)
         m = m_ref[:]
         m_new = jnp.maximum(m, s.max(axis=-1, keepdims=True))
@@ -431,108 +435,7 @@ def _decode_kernel_quant(len_ref, q_ref, k_ref, ks_ref, v_ref, vs_ref,
         l_ref[:] = l_ref[:] * alpha + p.sum(axis=-1, keepdims=True)
         acc_ref[:] = acc_ref[:] * alpha + lax.dot_general(
             p * v_s, v_blk,
-            dimension_numbers=(((1,), (1,)), ((0,), (0,))),
-            preferred_element_type=jnp.float32,
-        )
-
-    @pl.when(j == n_k - 1)
-    def _finish():
-        l_safe = jnp.maximum(l_ref[:], 1e-30)
-        o_ref[0, :, 0, :] = (acc_ref[:] / l_safe).astype(o_ref.dtype)
-
-
-def _paged_decode_kernel(len_ref, tbl_ref, q_ref, k_ref, v_ref, o_ref,
-                         m_ref, l_ref, acc_ref, *, block_k, scale):
-    """Paged sibling of ``_decode_kernel``: one (batch row, logical
-    block) program. The block table is consumed by the INDEX MAPS (it
-    rides the scalar-prefetch channel, so the physical block id is known
-    before the body runs and the DMA fetches pool block
-    ``tbl_ref[b, j]`` directly); the body itself only needs the length
-    mask — pool blocks arrive in their storage layout ``(bs, H, D)``, so
-    the dots batch over the MIDDLE heads dim instead of transposing the
-    pool."""
-    b_, j = pl.program_id(0), pl.program_id(1)
-    n_k = pl.num_programs(1)
-    length = len_ref[b_]
-
-    @pl.when(j == 0)
-    def _init():
-        m_ref[:] = jnp.full_like(m_ref, _NEG_INF)
-        l_ref[:] = jnp.zeros_like(l_ref)
-        acc_ref[:] = jnp.zeros_like(acc_ref)
-
-    @pl.when(j * block_k < length)
-    def _step():
-        q = q_ref[0]  # (H, D)
-        k_blk = k_ref[0]  # (Bk, H, D) — pool-block storage layout
-        v_blk = v_ref[0]
-        # (H, D) x (Bk, H, D) -> (H, Bk): batch over H (rhs dim 1).
-        s = lax.dot_general(
-            q, k_blk,
-            dimension_numbers=(((1,), (2,)), ((0,), (1,))),
-            preferred_element_type=jnp.float32,
-        ) * scale
-        kpos = j * block_k + lax.broadcasted_iota(jnp.int32, s.shape, 1)
-        s = jnp.where(kpos < length, s, _NEG_INF)
-        m = m_ref[:]
-        m_new = jnp.maximum(m, s.max(axis=-1, keepdims=True))
-        p = jnp.exp(s - m_new)
-        alpha = jnp.exp(m - m_new)
-        m_ref[:] = m_new
-        l_ref[:] = l_ref[:] * alpha + p.sum(axis=-1, keepdims=True)
-        # (H, Bk) x (Bk, H, D) -> (H, D): batch over H, contract Bk.
-        acc_ref[:] = acc_ref[:] * alpha + lax.dot_general(
-            p.astype(v_blk.dtype), v_blk,
-            dimension_numbers=(((1,), (0,)), ((0,), (1,))),
-            preferred_element_type=jnp.float32,
-        )
-
-    @pl.when(j == n_k - 1)
-    def _finish():
-        l_safe = jnp.maximum(l_ref[:], 1e-30)
-        o_ref[0] = (acc_ref[:] / l_safe).astype(o_ref.dtype)
-
-
-def _paged_decode_kernel_quant(len_ref, tbl_ref, q_ref, k_ref, ks_ref,
-                               v_ref, vs_ref, o_ref, m_ref, l_ref, acc_ref,
-                               *, block_k, scale):
-    """Quantized-pool sibling: 1-byte blocks upcast in VMEM, per-(pos,
-    head) scales fold into the score strip / probability row after the
-    dots — same per-chunk dequantize contract as ``_decode_kernel_quant``,
-    addressed through the block table."""
-    b_, j = pl.program_id(0), pl.program_id(1)
-    n_k = pl.num_programs(1)
-    length = len_ref[b_]
-
-    @pl.when(j == 0)
-    def _init():
-        m_ref[:] = jnp.full_like(m_ref, _NEG_INF)
-        l_ref[:] = jnp.zeros_like(l_ref)
-        acc_ref[:] = jnp.zeros_like(acc_ref)
-
-    @pl.when(j * block_k < length)
-    def _step():
-        q = q_ref[0].astype(jnp.float32)  # (H, D)
-        k_blk = k_ref[0].astype(jnp.float32)  # (Bk, H, D) — VMEM upcast
-        v_blk = v_ref[0].astype(jnp.float32)
-        k_s = ks_ref[0]  # (Bk, H) fp32 scales
-        v_s = vs_ref[0]
-        s = lax.dot_general(
-            q, k_blk,
-            dimension_numbers=(((1,), (2,)), ((0,), (1,))),
-            preferred_element_type=jnp.float32,
-        ) * jnp.swapaxes(k_s, 0, 1) * scale
-        kpos = j * block_k + lax.broadcasted_iota(jnp.int32, s.shape, 1)
-        s = jnp.where(kpos < length, s, _NEG_INF)
-        m = m_ref[:]
-        m_new = jnp.maximum(m, s.max(axis=-1, keepdims=True))
-        p = jnp.exp(s - m_new)
-        alpha = jnp.exp(m - m_new)
-        m_ref[:] = m_new
-        l_ref[:] = l_ref[:] * alpha + p.sum(axis=-1, keepdims=True)
-        acc_ref[:] = acc_ref[:] * alpha + lax.dot_general(
-            p * jnp.swapaxes(v_s, 0, 1), v_blk,
-            dimension_numbers=(((1,), (0,)), ((0,), (1,))),
+            dimension_numbers=(((2,), (1,)), ((0,), (0,))),
             preferred_element_type=jnp.float32,
         )
 
@@ -544,13 +447,20 @@ def _paged_decode_kernel_quant(len_ref, tbl_ref, q_ref, k_ref, ks_ref,
 
 def _paged_verify_kernel(len_ref, tbl_ref, q_ref, k_ref, v_ref, o_ref,
                          m_ref, l_ref, acc_ref, *, block_k, q_len, scale):
-    """Verify-tile sibling of ``_paged_decode_kernel`` (ISSUE 11): the
-    query is a small [T, H, D] tile, scores widen to [H, T, Bk], and the
-    causal mask is applied INSIDE the chunk loop — query t of a row at
-    total occupancy ``len_ref[b]`` admits keys at logical positions
-    ``< len - (T-1) + t``. Running max/denominator/accumulator carry the
-    extra T dim in VMEM scratch; the block-table DMA gather is the same
-    scalar-prefetch index map as the q_len=1 kernel."""
+    """THE paged kernel — one (batch row, logical block) program over a
+    small [T, H, D] query tile; single-token decode is the T=1 tile (a
+    dedicated q_len=1 kernel would be a batched mat-vec whose left
+    operand has no free dimension, which Mosaic refuses). The block
+    table is consumed by the INDEX MAPS (it rides the scalar-prefetch
+    channel, so the physical block id is known before the body runs and
+    the DMA fetches pool block ``tbl_ref[b, j]`` directly). Pool blocks
+    arrive in their storage layout ``(bs, H, D)``, so the dots batch
+    over the MIDDLE heads dim instead of transposing the pool; scores
+    are [H, T, Bk]. The causal mask is applied INSIDE the chunk loop —
+    query t of a row at total occupancy ``len_ref[b]`` admits keys at
+    logical positions ``< len - (T-1) + t`` (for T=1: ``< len``).
+    Running max/denominator/accumulator carry the T dim in VMEM
+    scratch."""
     b_, j = pl.program_id(0), pl.program_id(1)
     n_k = pl.num_programs(1)
     length = len_ref[b_]
@@ -599,10 +509,10 @@ def _paged_verify_kernel(len_ref, tbl_ref, q_ref, k_ref, v_ref, o_ref,
 def _paged_verify_kernel_quant(len_ref, tbl_ref, q_ref, k_ref, ks_ref,
                                v_ref, vs_ref, o_ref, m_ref, l_ref, acc_ref,
                                *, block_k, q_len, scale):
-    """Quantized-pool verify tile: 1-byte blocks upcast in VMEM, the
+    """Quantized-pool sibling: 1-byte blocks upcast in VMEM, the
     per-(position, head) scales fold into the [H, T, Bk] score strip /
-    probability rows after the dots — the ``_paged_decode_kernel_quant``
-    contract with the tile's causal mask composed on top."""
+    probability rows after the dots — same per-chunk dequantize contract
+    as ``_decode_kernel_quant``, addressed through the block table."""
     b_, j = pl.program_id(0), pl.program_id(1)
     n_k = pl.num_programs(1)
     length = len_ref[b_]
@@ -685,9 +595,9 @@ def _flash_decode(q, k, v, kv_len, *, block_k, interpret):
         in_specs=[q_spec, kv_spec, kv_spec],
         out_specs=q_spec,
         scratch_shapes=[
-            pltpu.VMEM((h, 1), jnp.float32),  # running max
-            pltpu.VMEM((h, 1), jnp.float32),  # running denom
-            pltpu.VMEM((h, d), jnp.float32),  # output accumulator
+            pltpu.VMEM((h, 1, 1), jnp.float32),  # running max
+            pltpu.VMEM((h, 1, 1), jnp.float32),  # running denom
+            pltpu.VMEM((h, 1, d), jnp.float32),  # output accumulator
         ],
     )
     return pl.pallas_call(
@@ -715,9 +625,9 @@ def _flash_decode_quant(q, k, k_scale, v, v_scale, kv_len, *, block_k,
         in_specs=[q_spec, kv_spec, sc_spec, kv_spec, sc_spec],
         out_specs=q_spec,
         scratch_shapes=[
-            pltpu.VMEM((h, 1), jnp.float32),  # running max
-            pltpu.VMEM((h, 1), jnp.float32),  # running denom
-            pltpu.VMEM((h, d), jnp.float32),  # output accumulator
+            pltpu.VMEM((h, 1, 1), jnp.float32),  # running max
+            pltpu.VMEM((h, 1, 1), jnp.float32),  # running denom
+            pltpu.VMEM((h, 1, d), jnp.float32),  # output accumulator
         ],
     )
     return pl.pallas_call(
@@ -758,61 +668,16 @@ def _paged_scale_index_map(block_k):
     return index_map
 
 
-def _flash_paged_decode(q, k_pool, v_pool, kv_len, tables, *, interpret,
-                        k_scale=None, v_scale=None):
-    """q ``[B, H, D]``, pools ``[N, bs, H, D]`` (+ optional ``[N, bs, H]``
-    fp32 scales), tables ``[B, M]`` int32 -> ``[B, H, D]``. Grid is
-    (rows, logical blocks); block_k == the pool's block size."""
-    b, h, d = q.shape
-    _, bs, _, _ = k_pool.shape
-    n_k = tables.shape[1]
-    q_spec = pl.BlockSpec((1, h, d), lambda b_, j, *_refs: (b_, 0, 0))
-    kv_spec = pl.BlockSpec((1, bs, h, d), _paged_kv_index_map(bs))
-    scratch = [
-        pltpu.VMEM((h, 1), jnp.float32),  # running max
-        pltpu.VMEM((h, 1), jnp.float32),  # running denom
-        pltpu.VMEM((h, d), jnp.float32),  # output accumulator
-    ]
-    if k_scale is None:
-        grid_spec = pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=2,
-            grid=(b, n_k),
-            in_specs=[q_spec, kv_spec, kv_spec],
-            out_specs=q_spec,
-            scratch_shapes=scratch,
-        )
-        return pl.pallas_call(
-            functools.partial(
-                _paged_decode_kernel, block_k=bs, scale=1.0 / np.sqrt(d)
-            ),
-            grid_spec=grid_spec,
-            out_shape=jax.ShapeDtypeStruct(q.shape, q.dtype),
-            interpret=interpret,
-        )(kv_len, tables, q, k_pool, v_pool)
-    sc_spec = pl.BlockSpec((1, bs, h), _paged_scale_index_map(bs))
-    grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=2,
-        grid=(b, n_k),
-        in_specs=[q_spec, kv_spec, sc_spec, kv_spec, sc_spec],
-        out_specs=q_spec,
-        scratch_shapes=scratch,
-    )
-    return pl.pallas_call(
-        functools.partial(
-            _paged_decode_kernel_quant, block_k=bs, scale=1.0 / np.sqrt(d)
-        ),
-        grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct(q.shape, q.dtype),
-        interpret=interpret,
-    )(kv_len, tables, q, k_pool, k_scale, v_pool, v_scale)
-
-
 # ------------------------------------------------------------------ router
 
 
-#: Preferred KV chunk: decode is HBM-bandwidth-bound, so the chunk only has
-#: to be big enough to amortize the revolving-buffer DMA; 512 matches the
-#: short-T training block. The on-chip ladder is queued (BACKLOG R8-1).
+#: Preferred KV chunk, in positions of a 2-byte (or narrower) cache: decode
+#: is HBM-bandwidth-bound, so the chunk only has to be big enough to
+#: amortize the revolving-buffer DMA; 512 matches the short-T training
+#: block. A 4-byte cache takes half as many positions (``_local_decode``):
+#: at H=16, D=64 the double-buffered fp32 K and V chunks of 512 positions
+#: overflow the v5e's scoped VMEM and Mosaic refuses the kernel. The
+#: on-chip ladder is queued (BACKLOG R8-1).
 _PREFERRED_BLOCK_K = 512
 
 
@@ -839,7 +704,8 @@ def _local_decode(q, k, v, kv_len, *, impl, interpret, k_scale=None,
     if interpret is None:
         interpret = FORCE_INTERPRET
     s, d = k.shape[1], q.shape[-1]
-    block_k = _pick_block(s, min(_PREFERRED_BLOCK_K, s))
+    pref = _PREFERRED_BLOCK_K * 2 // max(2, k.dtype.itemsize)
+    block_k = _pick_block(s, min(pref, s))
     if block_k is None or d % 32 != 0:
         if jax.default_backend() == "tpu":
             _warn_fallback(
@@ -906,7 +772,7 @@ def decode_attention(
     from frl_distributed_ml_scaffold_tpu.dist.mesh import (
         BATCH_AXES,
         current_mesh_env,
-        shard_map_compat,
+        shard_map_unchecked,
     )
 
     if (k_scale is None) != (v_scale is None):
@@ -926,7 +792,7 @@ def decode_attention(
     q_spec = P(batch, "model", None)
     kv_spec = P(batch, None, "model", None)
     if k_scale is None:
-        fn = shard_map_compat(
+        fn = shard_map_unchecked(
             functools.partial(_local_decode, impl=impl, interpret=interpret),
             mesh=env.mesh,
             in_specs=(q_spec, kv_spec, kv_spec, P(batch)),
@@ -934,7 +800,7 @@ def decode_attention(
         )
         return fn(q, k, v, kv_len)
     sc_spec = P(batch, None, "model")
-    fn = shard_map_compat(
+    fn = shard_map_unchecked(
         lambda q_, k_, v_, l_, ks_, vs_: _local_decode(
             q_, k_, v_, l_, impl=impl, interpret=interpret,
             k_scale=ks_, v_scale=vs_,
@@ -985,15 +851,19 @@ def _local_paged_decode(q, k_pool, v_pool, kv_len, tables, *, impl,
         interpret = False
     lens = jnp.maximum(kv_len.astype(jnp.int32), 1)
     tbl = tables.astype(jnp.int32)
+    # Single-token decode is the T=1 verify tile (``_paged_verify_kernel``).
+    qt = q[:, None]
     if quant:
-        return _flash_paged_decode(
-            q, k_pool, v_pool, lens, tbl, interpret=interpret,
+        o = _flash_paged_verify(
+            qt, k_pool, v_pool, lens, tbl, interpret=interpret,
             k_scale=k_scale.astype(jnp.float32),
             v_scale=v_scale.astype(jnp.float32),
         )
-    return _flash_paged_decode(
-        q, k_pool, v_pool, lens, tbl, interpret=interpret
-    )
+    else:
+        o = _flash_paged_verify(
+            qt, k_pool, v_pool, lens, tbl, interpret=interpret
+        )
+    return o[:, 0]
 
 
 def paged_decode_attention(
@@ -1032,7 +902,7 @@ def paged_decode_attention(
     from frl_distributed_ml_scaffold_tpu.dist.mesh import (
         BATCH_AXES,
         current_mesh_env,
-        shard_map_compat,
+        shard_map_unchecked,
     )
 
     if (k_scale is None) != (v_scale is None):
@@ -1053,7 +923,7 @@ def paged_decode_attention(
     pool_spec = P(None, None, "model", None)
     tbl_spec = P(batch, None)
     if k_scale is None:
-        fn = shard_map_compat(
+        fn = shard_map_unchecked(
             functools.partial(
                 _local_paged_decode, impl=impl, interpret=interpret
             ),
@@ -1063,7 +933,7 @@ def paged_decode_attention(
         )
         return fn(q, k_pool, v_pool, kv_len, block_tables)
     sc_spec = P(None, None, "model")
-    fn = shard_map_compat(
+    fn = shard_map_unchecked(
         lambda q_, k_, v_, l_, t_, ks_, vs_: _local_paged_decode(
             q_, k_, v_, l_, t_, impl=impl, interpret=interpret,
             k_scale=ks_, v_scale=vs_,
@@ -1081,10 +951,10 @@ def paged_decode_attention(
 
 def _flash_paged_verify(q, k_pool, v_pool, kv_len, tables, *, interpret,
                         k_scale=None, v_scale=None):
-    """q ``[B, T, H, D]``, pools ``[N, bs, H, D]`` (+ optional scales),
-    tables ``[B, M]`` int32 -> ``[B, T, H, D]``. Grid is (rows, logical
-    blocks) exactly like the q_len=1 kernel; the scratch accumulators
-    carry the extra T dim."""
+    """q ``[B, T, H, D]``, pools ``[N, bs, H, D]`` (+ optional
+    ``[N, bs, H]`` fp32 scales), tables ``[B, M]`` int32 ->
+    ``[B, T, H, D]``. Grid is (rows, logical blocks); block_k == the
+    pool's block size; the scratch accumulators carry the T dim."""
     b, t, h, d = q.shape
     _, bs, _, _ = k_pool.shape
     n_k = tables.shape[1]
@@ -1209,7 +1079,7 @@ def paged_verify_attention(
     from frl_distributed_ml_scaffold_tpu.dist.mesh import (
         BATCH_AXES,
         current_mesh_env,
-        shard_map_compat,
+        shard_map_unchecked,
     )
 
     if (k_scale is None) != (v_scale is None):
@@ -1230,7 +1100,7 @@ def paged_verify_attention(
     pool_spec = P(None, None, "model", None)
     tbl_spec = P(batch, None)
     if k_scale is None:
-        fn = shard_map_compat(
+        fn = shard_map_unchecked(
             functools.partial(
                 _local_paged_verify, impl=impl, interpret=interpret
             ),
@@ -1240,7 +1110,7 @@ def paged_verify_attention(
         )
         return fn(q, k_pool, v_pool, kv_len, block_tables)
     sc_spec = P(None, None, "model")
-    fn = shard_map_compat(
+    fn = shard_map_unchecked(
         lambda q_, k_, v_, l_, t_, ks_, vs_: _local_paged_verify(
             q_, k_, v_, l_, t_, impl=impl, interpret=interpret,
             k_scale=ks_, v_scale=vs_,

@@ -550,10 +550,10 @@ def flash_attention(
     # batch axes and the TP head axis keeps it fully local (same mechanism
     # as the ring/Ulysses siblings). Sequence sharding is ring attention's
     # job, not this kernel's (validated above).
-    from frl_distributed_ml_scaffold_tpu.dist.mesh import shard_map_compat
+    from frl_distributed_ml_scaffold_tpu.dist.mesh import shard_map_unchecked
 
     spec = jax.sharding.PartitionSpec(BATCH_AXES, None, "model", None)
-    return shard_map_compat(
+    return shard_map_unchecked(
         _call,
         mesh=env.mesh,
         in_specs=(spec, spec, spec),

@@ -260,7 +260,7 @@ def _bn_bwd_dispatch(x, dy, scale, mean, var, eps, interpret):
     from frl_distributed_ml_scaffold_tpu.dist.mesh import (
         BATCH_AXES,
         current_mesh_env,
-        shard_map_compat,
+        shard_map_unchecked,
     )
 
     env = current_mesh_env()
@@ -275,7 +275,7 @@ def _bn_bwd_dispatch(x, dy, scale, mean, var, eps, interpret):
 
     batch = P(BATCH_AXES, *([None] * (x.ndim - 1)))
     rep = P()
-    return shard_map_compat(
+    return shard_map_unchecked(
         functools.partial(local, axis_names=BATCH_AXES),
         mesh=env.mesh,
         in_specs=(batch, batch, rep, rep, rep),

@@ -54,9 +54,9 @@ def ulysses_attention(
     inner = partial(
         _ulysses_shard_fn, axis_name=axis_name, causal=causal, interpret=interpret
     )
-    from frl_distributed_ml_scaffold_tpu.dist.mesh import shard_map_compat
+    from frl_distributed_ml_scaffold_tpu.dist.mesh import shard_map_unchecked
 
-    return shard_map_compat(
+    return shard_map_unchecked(
         inner,
         mesh=env.mesh,
         in_specs=(spec, spec, spec),

@@ -54,7 +54,7 @@ from jax.sharding import PartitionSpec as P
 from frl_distributed_ml_scaffold_tpu.dist import collectives
 from frl_distributed_ml_scaffold_tpu.dist.mesh import (
     current_mesh_env,
-    shard_map_compat,
+    shard_map_unchecked,
 )
 
 #: checkpoint_name tag on gathered params; the remat policy drops exactly
@@ -161,7 +161,7 @@ def gather_leaf(x: jax.Array, spec: P, *, axis: str = "fsdp", token=None):
     def inner(shard):
         return collectives.all_gather(shard, axis, gather_axis=dim, tiled=True)
 
-    y = shard_map_compat(
+    y = shard_map_unchecked(
         inner, mesh=env.mesh, in_specs=(spec,), out_specs=out_spec
     )(x)
     return checkpoint_name(y, GATHER_NAME)

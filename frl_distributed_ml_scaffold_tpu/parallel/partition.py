@@ -75,6 +75,35 @@ def fsdp_spec_for(
     return P(*entries)
 
 
+def _fit_rule_to_shape(
+    name: str, spec: P, shape: Sequence[int], mesh: Mesh
+) -> P:
+    """Drop a rule's mesh axes from every dimension they do not divide;
+    that dimension is then replicated (and the fsdp overlay may still take
+    another). A rule names the dimension a layer WANTS cut, but only the
+    shape says whether it can be: GPT-2's published vocabulary, 50257, is
+    odd, so no ``model`` axis divides the embedding's vocab dimension and
+    the Megatron rule for ``wte`` cannot apply at full width."""
+    entries = list(spec)
+    for i, (dim, e) in enumerate(zip(shape, entries)):
+        if e is None:
+            continue
+        axes = e if isinstance(e, tuple) else (e,)
+        size = int(np.prod([mesh.shape[a] for a in axes]))
+        if dim % size != 0:
+            from frl_distributed_ml_scaffold_tpu.utils.logging import (
+                get_logger,
+            )
+
+            get_logger().warning(
+                "param %s: dim %d of shape %s is not divisible by mesh "
+                "axis %r (size %d); that dimension stays replicated",
+                name, i, tuple(shape), e, size,
+            )
+            entries[i] = None
+    return P(*entries)
+
+
 def param_specs(
     params: Any,
     parallel: ParallelConfig,
@@ -86,6 +115,7 @@ def param_specs(
 
     def decide(name: str, leaf) -> P:
         base = (rules.match(name) if rules else None) or P()
+        base = _fit_rule_to_shape(name, base, leaf.shape, mesh)
         if parallel.param_sharding == "fsdp":
             return fsdp_spec_for(
                 leaf.shape,

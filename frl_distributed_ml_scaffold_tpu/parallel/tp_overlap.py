@@ -45,7 +45,7 @@ from jax.sharding import PartitionSpec as P
 from frl_distributed_ml_scaffold_tpu.dist.mesh import (
     BATCH_AXES,
     current_mesh_env,
-    shard_map_compat,
+    shard_map_unchecked,
 )
 from frl_distributed_ml_scaffold_tpu.ops.collective_matmul import (
     all_gather_matmul,
@@ -168,7 +168,7 @@ class TpHooks:
             precision=precision,
             lowp=self.lowp,
         )
-        y2 = shard_map_compat(
+        y2 = shard_map_unchecked(
             inner,
             mesh=env.mesh,
             in_specs=(self.stream_spec(), P(None, self.axis)),
@@ -199,7 +199,7 @@ class TpHooks:
             precision=precision,
             lowp=self.lowp,
         )
-        z2 = shard_map_compat(
+        z2 = shard_map_unchecked(
             inner,
             mesh=env.mesh,
             in_specs=(self._split_spec(), P(self.axis, None)),
@@ -262,7 +262,7 @@ class _QkvContext:
             precision=precision,
             lowp=hooks.lowp,
         )
-        y2, full = shard_map_compat(
+        y2, full = shard_map_unchecked(
             inner,
             mesh=env.mesh,
             in_specs=(hooks.stream_spec(), P(None, hooks.axis)),

@@ -116,14 +116,14 @@ def collective_callable(plan: LeafPlan):
     they cannot drift. ``_NAIVE_GATHER_SCATTER`` swaps in the
     replicated-staging reference, which is both the mutation gate's
     mutant and the tests' equivalence oracle."""
-    from frl_distributed_ml_scaffold_tpu.dist.mesh import shard_map_compat
+    from frl_distributed_ml_scaffold_tpu.dist.mesh import shard_map_unchecked
 
     body = (
         _naive_body(plan.transition)
         if _NAIVE_GATHER_SCATTER
         else _collective_body(plan.transition)
     )
-    return shard_map_compat(
+    return shard_map_unchecked(
         body, mesh=plan.dst_sharding.mesh,
         in_specs=(plan.src_sharding.spec,),
         out_specs=plan.dst_sharding.spec,

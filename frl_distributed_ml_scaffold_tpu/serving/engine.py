@@ -956,6 +956,20 @@ class ServingEngine:
 
     # ------------------------------------------------------ jitted shapes
 
+    def lower_decode_step(self):
+        """The decode program at the engine's current cache shape, lowered
+        — for callers that inspect the program (chip_smoke.py checks that
+        the decode kernel is in it)."""
+        fn = (
+            self._paged_decode_fn() if self.paged
+            else self._decode_fn(self.bucket)
+        )
+        with self._trace_ctx():
+            return fn.lower(
+                self.params, self.cache, jnp.asarray(self._last_tok),
+                self._rng,
+            )
+
     def _model_at(self, cache_len: int):
         return self.model.clone(cache_len=int(cache_len))
 

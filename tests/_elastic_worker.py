@@ -19,7 +19,7 @@ import sys
 def main() -> int:
     from frl_distributed_ml_scaffold_tpu.launcher.launch import main as launch_main
 
-    return launch_main(
+    rc = launch_main(
         [
             "--config", "mnist_mlp",
             "--device", "cpu",
@@ -41,6 +41,12 @@ def main() -> int:
             "workdir=" + os.environ["FRL_TEST_WORKDIR"],
         ]
     )
+    # A chip belongs to one process at a time: the supervisor (this
+    # process) must never have initialized a backend its children need.
+    from jax._src import xla_bridge
+
+    print(f"SUPERVISOR_BACKENDS={len(xla_bridge._backends)}", flush=True)
+    return rc
 
 
 if __name__ == "__main__":

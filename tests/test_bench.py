@@ -1,4 +1,7 @@
-"""Benchmark harness: protocol record completeness (BASELINE.md §protocol)."""
+"""Benchmark harness: protocol record completeness (BASELINE.md §protocol),
+and the rule that a measurement path which finds no chip FAILS — it never
+benches the CPU under the chip's name, never republishes an older number,
+and never exits 0 after a failed benchmark."""
 
 from __future__ import annotations
 import pytest as _pytest_mark  # noqa: E402
@@ -19,200 +22,6 @@ import bench
 import pytest
 
 
-@pytest.fixture(autouse=True)
-def sandbox_last_good(tmp_path, monkeypatch):
-    """Point the last-good evidence cache at a sandbox for EVERY test here.
-
-    The round-5 self-poisoning bug: ``test_main_falls_through_candidate_
-    ladder`` drives ``main()``, which calls ``_save_last_good`` — so every
-    pytest run stamped the fixture value (123.0) into the committed
-    ``bench_last_good.json``, and the tier-1 stale fallback could never
-    re-emit real data. The env var covers subprocess reachers; the setattr
-    covers the already-imported module object.
-    """
-    path = tmp_path / "bench_last_good.json"
-    monkeypatch.setenv("FRL_BENCH_LAST_GOOD_PATH", str(path))
-    monkeypatch.setattr(bench, "LAST_GOOD_PATH", str(path))
-    yield path
-
-
-def test_save_last_good_writes_sandbox_not_repo(sandbox_last_good):
-    """The committed evidence cache must be untouchable from tests: writes
-    land in the env-overridden sandbox and the repo copy stays
-    byte-identical (it holds only real relay captures — the regenerated
-    2256.04 protocol-row record, corroborable by BENCH_TABLE.jsonl)."""
-    repo_cache = os.path.join(
-        os.path.dirname(os.path.abspath(bench.__file__)),
-        "bench_last_good.json",
-    )
-    before = open(repo_cache, "rb").read() if os.path.exists(repo_cache) else None
-    bench._save_last_good({"metric": "m", "value": 1.0, "unit": "x",
-                           "vs_baseline": 0.0})
-    assert sandbox_last_good.exists()
-    after = open(repo_cache, "rb").read() if os.path.exists(repo_cache) else None
-    assert before == after, (
-        "a test wrote the committed bench_last_good.json — the sandbox "
-        "fixture is not covering some _save_last_good path"
-    )
-    if before is not None:
-        assert json.loads(before).get("value") != 123.0, (
-            "the committed cache holds the old test-fixture value again"
-        )
-
-
-REPO_ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
-
-
-def test_session_sandbox_env_is_active():
-    """conftest.py must export the session-wide cache sandbox BEFORE any
-    test imports bench — the committed evidence file is then unreachable
-    even from tests (and subprocesses) outside this module."""
-    sandbox = os.environ.get("FRL_BENCH_LAST_GOOD_PATH")
-    assert sandbox, "conftest session sandbox env var missing"
-    assert os.path.abspath(sandbox) != os.path.join(
-        REPO_ROOT, "bench_last_good.json"
-    )
-    assert not os.path.abspath(sandbox).startswith(REPO_ROOT + os.sep)
-
-
-def test_committed_cache_is_corroborated(monkeypatch):
-    """The acceptance gate: the committed bench_last_good.json must carry
-    the real protocol-row capture (2256.04) and pass _corroborated against
-    the committed BENCH_TABLE.jsonl, so the tier-1 stale fallback can
-    actually fire with real data after a relay outage."""
-    committed = os.path.join(REPO_ROOT, "bench_last_good.json")
-    rec = json.load(open(committed))
-    # _corroborated derives the table path from LAST_GOOD_PATH's dirname;
-    # point it at the repo READ-ONLY (no write path runs here).
-    monkeypatch.setattr(bench, "LAST_GOOD_PATH", committed)
-    assert bench._corroborated(rec), rec
-    assert rec["value"] != 123.0, "test-fixture value in the committed cache"
-    import re
-
-    assert re.match(r"\d{4}-\d{2}-\d{2}T", rec.get("captured_at", "")), rec
-
-
-def test_bench_table_rows_meet_protocol_schema():
-    """Every committed protocol row must carry the full measurement
-    context: mesh, per-sample FLOPs and MFU (BASELINE.md protocol), plus
-    capture provenance — incomplete rows can't back the stale fallback.
-
-    ``status: "queued"`` rows are one sanctioned exception: they
-    record an experiment awaiting its relay window (BACKLOG R7-1 style)
-    and must carry config/mesh/provenance and a note naming the queued
-    A/B — but NO measurement fields, so a placeholder can never be
-    mistaken for (or corroborate) a measured number.
-
-    ``status: "stale"`` rows are the other (ISSUE 10 satellite): the
-    relay-down fallback's re-emission of the last real capture
-    (bench.py ``_emit_stale_or_error`` stamps them since round 13 —
-    through rounds 5–9 the 2256.04 RN50 row was re-emitted as if
-    fresh). A stale row carries real measured numbers, so it must keep
-    the measured fields AND declare its staleness: ``stale_reason``
-    plus ``captured_at`` provenance of the ORIGINAL capture — a stale
-    row with no capture time is a fabrication vector, refused."""
-    table = os.path.join(REPO_ROOT, "BENCH_TABLE.jsonl")
-    rows = [json.loads(l) for l in open(table).read().splitlines() if l.strip()]
-    assert rows, "committed BENCH_TABLE.jsonl is empty"
-    assert any(
-        row.get("status") not in ("queued", "stale") for row in rows
-    ), (
-        "BENCH_TABLE.jsonl holds only queued/stale placeholders — the "
-        "stale fallback has nothing to corroborate against"
-    )
-    for row in rows:
-        ctx = f"row for {row.get('config')}"
-        if row.get("status") == "queued":
-            for key in ("config", "mesh", "note"):
-                assert key in row, f"queued {ctx} missing {key}"
-            assert isinstance(row["mesh"], dict) and row["mesh"], ctx
-            for key in ("samples_per_sec_per_chip", "step_time_median_s",
-                        "mfu", "model_flops_per_sample"):
-                assert key not in row, (
-                    f"queued {ctx} carries measurement field {key} — "
-                    "placeholders must not wear measured numbers"
-                )
-            assert bench._row_captured_at(row), (
-                f"queued {ctx} has no provenance (stamp the queue date "
-                "in source/captured_at)"
-            )
-            continue
-        if row.get("status") == "stale":
-            assert row.get("stale") is True, (
-                f"stale {ctx} missing the stale flag"
-            )
-            assert row.get("stale_reason"), (
-                f"stale {ctx} does not say WHY it is stale"
-            )
-            assert bench._row_captured_at(row), (
-                f"stale {ctx} has no provenance of the original capture"
-            )
-            continue
-        for key in ("config", "samples_per_sec_per_chip", "mesh",
-                    "model_flops_per_sample", "mfu"):
-            assert key in row, f"{ctx} missing {key}"
-        assert isinstance(row["mesh"], dict) and row["mesh"], ctx
-        assert row["model_flops_per_sample"] > 0, ctx
-        assert 0 < row["mfu"] < 1.0, ctx
-        assert bench._row_captured_at(row), f"{ctx} has no capture provenance"
-        assert "stale" not in row and "stale_reason" not in row, (
-            f"{ctx} carries stale markers without status:'stale' — "
-            "stamp the status so consumers can filter on it"
-        )
-
-
-def test_stale_fallback_tier1_carries_captured_at(
-    sandbox_last_good, monkeypatch, capsys
-):
-    """Simulated outage, tier 1 (cache present): the re-emitted record
-    must carry a real captured_at, not 'unknown time'."""
-    rec = {
-        "metric": "rn50_imagenet_samples_per_sec_per_chip",
-        "value": 2256.04, "unit": "samples/sec/chip", "vs_baseline": 0.9,
-        "captured_at": "2026-07-30T00:00:00Z",
-    }
-    sandbox_last_good.write_text(json.dumps(rec))
-    (sandbox_last_good.parent / "BENCH_TABLE.jsonl").write_text(
-        json.dumps({"config": "imagenet_rn50_ddp",
-                    "samples_per_sec_per_chip": 2256.04}) + "\n"
-    )
-    rc = bench._emit_stale_or_error("relay down (simulated)")
-    assert rc == 1
-    out = [l for l in capsys.readouterr().out.splitlines() if l.startswith("{")]
-    final = json.loads(out[-1])
-    assert final["stale"] is True
-    assert final["status"] == "stale"  # the typed stamp (ISSUE 10)
-    assert final["stale_reason"].startswith("relay down")
-    assert final["captured_at"] == "2026-07-30T00:00:00Z"
-
-
-def test_stale_fallback_tier2_parses_captured_at_from_table_row(
-    sandbox_last_good, monkeypatch, capsys
-):
-    """Simulated outage, tier 2 (no cache — reconstruct from the protocol
-    table): captured_at must be parsed out of the row (explicit field or
-    the source free text), so tier 2 no longer logs 'unknown time'."""
-    assert not sandbox_last_good.exists()
-    (sandbox_last_good.parent / "BENCH_TABLE.jsonl").write_text(
-        json.dumps({
-            "config": "imagenet_rn50_ddp",
-            "samples_per_sec_per_chip": 2256.04, "mfu": 0.3233,
-            "chip": "TPU v5 lite",
-            "source": "evidence log, captured 2026-07-30 ~21:26 UTC",
-        }) + "\n"
-    )
-    rc = bench._emit_stale_or_error("relay down (simulated)")
-    assert rc == 1
-    captured = capsys.readouterr()
-    out = [l for l in captured.out.splitlines() if l.startswith("{")]
-    final = json.loads(out[-1])
-    assert final["stale"] is True
-    assert final["status"] == "stale"  # the typed stamp (ISSUE 10)
-    assert final["value"] == 2256.04
-    assert final["captured_at"] == "2026-07-30T21:26:00Z"
-    assert "unknown time" not in captured.err
-
-
 def test_bench_config_emits_protocol_record():
     perf = bench.bench_config(
         "mnist_mlp",
@@ -223,24 +32,30 @@ def test_bench_config_emits_protocol_record():
     rec = perf["_record"]
     for key in (
         "config", "model", "global_batch_size", "per_chip_batch_size",
-        "mesh", "param_sharding", "precision", "n_chips", "chip",
-        "steps_per_sec", "samples_per_sec_per_chip", "step_time_median_s",
+        "mesh", "param_sharding", "precision", "n_chips", "platform",
+        "chip", "steps_per_sec", "samples_per_sec_per_chip", "step_time_median_s",
         "step_time_p90_s",
     ):
         assert key in rec, f"protocol record missing {key}"
     assert rec["samples_per_sec_per_chip"] > 0
     assert rec["per_chip_batch_size"] * rec["n_chips"] == 64
+    # Every record names the device it ran on; a CPU has no published
+    # peak, so a CPU wall time is never written as a utilization.
+    assert rec["platform"] == "cpu"
+    assert "mfu" not in rec
 
 
 def test_protocol_record_reports_mfu_when_peak_known(monkeypatch):
     """On chips with a known bf16 peak the record must carry model FLOPs +
-    MFU (BASELINE.md protocol). CPU has no honest peak, so inject one —
-    this exercises the same path the TPU jaxpr-fallback count feeds."""
+    MFU (BASELINE.md protocol). CPU has no honest peak, so inject one into
+    the repo's one peaks table — this exercises the path a TPU run takes."""
     import jax
 
-    kind = getattr(jax.devices()[0], "device_kind", "cpu")
-    monkeypatch.setitem(bench.CHIP_PEAK_FLOPS, kind, 1e12)
-    # 2-step windows: the production default of 20 would run 60+ MNIST
+    from frl_distributed_ml_scaffold_tpu.utils import flops
+
+    kind = jax.devices()[0].device_kind
+    monkeypatch.setitem(flops.PEAK_BF16_FLOPS, kind, 1e12)
+    # 2-step windows: the production default of 30 would run 90+ MNIST
     # steps here just to time them — irrelevant to what this test asserts.
     monkeypatch.setenv("FRL_BENCH_WINDOW", "2")
     perf = bench.bench_config(
@@ -254,6 +69,50 @@ def test_protocol_record_reports_mfu_when_peak_known(monkeypatch):
     assert 0 < rec["mfu"] < 1.0
 
 
+class _FakeDevice:
+    def __init__(self, platform, device_kind):
+        self.platform, self.device_kind = platform, device_kind
+
+
+@pytest.mark.parametrize(
+    "platform,kind,want",
+    [
+        ("tpu", "TPU v5 lite", 197e12),
+        ("cpu", "cpu", None),
+        ("tpu", "TPU v99", ValueError),
+        ("gpu", "NVIDIA H100", ValueError),
+    ],
+)
+def test_peak_table_knows_its_devices_and_refuses_others(platform, kind, want):
+    """One peaks table keyed by device_kind: a listed chip gets its
+    published peak, a CPU gets None (callers then write no MFU), and an
+    accelerator that is not listed raises — it is never handed the v5e's
+    peak by default."""
+    from frl_distributed_ml_scaffold_tpu.utils.flops import peak_flops_per_chip
+
+    dev = _FakeDevice(platform, kind)
+    if want is ValueError:
+        with pytest.raises(ValueError, match="no peak FLOP/s known"):
+            peak_flops_per_chip(dev)
+    else:
+        assert peak_flops_per_chip(dev) == want
+
+
+def test_probe_refuses_a_cpu_unless_asked():
+    """bench.py's device query is in-process and requires platform 'tpu';
+    only a caller that asks for whatever device there is gets the CPU."""
+    with pytest.raises(RuntimeError, match="needs a TPU"):
+        bench.probe_backend()
+    assert bench.probe_backend(require_tpu=False) == "cpu"
+
+
+def test_main_without_a_tpu_fails_and_prints_no_number(monkeypatch, capsys):
+    monkeypatch.setattr("sys.argv", ["bench.py"])
+    with pytest.raises(RuntimeError, match="needs a TPU"):
+        bench.main()
+    assert capsys.readouterr().out == ""
+
+
 def test_run_all_writes_jsonl(tmp_path, monkeypatch):
     monkeypatch.setenv("FRL_BENCH_WINDOW", "2")
     monkeypatch.setattr(
@@ -261,38 +120,31 @@ def test_run_all_writes_jsonl(tmp_path, monkeypatch):
         [("mnist_mlp", ["data.global_batch_size=64"], 4)],
     )
     out = tmp_path / "table.jsonl"
-    assert bench.run_all(str(out)) == 0
+    assert bench.run_all(str(out), require_tpu=False) == 0
     lines = [json.loads(l) for l in out.read_text().splitlines()]
     assert len(lines) == 1 and lines[0]["config"] == "mnist_mlp"
 
 
-def test_run_all_preserves_table_when_backend_down(tmp_path, monkeypatch):
-    """A dead relay must never clobber the last good BENCH_TABLE capture
-    with a one-line probe-error record."""
-    import bench
-
+def test_run_all_preserves_table_when_no_tpu(tmp_path):
+    """A run that finds no chip must never overwrite an earlier output
+    file: it raises before the file is opened."""
     table = tmp_path / "BENCH_TABLE.jsonl"
     table.write_text('{"config": "imagenet_rn50_ddp", "good": true}\n')
-    monkeypatch.setattr(
-        bench, "probe_backend", lambda: (None, "backend init timeout")
-    )
-    rc = bench.run_all(str(table))
-    assert rc == 1
+    with pytest.raises(RuntimeError, match="needs a TPU"):
+        bench.run_all(str(table))
     assert table.read_text() == '{"config": "imagenet_rn50_ddp", "good": true}\n'
 
 
 def test_run_all_preserves_table_when_all_configs_fail(tmp_path, monkeypatch):
-    """Backend dies AFTER a successful probe: all rows error out — the
-    previous capture must still survive (staged-tmp-file invariant)."""
-    import bench
-
+    """The device answers but every config raises: the run returns
+    non-zero and the earlier output file survives (staged-tmp-file
+    invariant)."""
     table = tmp_path / "BENCH_TABLE.jsonl"
     table.write_text('{"config": "imagenet_rn50_ddp", "good": true}\n')
-    monkeypatch.setattr(bench, "probe_backend", lambda: ("fake-chip", None))
     def boom(*a, **k):
         raise RuntimeError("backend died mid-run")
     monkeypatch.setattr(bench, "bench_config", boom)
-    rc = bench.run_all(str(table))
+    rc = bench.run_all(str(table), require_tpu=False)
     assert rc == 1
     assert table.read_text() == '{"config": "imagenet_rn50_ddp", "good": true}\n'
     assert not (tmp_path / "BENCH_TABLE.jsonl.tmp").exists()
@@ -300,12 +152,10 @@ def test_run_all_preserves_table_when_all_configs_fail(tmp_path, monkeypatch):
 
 def test_run_all_preserves_table_on_partial_failure(tmp_path, monkeypatch):
     """Replacement is all-or-nothing: one config succeeding while others
-    fail must not drop the failed configs' previous good rows."""
-    import bench
-
+    fail returns non-zero and must not drop the failed configs' earlier
+    rows."""
     table = tmp_path / "BENCH_TABLE.jsonl"
     table.write_text('{"config": "old", "good": true}\n')
-    monkeypatch.setattr(bench, "probe_backend", lambda: ("fake-chip", None))
     calls = []
 
     def flaky(name, overrides, *, steps, warmup):
@@ -316,40 +166,26 @@ def test_run_all_preserves_table_on_partial_failure(tmp_path, monkeypatch):
                             "step_time_median_s": 0.001, "mesh": {}}}
 
     monkeypatch.setattr(bench, "bench_config", flaky)
-    rc = bench.run_all(str(table))
+    rc = bench.run_all(str(table), require_tpu=False)
     assert rc == 1
     assert table.read_text() == '{"config": "old", "good": true}\n'
     assert not (tmp_path / "BENCH_TABLE.jsonl.tmp").exists()
 
 
-def test_main_falls_through_candidate_ladder(monkeypatch, capsys):
-    """If the headline candidate's child fails, main() must fall through
-    to the next candidate and still print exactly one final JSON line."""
-    import json as _json
-
-    import bench
-
-    monkeypatch.setattr(bench, "probe_backend", lambda: ("fake-chip", None))
-
+def test_main_fails_when_the_headline_benchmark_fails(monkeypatch, capsys):
+    """No ladder: when the headline benchmark raises, main() must not
+    report some other model and exit 0 — the failure propagates (a
+    non-zero exit) and no result line is printed."""
+    monkeypatch.setattr(bench, "probe_backend", lambda **kw: "fake-chip")
     calls = []
 
-    def fake_run_bounded(argv, timeout_s):
-        spec = _json.loads(argv[argv.index("--child") + 1])
-        calls.append(spec["config"])
-        if spec["config"] == "imagenet_rn50_ddp":
-            return 1, "", "simulated OOM"  # child failed
-        result = {"metric": spec["metric"], "value": 123.0,
-                  "unit": "samples/sec/chip", "vs_baseline": 0.5}
-        return 0, "RESULT " + _json.dumps(result) + "\n", ""
+    def boom(name, overrides, *, steps, warmup):
+        calls.append(name)
+        raise RuntimeError("simulated OOM")
 
-    monkeypatch.setattr(bench, "_run_bounded", fake_run_bounded)
+    monkeypatch.setattr(bench, "bench_config", boom)
     monkeypatch.setattr("sys.argv", ["bench.py"])
-    rc = bench.main()
-    assert rc == 0
-    assert calls == ["imagenet_rn50_ddp", "mnist_mlp"]
-    final = [l for l in capsys.readouterr().out.splitlines()
-             if l.startswith("{")]
-    assert len(final) == 1
-    rec = _json.loads(final[0])
-    assert rec["metric"] == "mnist_mlp_samples_per_sec_per_chip"
-    assert rec["value"] == 123.0
+    with pytest.raises(RuntimeError, match="simulated OOM"):
+        bench.main()
+    assert calls == ["imagenet_rn50_ddp"]
+    assert capsys.readouterr().out == ""

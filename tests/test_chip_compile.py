@@ -1,0 +1,199 @@
+"""Real-size compiles for a DESCRIBED TPU v5e (no chip attached).
+
+The Pallas interpreter accepts kernels Mosaic refuses: all four single-token
+decode kernels passed every interpret-mode test and failed their first real
+compile (a batched mat-vec whose left operand has no free dimension). The
+TPU's compiler is installed in the sandbox and compiles for a chip that is
+described, not attached — about a second per kernel — so the kernels of the
+train and serve paths are compiled here at GPT-2-medium / RN50 widths on
+every test run. A compile that passes is NOT a chip run: nothing executes,
+so this says nothing about results or times (chip_smoke.py does).
+
+The topology is described inside a module-scoped fixture, never at import:
+only one process may hold the TPU library, pytest-xdist workers each import
+every test file, and a module that decided at import whether its tests
+exist would give the workers different collections. Keep these tests in
+this ONE file (a second file could land on another worker, whose fixture
+would then skip).
+"""
+
+from __future__ import annotations
+
+import importlib
+
+import jax
+import jax.numpy as jnp
+import pytest
+from jax.sharding import SingleDeviceSharding
+
+from frl_distributed_ml_scaffold_tpu.ops.flash_attention import flash_attention
+from frl_distributed_ml_scaffold_tpu.ops.fused_adamw import fused_adamw
+from frl_distributed_ml_scaffold_tpu.ops.fused_bn import fused_bn_train
+
+# ops/__init__ re-exports the function under the module's own name.
+da = importlib.import_module(
+    "frl_distributed_ml_scaffold_tpu.ops.decode_attention"
+)
+
+# GPT-2-medium: 16 heads of 64, context 1024; the bench operating point's
+# batch of 8; pool blocks of 16 and 64; a k=3 speculative verify tile.
+B, H, D, S, T_VERIFY = 8, 16, 64, 1024, 4
+
+
+@pytest.fixture(scope="module")
+def one_chip():
+    """A sharding on one described v5e chip. The persistent compile cache is
+    off while this module runs: an entry compiled for a described chip is
+    written but cannot be read back without one, and the next run would
+    warn and recompile."""
+    from jax.experimental import topologies
+    from jax.experimental.compilation_cache import compilation_cache
+
+    try:
+        topo = topologies.get_topology_desc(
+            platform="tpu", topology_name="v5e:2x2"
+        )
+    except Exception as e:
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+    was_on = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    yield SingleDeviceSharding(topo.devices[0])
+    jax.config.update("jax_enable_compilation_cache", was_on)
+    compilation_cache.reset_cache()
+
+
+def _compile_has_kernel(one_chip, fn, *shapes) -> None:
+    """Compile ``fn`` for the described chip — raises what the chip's
+    compiler would raise — and require the Pallas kernel in the program."""
+    args = [
+        jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+        for shape, dtype in shapes
+    ]
+    compiled = jax.jit(fn).lower(*args).compile()
+    assert "tpu_custom_call" in compiled.as_text()
+
+
+BF16, F32, I8, I32 = jnp.bfloat16, jnp.float32, jnp.int8, jnp.int32
+QKV = ((B, S, H, D), BF16)
+
+
+def _flash(q, k, v):
+    return flash_attention(q, k, v, causal=True, interpret=False)
+
+
+def test_flash_attention_forward(one_chip):
+    _compile_has_kernel(one_chip, _flash, QKV, QKV, QKV)
+
+
+def test_flash_attention_backward(one_chip):
+    grad = jax.grad(
+        lambda q, k, v: _flash(q, k, v).astype(F32).sum(), argnums=(0, 1, 2)
+    )
+    _compile_has_kernel(one_chip, grad, QKV, QKV, QKV)
+
+
+@pytest.mark.parametrize(
+    "seq,dtype",
+    [(256, BF16), (1024, BF16), (1024, F32)],
+    ids=["s256-bf16", "s1024-bf16", "s1024-fp32"],
+)
+def test_decode_attention(one_chip, seq, dtype):
+    """Single-token decode over a contiguous cache — generate()'s path.
+    fp32 is the serving benchmark's policy: at 512-position chunks its K
+    and V overflow VMEM, so the chunk is sized in bytes."""
+    kv = ((B, seq, H, D), dtype)
+    _compile_has_kernel(
+        one_chip,
+        lambda q, k, v, n: da.decode_attention(
+            q, k, v, n, impl="flash", interpret=False),
+        ((B, H, D), dtype), kv, kv, ((B,), I32),
+    )
+
+
+def test_decode_attention_int8_cache(one_chip):
+    kv, sc = ((B, S, H, D), I8), ((B, S, H), BF16)
+    _compile_has_kernel(
+        one_chip,
+        lambda q, k, v, n, ks, vs: da.decode_attention(
+            q, k, v, n, k_scale=ks, v_scale=vs, impl="flash",
+            interpret=False),
+        ((B, H, D), BF16), kv, kv, ((B,), I32), sc, sc,
+    )
+
+
+def _pool_shapes(block: int, dtype):
+    n_blocks, table = B * S // block + 1, S // block
+    return (
+        ((n_blocks, block, H, D), dtype),
+        ((B,), I32),
+        ((B, table), I32),
+        ((n_blocks, block, H), BF16),
+    )
+
+
+@pytest.mark.parametrize("block", [16, 64])
+def test_paged_decode_attention(one_chip, block):
+    """Single-token decode over the block pool — the serving engine's path."""
+    pool, lens, tables, _ = _pool_shapes(block, BF16)
+    _compile_has_kernel(
+        one_chip,
+        lambda q, k, v, n, t: da.paged_decode_attention(
+            q, k, v, n, t, impl="flash", interpret=False),
+        ((B, H, D), BF16), pool, pool, lens, tables,
+    )
+
+
+@pytest.mark.parametrize("block", [16, 64])
+def test_paged_decode_attention_int8_pool(one_chip, block):
+    pool, lens, tables, scales = _pool_shapes(block, I8)
+    _compile_has_kernel(
+        one_chip,
+        lambda q, k, v, n, t, ks, vs: da.paged_decode_attention(
+            q, k, v, n, t, k_scale=ks, v_scale=vs, impl="flash",
+            interpret=False),
+        ((B, H, D), BF16), pool, pool, lens, tables, scales, scales,
+    )
+
+
+@pytest.mark.parametrize("block", [16, 64])
+def test_paged_verify_attention(one_chip, block):
+    """The speculative verify tile (k=3 drafts + the last accepted token)."""
+    pool, lens, tables, _ = _pool_shapes(block, BF16)
+    _compile_has_kernel(
+        one_chip,
+        lambda q, k, v, n, t: da.paged_verify_attention(
+            q, k, v, n, t, impl="flash", interpret=False),
+        ((B, T_VERIFY, H, D), BF16), pool, pool, lens, tables,
+    )
+
+
+@pytest.mark.parametrize(
+    "shape",
+    [(32, 56, 56, 256), (32, 112, 112, 64)],
+    ids=["rn50-56x56x256", "rn50-stem"],
+)
+def test_fused_bn_backward(one_chip, shape):
+    """RN50's widest-traffic BatchNorm and its stem, backward (two Pallas
+    passes behind a custom VJP)."""
+    c = shape[-1]
+    grad = jax.grad(
+        lambda x, scale, bias: fused_bn_train(  # -> (y, mean, var)
+            x, scale, bias, interpret=False)[0].astype(F32).sum(),
+        argnums=(0, 1, 2),
+    )
+    _compile_has_kernel(
+        one_chip, grad, (shape, BF16), ((c,), F32), ((c,), F32)
+    )
+
+
+def test_fused_adamw(one_chip):
+    """One fused pass over a GPT-2-medium MLP weight."""
+    tx = fused_adamw(1e-3, weight_decay=0.01, interpret=False)
+    w = ((1024, 4096), F32)
+
+    def apply(g, mu, nu, p):
+        state = tx.init({"w": p})._replace(mu={"w": mu}, nu={"w": nu})
+        return tx.fused_apply({"w": g}, state, {"w": p})
+
+    _compile_has_kernel(one_chip, apply, w, w, w, w)

@@ -78,10 +78,10 @@ def test_local_batch_size_single_process():
 
 
 def _shmap(fn, mesh, in_specs, out_specs):
-    from frl_distributed_ml_scaffold_tpu.dist.mesh import shard_map_compat
+    from frl_distributed_ml_scaffold_tpu.dist.mesh import shard_map_unchecked
 
     return jax.jit(
-        shard_map_compat(fn, mesh=mesh, in_specs=in_specs, out_specs=out_specs)
+        shard_map_unchecked(fn, mesh=mesh, in_specs=in_specs, out_specs=out_specs)
     )
 
 

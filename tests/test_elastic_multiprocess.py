@@ -36,6 +36,10 @@ def test_multiprocess_kill_and_resume(tmp_path):
     # faulted child on host 0, the peer-loss exit on host 1.
     for out in outputs:
         assert "elastic: run completed after 1 restart(s)" in out, out[-3000:]
+        # One process per chip: the supervising parent imported jax (config
+        # only) but never brought a backend up — on the chip it would
+        # otherwise hold the device its training child needs.
+        assert "SUPERVISOR_BACKENDS=0" in out, out[-3000:]
     assert "fault injection: hard-exit" in outputs[0]
     # The survivor died to the coordination service noticing the dead peer,
     # not to the fault hook (it was never armed there).

@@ -101,7 +101,7 @@ def test_fallback_on_untileable_shapes():
 
 def test_length_adaptive_block_ladder():
     """Pin the auto block selection the on-chip sweep tuned
-    (evidence_r4/flash_sweep.log → BASELINE.md long-context table):
+    (2026-07-30 → BASELINE.md long-context table):
     512 below 16k, 1024 from 16k up — at 16k/32k/64k the 1024×1024
     blocks measured +21%/+37%/+39% over 512×512 on v5e. A regression
     here silently costs a third of long-context throughput."""

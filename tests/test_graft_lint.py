@@ -40,7 +40,7 @@ from frl_distributed_ml_scaffold_tpu.analysis.reshard import (
 from frl_distributed_ml_scaffold_tpu.dist.mesh import (
     build_mesh,
     mesh_context,
-    shard_map_compat,
+    shard_map_unchecked,
 )
 from frl_distributed_ml_scaffold_tpu.config.schema import MeshConfig
 
@@ -68,7 +68,7 @@ def test_census_counts_collectives_with_axes_and_scan_trips():
         y, _ = jax.lax.scan(body, x, None, length=3)
         return y
 
-    f = shard_map_compat(
+    f = shard_map_unchecked(
         inner, mesh=env.mesh, in_specs=P("data"), out_specs=P("data")
     )
     with mesh_context(env):
@@ -98,7 +98,7 @@ def test_census_diff_reports_added_and_removed():
         return jax.lax.psum(jax.lax.psum(x, "data"), "data")
 
     def mk(fn):
-        f = shard_map_compat(
+        f = shard_map_unchecked(
             fn, mesh=env.mesh, in_specs=P("data"), out_specs=P()
         )
         with mesh_context(env):
@@ -126,7 +126,7 @@ def test_census_diff_sees_scan_trip_count_drift():
 
             return jax.lax.scan(body, x, None, length=length)[0]
 
-        f = shard_map_compat(
+        f = shard_map_unchecked(
             inner, mesh=env.mesh, in_specs=P("data"), out_specs=P("data")
         )
         with mesh_context(env):
@@ -204,7 +204,7 @@ def test_monolithic_gather_detector_on_synthetic_gathers():
     def gather(x):
         return jax.lax.all_gather(x, "fsdp", tiled=True)
 
-    f = shard_map_compat(
+    f = shard_map_unchecked(
         gather, mesh=env.mesh, in_specs=P("fsdp"), out_specs=P()
     )
     with mesh_context(env):
@@ -332,7 +332,7 @@ def test_collective_bytes_pin_positive_and_negative():
         s = jax.lax.ppermute(s, "data", perm)
         return q.astype(jnp.float32) * s
 
-    f = shard_map_compat(
+    f = shard_map_unchecked(
         inner, mesh=env.mesh, in_specs=P("data"), out_specs=P("data")
     )
     with mesh_context(env):
@@ -1242,7 +1242,7 @@ def test_stage_program_lint_clean_and_mutations_trip(monkeypatch):
 
         y = real(module, policy, params_c, x, rng, train)
         env = current_mesh_env()
-        return shard_map_compat(
+        return shard_map_unchecked(
             lambda t: jax.lax.psum(t, "pipe"),
             mesh=env.mesh, in_specs=P(), out_specs=P(),
         )(y)
@@ -1431,8 +1431,8 @@ def test_perf_ledger_check_matches_committed_baseline(tmp_path):
     """ISSUE 8 acceptance gate: `python tools/perf_ledger.py --check`
     round-trips green against the committed PERF_LEDGER.json — the
     analytic census/FLOPs of the baseline recipes are bit-deterministic
-    on the CPU sim, so this is the census-vs-measured regression gate
-    that substitutes for the dead bench relay."""
+    on the CPU sim, so this is the census regression gate (counts that
+    repeat exactly; never a speed claim)."""
     repo = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
     env = dict(os.environ)
     env["JAX_PLATFORMS"] = "cpu"
@@ -1454,7 +1454,9 @@ def test_perf_ledger_check_matches_committed_baseline(tmp_path):
     assert tp["collectives"]["ppermute"]["total_bytes"] > 0  # the rings
     assert tp["flops_per_step"] > 0
     assert tp["measured"]["step_time_p50_s"] > 0
-    assert tp["attribution"]["mfu"] > 0
+    # A CPU-sim wall time is provenance, never a utilization: the ledger
+    # derives no MFU / achieved FLOP/s from it.
+    assert "attribution" not in tp
     assert rows["serving:decode_step"]["measured"]["tpot_p50_s"] > 0
 
 

@@ -26,7 +26,7 @@ from frl_distributed_ml_scaffold_tpu.config import apply_overrides, get_config
 from frl_distributed_ml_scaffold_tpu.dist.mesh import (
     build_mesh,
     mesh_context,
-    shard_map_compat,
+    shard_map_unchecked,
 )
 from frl_distributed_ml_scaffold_tpu.trainer.loop import Trainer
 
@@ -98,14 +98,14 @@ def _ring_pair(lowp, grad=False):
     w2 = jnp.asarray(rng.normal(size=(24, 16)), jnp.float32) * 0.2
 
     def fwd(x, w1, w2):
-        agm = shard_map_compat(
+        agm = shard_map_unchecked(
             partial(all_gather_matmul, axis_name="model", chunk_axis=1,
                     return_full=False, precision=None, lowp=lowp),
             mesh=env.mesh,
             in_specs=(P(None, "model", None), P(None, "model")),
             out_specs=P(None, None, "model"),
         )
-        mrs = shard_map_compat(
+        mrs = shard_map_unchecked(
             partial(matmul_reduce_scatter, axis_name="model", chunk_axis=1,
                     precision=None, lowp=lowp),
             mesh=env.mesh,

@@ -188,20 +188,6 @@ def test_pp_moe_aux_loss_batch_invariant():
     assert 0.5 < ratio < 2.0, f"aux scales with microbatch count: {ratio}"
 
 
-@pytest.mark.xfail(
-    strict=True,
-    reason="pipeline(stage-vmap spmd_axis_name='pipe') x sequence-parallel "
-    "shard_map produces a DETERMINISTIC wrong forward in this jaxlib build: "
-    "identical ~0.18-0.21 max diff across meshes (pipe2xdata2xseq2, 4-dev), "
-    "microbatch counts (2/4), single-CPU taskset, and Pallas-interpreter "
-    "local attention, while pp x dense/flash and plain ring/ulysses are all "
-    "exact — NOT a tolerance class (do not re-tolerance; see CHANGES.md "
-    "PR 3 / memory repo-test-flakiness). Tracked in BACKLOG R8-2; "
-    "strict=True so a fixed jaxlib un-xfails this loudly. RESOLVED on the "
-    "MPMD backend (ISSUE 14): test_pp_composes_with_ring_attention_mpmd "
-    "passes the same composition through per-stage programs with no "
-    "stage vmap — pp x SP users should run model.pipeline_impl=mpmd.",
-)
 def test_pp_composes_with_ring_attention():
     """Round-1 exclusion, lifted: ring attention's shard_map (ppermute over
     ``seq``) nests inside the pipeline's stage vmap via spmd_axis_name.
@@ -272,16 +258,6 @@ def test_pp_composes_with_remat(tmp_path):
         )
 
 
-@pytest.mark.xfail(
-    strict=True,
-    reason="same deterministic pipeline x sequence-parallel divergence as "
-    "test_pp_composes_with_ring_attention (the composition, not the "
-    "attention impl, is what breaks — Ulysses' all_to_all shows the "
-    "identical diff). Tracked in BACKLOG R8-2; strict=True so a fixed "
-    "jaxlib un-xfails this loudly. RESOLVED on the MPMD backend (ISSUE "
-    "14): test_pp_composes_with_ulysses_attention_mpmd passes the same "
-    "composition through per-stage programs with no stage vmap.",
-)
 def test_pp_composes_with_ulysses_attention():
     """Ulysses' all_to_all shard_map also batches over the stage vmap."""
     from frl_distributed_ml_scaffold_tpu.config.schema import MeshConfig
@@ -305,11 +281,10 @@ def test_pp_composes_with_ulysses_attention():
 
 
 def test_pp_composes_with_ring_attention_mpmd(tmp_path):
-    """BACKLOG R8-2, resolved on the MPMD path (ISSUE 14): the per-stage
+    """The MPMD twin of test_pp_composes_with_ring_attention: the per-stage
     programs have no vmap(spmd_axis_name), so ring attention's shard_map
     (ppermute over ``seq``) opens directly inside each stage program —
-    the pipe2 x data2 x seq2 composition that deterministically diverges
-    under the SPMD stage vmap (the strict-xfail twin above) must PASS
+    the pipe2 x data2 x seq2 composition must pass
     here, forward AND through two finite training steps."""
     import dataclasses as _dc
 
@@ -348,8 +323,7 @@ def test_pp_composes_with_ring_attention_mpmd(tmp_path):
 
 def test_pp_composes_with_ulysses_attention_mpmd(tmp_path):
     """Ulysses' all_to_all shard_map through the MPMD per-stage programs:
-    the second half of the R8-2 pair, passing where the stage-vmap twin
-    strict-xfails."""
+    the MPMD twin of test_pp_composes_with_ulysses_attention."""
     import dataclasses as _dc
 
     trainer = make_gpt_trainer(

@@ -1098,8 +1098,9 @@ def test_serve_bench_runs_and_emits_schema_valid_row(capsys):
     """tools/serve_bench.py end-to-end on CPU sim: continuous batching
     completes every request (more requests than slots, so retired slots
     are refilled and the refilled requests finish) and the emitted row
-    meets the BENCH_TABLE measured-row schema (the test_bench.py
-    contract: config + mesh + per-sample FLOPs + MFU + provenance)."""
+    meets the measured-row schema (config + mesh + per-sample FLOPs +
+    device + provenance; MFU only where the device has a published
+    peak)."""
     import json
 
     sys_path_mod = __import__("sys")
@@ -1129,11 +1130,13 @@ def test_serve_bench_runs_and_emits_schema_valid_row(capsys):
     for line in lines:
         row = json.loads(line)
         for key in ("config", "samples_per_sec_per_chip", "mesh",
-                    "model_flops_per_sample", "mfu"):
+                    "model_flops_per_sample", "chip"):
             assert key in row, f"row missing {key}"
         assert isinstance(row["mesh"], dict) and row["mesh"]
         assert row["model_flops_per_sample"] > 0
-        assert 0 < row["mfu"] < 1.0
+        # A CPU has no published peak: the row names its device and
+        # carries NO mfu (never a "tiny-but-positive" placeholder).
+        assert row["chip"] == "cpu" and "mfu" not in row
         assert re.match(r"\d{4}-\d{2}-\d{2}T", row["captured_at"])
         s = row["serving"]
         assert s["engine_stats"]["completed"] == 5
@@ -1589,13 +1592,16 @@ def test_handoff_splice_reshard_free_compiled_hlo(gpt):
 
 
 def test_serve_bench_disagg_arm_tail_isolation_pin(capsys):
-    """THE ISSUE 12 acceptance pin: the serve_bench ``*_disagg`` arm's
-    burst A/B holds decode TPOT p99 under a prefill burst at <= 0.5x
-    the colocated arm's (>= 2x tail isolation — structurally ~(P+d) vs
-    ~(k·P+d) with k free slots churning budget-1 prefills, so the
-    margin is architectural, not a timing accident), with the handoff
-    a zero-copy re-own (0 transfer bytes) and the burst genuinely
-    deferred."""
+    """THE ISSUE 12 acceptance pin, in COUNTS: under the serve_bench
+    ``*_disagg`` arm's burst A/B the colocated engine runs several
+    prefills between two decode ticks of a running request (it fills
+    every free slot before the next tick — the inter-token gap is
+    ~(k·P+d)), the scheduler at most ``prefill_max_per_tick`` = 1
+    (~(P+d)); the handoff is a zero-copy re-own (0 transfer bytes) and
+    the burst is genuinely deferred. The >= 2x decode-gap TAIL this
+    structure buys is a wall-clock ratio: a CPU wall time is not a speed
+    (ROADMAP aim 1), so that claim is a chip measurement for the
+    benchmark, not a tier-1 assertion."""
     import json
 
     sys_path_mod = __import__("sys")
@@ -1626,12 +1632,10 @@ def test_serve_bench_disagg_arm_tail_isolation_pin(capsys):
     assert s["disaggregated"] is True
     assert s["engine_stats"]["handoffs"] == s["requests"]
     d = s["disagg"]
-    # Tail isolation: disagg p99 <= 0.5x colocated p99.
-    assert d["tail_isolation_x"] >= 2.0, d
-    assert (
-        d["disagg_decode_tpot_p99_ms"]
-        <= 0.5 * d["colocated_decode_tpot_p99_ms"]
-    ), d
+    # Prefill work admitted between two decode ticks, per arm — read off
+    # each engine's own ordered phase record.
+    assert d["disagg_max_prefills_between_decode_ticks"] <= 1, d
+    assert d["colocated_max_prefills_between_decode_ticks"] >= 2, d
     # The handoff is a block-table splice: zero cache-copy bytes moved
     # (shared pool: ownership re-owns; the census/HLO pins live in
     # test_graft_lint.py and test_handoff_splice_reshard_free above).

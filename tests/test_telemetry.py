@@ -572,12 +572,18 @@ def test_step_timer_summary_reports_tail_percentiles():
 @pytest.fixture(scope="module")
 def telemetry_run(tmp_path_factory):
     """One telemetry-enabled trainer run shared by the trainer-tier tests
-    (>= 2 post-warmup log windows so MFU and the step histogram fill)."""
+    (>= 2 post-warmup log windows so MFU and the step histogram fill).
+    A CPU has no published peak, so a real CPU run carries NO mfu
+    (tests/test_chip_smoke.py pins that); a peak is injected into the
+    repo's one peaks table here so the path a TPU run takes is exercised."""
+    import jax
+
     from frl_distributed_ml_scaffold_tpu.config import (
         apply_overrides,
         get_config,
     )
     from frl_distributed_ml_scaffold_tpu.trainer.loop import Trainer
+    from frl_distributed_ml_scaffold_tpu.utils import flops
 
     workdir = tmp_path_factory.mktemp("telemetry_run")
     cfg = apply_overrides(
@@ -591,7 +597,12 @@ def telemetry_run(tmp_path_factory):
             f"workdir={workdir}",
         ],
     )
-    _, last = Trainer(cfg).fit()
+    kind = jax.devices()[0].device_kind
+    flops.PEAK_BF16_FLOPS[kind] = 1e12
+    try:
+        _, last = Trainer(cfg).fit()
+    finally:
+        del flops.PEAK_BF16_FLOPS[kind]
     return os.path.join(workdir, cfg.name), last
 
 

@@ -132,11 +132,6 @@ def _setup_backend(args) -> None:
             ).strip()
 
 
-#: v5e bf16 peak — the MFU convention every BENCH_TABLE row uses; on CPU
-#: sim the resulting mfu is a nominal tiny-but-positive placeholder.
-_PEAK_FLOPS = 197e12
-
-
 def _build(preset: str):
     import jax
 
@@ -572,6 +567,26 @@ def _decode_gaps_ms(done, dec_ids):
     return np.asarray(gaps, np.float64)
 
 
+def _max_prefills_between_decode_ticks(eng) -> int:
+    """The most prefills that ran between two consecutive decode ticks,
+    read off the engine's own ordered phase record (its Timeline) — the
+    COUNT behind the decode-gap tail: colocated admission fills every
+    free slot before the next tick (k prefills in one gap), the
+    scheduler starts at most ``prefill_max_per_tick``. Unlike the gap
+    itself it does not depend on the host's clock."""
+    timeline = getattr(eng, "decode", eng).timeline
+    worst = run = 0
+    ticking = False  # prefills before the first tick delay no token
+    for event in timeline.tail(len(timeline)):
+        if event["name"] == "decode":
+            # A run counts once a tick CLOSES it: prefills after the last
+            # tick sit in no running request's gap.
+            worst, run, ticking = max(worst, run), 0, True
+        elif event["name"] == "prefill" and ticking:
+            run += 1
+    return worst
+
+
 def _disagg_pass(model, run_params, args, kv_kwargs) -> dict:
     """The disaggregation headline, measured (ISSUE 12 acceptance):
     serve the mixed burst workload through the colocated paged engine
@@ -670,6 +685,10 @@ def _disagg_pass(model, run_params, args, kv_kwargs) -> dict:
         ),
         "disagg_decode_tpot_p99_ms": round(dis_p99, 3),
         "tail_isolation_x": round(colo_p99 / max(dis_p99, 1e-9), 4),
+        "colocated_max_prefills_between_decode_ticks":
+            _max_prefills_between_decode_ticks(eng_c),
+        "disagg_max_prefills_between_decode_ticks":
+            _max_prefills_between_decode_ticks(eng_d),
         "handoffs": int(eng_d.stats["handoffs"]),
         "handoff_p50_ms": round(handoff_h.quantile(0.50) * 1e3, 3),
         "prefill_deferred": int(eng_d.stats["prefill_deferred"]),
@@ -728,6 +747,7 @@ def run_arm(model, params, arm: str, args, flops_per_token: int) -> dict:
         shard_params_for_serving,
     )
     from frl_distributed_ml_scaffold_tpu.serving import ServingEngine
+    from frl_distributed_ml_scaffold_tpu.utils.flops import peak_flops_per_chip
 
     parts = arm.split("_")
     suffixes = parts[2:]
@@ -924,6 +944,7 @@ def run_arm(model, params, arm: str, args, flops_per_token: int) -> dict:
     tok_per_sec = n_tokens / wall
     chip = jax.devices()[0].device_kind
     per_chip = tok_per_sec / n_chips
+    peak = peak_flops_per_chip()
     row = {
         "config": f"serve_bench_{args.preset}",
         "model": "gpt",
@@ -940,7 +961,8 @@ def run_arm(model, params, arm: str, args, flops_per_token: int) -> dict:
         "samples_per_sec_per_chip": round(per_chip, 3),
         "step_time_median_s": round(float(np.median(lat)), 6),
         "model_flops_per_sample": int(flops_per_token),
-        "mfu": max(1e-9, flops_per_token * per_chip / _PEAK_FLOPS),
+        # Absent on the CPU (no published peak — utils/flops.py).
+        **({"mfu": flops_per_token * per_chip / peak} if peak else {}),
         "serving": {
             "arm": arm,
             "decode_attention": impl,
