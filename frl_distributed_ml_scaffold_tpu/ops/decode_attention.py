@@ -607,6 +607,7 @@ def _flash_decode(q, k, v, kv_len, *, block_k, interpret):
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct(q.shape, q.dtype),
         interpret=interpret,
+        name="attn_decode",
     )(kv_len, q, k, v)
 
 
@@ -637,6 +638,7 @@ def _flash_decode_quant(q, k, k_scale, v, v_scale, kv_len, *, block_k,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct(q.shape, q.dtype),
         interpret=interpret,
+        name="attn_decode_quant",
     )(kv_len, q, k, k_scale, v, v_scale)
 
 
@@ -856,12 +858,14 @@ def _local_paged_decode(q, k_pool, v_pool, kv_len, tables, *, impl,
     if quant:
         o = _flash_paged_verify(
             qt, k_pool, v_pool, lens, tbl, interpret=interpret,
+            name="attn_paged_decode",
             k_scale=k_scale.astype(jnp.float32),
             v_scale=v_scale.astype(jnp.float32),
         )
     else:
         o = _flash_paged_verify(
-            qt, k_pool, v_pool, lens, tbl, interpret=interpret
+            qt, k_pool, v_pool, lens, tbl, interpret=interpret,
+            name="attn_paged_decode",
         )
     return o[:, 0]
 
@@ -950,11 +954,15 @@ def paged_decode_attention(
 
 
 def _flash_paged_verify(q, k_pool, v_pool, kv_len, tables, *, interpret,
-                        k_scale=None, v_scale=None):
+                        name, k_scale=None, v_scale=None):
     """q ``[B, T, H, D]``, pools ``[N, bs, H, D]`` (+ optional
     ``[N, bs, H]`` fp32 scales), tables ``[B, M]`` int32 ->
     ``[B, T, H, D]``. Grid is (rows, logical blocks); block_k == the
-    pool's block size; the scratch accumulators carry the T dim."""
+    pool's block size; the scratch accumulators carry the T dim. The
+    kernel serves a decode step (T=1) and a verify tile: its caller
+    names it (``attn_paged_decode`` / ``attn_paged_verify``; the
+    quantized pool's kernel adds ``_quant``), and that is what a device
+    trace calls it."""
     b, t, h, d = q.shape
     _, bs, _, _ = k_pool.shape
     n_k = tables.shape[1]
@@ -981,6 +989,7 @@ def _flash_paged_verify(q, k_pool, v_pool, kv_len, tables, *, interpret,
             grid_spec=grid_spec,
             out_shape=jax.ShapeDtypeStruct(q.shape, q.dtype),
             interpret=interpret,
+            name=name,
         )(kv_len, tables, q, k_pool, v_pool)
     sc_spec = pl.BlockSpec((1, bs, h), _paged_scale_index_map(bs))
     grid_spec = pltpu.PrefetchScalarGridSpec(
@@ -998,6 +1007,7 @@ def _flash_paged_verify(q, k_pool, v_pool, kv_len, tables, *, interpret,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct(q.shape, q.dtype),
         interpret=interpret,
+        name=name + "_quant",
     )(kv_len, tables, q, k_pool, k_scale, v_pool, v_scale)
 
 
@@ -1040,11 +1050,13 @@ def _local_paged_verify(q, k_pool, v_pool, kv_len, tables, *, impl,
     if quant:
         return _flash_paged_verify(
             q, k_pool, v_pool, lens, tbl, interpret=interpret,
+            name="attn_paged_verify",
             k_scale=k_scale.astype(jnp.float32),
             v_scale=v_scale.astype(jnp.float32),
         )
     return _flash_paged_verify(
-        q, k_pool, v_pool, lens, tbl, interpret=interpret
+        q, k_pool, v_pool, lens, tbl, interpret=interpret,
+        name="attn_paged_verify",
     )
 
 

@@ -178,6 +178,7 @@ def _fwd(q, k, v, *, causal, block_q, block_k, interpret):
             pltpu.VMEM((block_q, d), jnp.float32),   # output accumulator
         ],
         interpret=interpret,
+        name="attn_flash_fwd",
     )(q, k, v)
     return o, lse
 
@@ -278,6 +279,7 @@ def _bwd(causal, block_q, block_k, interpret, residuals, dout):
             pltpu.VMEM((block_q, 1), jnp.float32),  # delta row term
         ],
         interpret=interpret,
+        name="attn_flash_dq",
     )(q, k, v, o, dout, lse)
 
     # dk/dv: outer grid over K blocks, inner over Q/dO blocks.
@@ -300,6 +302,7 @@ def _bwd(causal, block_q, block_k, interpret, residuals, dout):
             pltpu.VMEM((block_k, d), jnp.float32),  # dv accumulator
         ],
         interpret=interpret,
+        name="attn_flash_dkv",
     )(q, k, v, o, dout, lse)
     return dq, dk, dv
 

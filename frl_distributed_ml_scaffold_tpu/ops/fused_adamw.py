@@ -92,6 +92,7 @@ def _update_leaf(g, m, v, p, lr, bc1, bc2, *, b1, b2, eps, wd, interpret):
         out_specs=[block_spec] * 3,
         out_shape=[out2d] * 3,
         interpret=interpret,
+        name="fused_adamw",
     )(to2(lr), to2(bc1), to2(bc2), prep(g), prep(m), prep(v), prep(p))
 
     unpad = lambda x: x.reshape(-1)[:n].reshape(shape).astype(dtype)

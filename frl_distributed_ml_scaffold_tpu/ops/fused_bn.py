@@ -182,6 +182,7 @@ def _kernel_sums(x2d, dy2d, mean, var, eps, interpret):
             pltpu.VMEM((1, c_pad), jnp.float32),
         ],
         interpret=interpret,
+        name="bn_bwd_reduce",
     )(
         _pad_2d(x2d, rows_pad, c_pad),
         _pad_2d(dy2d, rows_pad, c_pad),
@@ -210,6 +211,7 @@ def _kernel_dx(x2d, dy2d, scale, mean, var, dgamma, dbeta, eps, m, interpret):
         out_specs=blk,
         out_shape=jax.ShapeDtypeStruct((rows_pad, c_pad), x2d.dtype),
         interpret=interpret,
+        name="bn_bwd_dx",
     )(
         _pad_2d(x2d, rows_pad, c_pad),
         _pad_2d(dy2d, rows_pad, c_pad),

@@ -54,6 +54,7 @@ from __future__ import annotations
 
 import collections
 import dataclasses
+import functools
 import time
 from typing import Any
 
@@ -143,11 +144,11 @@ def make_prefill_program(model, sample_kw: dict):
     change here (donation, sampling) lands on both or neither."""
     kw = dict(sample_kw)
 
-    def fn(params, prompt, lengths, rng):
+    def serve_prefill(params, prompt, lengths, rng):
         logits, cache = _prefill(model, params, prompt, lengths)
         return _sample(logits, rng, **kw), cache
 
-    return jax.jit(fn)
+    return jax.jit(serve_prefill)
 
 
 def make_seeded_prefill_program(model, sample_kw: dict):
@@ -155,11 +156,11 @@ def make_seeded_prefill_program(model, sample_kw: dict):
     cache (donated — the seed is single-use by construction)."""
     kw = dict(sample_kw)
 
-    def fn(params, prompt, lengths, rng, cache0):
+    def serve_prefill_seeded(params, prompt, lengths, rng, cache0):
         logits, cache = _prefill(model, params, prompt, lengths, cache=cache0)
         return _sample(logits, rng, **kw), cache
 
-    return jax.jit(fn, donate_argnums=(4,))
+    return jax.jit(serve_prefill_seeded, donate_argnums=(4,))
 
 
 def prefill_request(
@@ -308,6 +309,38 @@ def _hbm_gib() -> dict[str, float]:
         k: v for k, v in stats.items()
         if k in ("hbm_in_use_gib", "hbm_peak_gib")
     }
+
+
+class _Phase:
+    """``ServingEngine._span`` under a tracer that does not tee into the
+    engine's timeline: the tracer's scoped span, plus the bare timeline
+    event ``_phase`` falls back to."""
+
+    __slots__ = ("_timeline", "_name", "_span", "_attrs", "_t0")
+
+    def __init__(self, timeline, name, span, attrs):
+        self._timeline, self._name, self._span = timeline, name, span
+        self._attrs = attrs
+
+    # What the tracer asks of a parent.
+    span_id = property(lambda self: self._span.span_id)
+    trace = property(lambda self: self._span.trace)
+
+    def set(self, **attrs: Any) -> None:
+        self._span.set(**attrs)
+        self._attrs.update(attrs)
+
+    def __enter__(self) -> "_Phase":
+        self._t0 = time.perf_counter()
+        self._span.__enter__()
+        return self
+
+    def __exit__(self, *exc: Any) -> None:
+        self._span.__exit__(*exc)
+        self._timeline.event(
+            self._name, dur_s=round(time.perf_counter() - self._t0, 9),
+            **self._attrs,
+        )
 
 
 class ServingEngine:
@@ -598,6 +631,13 @@ class ServingEngine:
             self.tracing.enabled and self.tracing.timeline is self.timeline
         )
         self._engine_trace = self.tracing.new_trace("engine")
+        # The open `step` span: parent of the engine-lane spans recorded
+        # while a request-lane span (`prefill`) is the innermost one.
+        self._step_span: Any = None
+        # (program, key) pairs whose first call has run: `_call` records
+        # every other first call as a `program_build`.
+        self._ran: set[tuple[str, Any]] = set()
+        self._hbm_sampled_at = float("-inf")
         t = self.telemetry
         self._m_ttft = t.histogram(
             "serve_ttft_seconds", help="time to first token (prefill+graft)"
@@ -633,6 +673,11 @@ class ServingEngine:
         )
         self._m_completed = t.counter(
             "serve_completed_total", help="requests finished"
+        )
+        self._m_builds = t.counter(
+            "serve_program_builds_total",
+            help="first calls of an engine program at a new shape "
+            "(trace + compile or cache load); 0 in a warmed engine",
         )
         # Failure-semantics counters (ISSUE 9): the OBSERVED side of the
         # fault ledger — chaos drills diff these against the FaultPlan's
@@ -741,6 +786,50 @@ class ServingEngine:
             self.timeline.event(
                 name, dur_s=round(max(float(dur_s), 0.0), 9), **attrs
             )
+
+    def _span(self, name, *, trace=None, parent=None, **attrs):
+        """``_phase`` for a phase that is one lexical block: a scoped span
+        (``with self._span(...) as sp:``; ``sp.set(...)`` adds what is only
+        known inside), so an ``annotate=True`` tracer also writes it into
+        the profiler's host plane, on the device trace's own clock. Without
+        ``trace``/``parent`` it nests under the innermost open span —
+        ``step`` and its children ride the engine lane that way."""
+        span = self.tracing.span(
+            name, trace=trace, parent=parent, cat="serve", **attrs
+        )
+        if self._phases_via_tee:
+            return span
+        return _Phase(self.timeline, name, span, attrs)
+
+    def _call(self, program: str, key: Any, fn, *args):
+        """Call an engine program. The first call at a new (program, key)
+        traces and compiles or loads it: that one runs inside an
+        engine-lane ``program_build`` span and counts in
+        ``serve_program_builds_total`` (building the jitted function
+        itself is lazy and costs nothing)."""
+        if (program, key) in self._ran:
+            return fn(*args)
+        with self._span(
+            "program_build", trace=self._engine_trace,
+            parent=self._step_span, program=program, key=str(key),
+        ):
+            out = fn(*args)
+        self._ran.add((program, key))
+        self._m_builds.inc()
+        self.stats["program_builds"] += 1
+        return out
+
+    def _sample_hbm(self) -> None:
+        """The two HBM gauges are last-written levels for a scrape, not a
+        per-step series: ``memory_stats()`` is a per-device runtime call,
+        so it runs at most once a second (and never with telemetry off)."""
+        now = time.perf_counter()
+        if not self.telemetry.enabled or now - self._hbm_sampled_at < 1.0:
+            return
+        self._hbm_sampled_at = now
+        for k, v in _hbm_gib().items():
+            (self._m_hbm_used if k == "hbm_in_use_gib"
+             else self._m_hbm_peak).set(v)
 
     # ----------------------------------------------------------- frontend
 
@@ -990,7 +1079,7 @@ class ServingEngine:
             m = self._model_at(s)
             kw = dict(self._sample_kw)
 
-            def fn(params, cache, tok, rng):
+            def serve_decode(params, cache, tok, rng):
                 logits, cache = _decode_step(m, params, cache, tok)
                 return _sample(logits, rng, **kw), cache
 
@@ -1002,7 +1091,7 @@ class ServingEngine:
             # allocation spike continuous batching sizes its slot count
             # against. Pinned by tests/test_serving.py donation pins via
             # analysis.pins.assert_donated/assert_aliased.
-            self._decode_jit[s] = jax.jit(fn, donate_argnums=(1,))
+            self._decode_jit[s] = jax.jit(serve_decode, donate_argnums=(1,))
         return self._decode_jit[s]
 
     def _graft_fn(self, s_p: int, s: int):
@@ -1014,7 +1103,7 @@ class ServingEngine:
         if (s_p, s) not in self._graft_jit:
             n = self.num_slots
 
-            def fn(cache, slot_cache, slot):
+            def serve_graft(cache, slot_cache, slot):
                 def leaf(e, p):
                     ax = cache_batch_axis(e, n)
                     assert ax is not None, (
@@ -1030,13 +1119,15 @@ class ServingEngine:
             # The engine cache is rebound to the graft's output too —
             # donate it (same audit find as _decode_fn; the slot cache is
             # NOT donated: its rows are read strided into the update).
-            self._graft_jit[(s_p, s)] = jax.jit(fn, donate_argnums=(0,))
+            self._graft_jit[(s_p, s)] = jax.jit(
+                serve_graft, donate_argnums=(0,)
+            )
         return self._graft_jit[(s_p, s)]
 
     def _grow_fn(self, s_old: int, s_new: int):
         if (s_old, s_new) not in self._grow_jit:
 
-            def fn(cache):
+            def serve_grow(cache):
                 def leaf(e):
                     # Pad every capacity-bearing leaf (K/V stacks AND
                     # their quantization-scale stacks) along the cache
@@ -1050,7 +1141,7 @@ class ServingEngine:
 
                 return jax.tree.map(leaf, cache)
 
-            self._grow_jit[(s_old, s_new)] = jax.jit(fn)
+            self._grow_jit[(s_old, s_new)] = jax.jit(serve_grow)
         return self._grow_jit[(s_old, s_new)]
 
     # ------------------------------------------------------- paged programs
@@ -1073,12 +1164,13 @@ class ServingEngine:
             )[1]["cache"],
             self.params, tok,
         )
+        def serve_init_cache():
+            return jax.tree.map(lambda s: jnp.zeros(s.shape, s.dtype), shapes)
+
         with self._trace_ctx():
-            self.cache = jax.jit(
-                lambda: jax.tree.map(
-                    lambda s: jnp.zeros(s.shape, s.dtype), shapes
-                )
-            )()
+            self.cache = self._call(
+                "init_cache", None, jax.jit(serve_init_cache)
+            )
         self._tables_dirty = True
 
     def _paged_decode_fn(self):
@@ -1089,13 +1181,15 @@ class ServingEngine:
             m = self._paged_model()
             kw = dict(self._sample_kw)
 
-            def fn(params, cache, tok, rng):
+            def serve_paged_decode(params, cache, tok, rng):
                 logits, cache = _decode_step(m, params, cache, tok)
                 return _sample(logits, rng, **kw), cache
 
             # Donate the cache (pool included) — the same two-caches-live
             # audit fix as _decode_fn, now sized at the POOL.
-            self._paged_decode_jit = jax.jit(fn, donate_argnums=(1,))
+            self._paged_decode_jit = jax.jit(
+                serve_paged_decode, donate_argnums=(1,)
+            )
         return self._paged_decode_jit
 
     def _prefill_seeded_fn(self, s_p: int, s_c: int):
@@ -1121,7 +1215,7 @@ class ServingEngine:
         if (s_c, m) not in self._seed_jit:
             bs = self.block_size
 
-            def fn(cache, ids):
+            def serve_seed(cache, ids):
                 from flax.traverse_util import flatten_dict, unflatten_dict
 
                 flat = flatten_dict(cache)
@@ -1150,7 +1244,7 @@ class ServingEngine:
                     # block_tables: slot caches carry none.
                 return unflatten_dict(out)
 
-            self._seed_jit[(s_c, m)] = jax.jit(fn)
+            self._seed_jit[(s_c, m)] = jax.jit(serve_seed)
         return self._seed_jit[(s_c, m)]
 
     def _paged_graft_fn(self, s_c: int, n_priv: int):
@@ -1165,13 +1259,15 @@ class ServingEngine:
         The engine cache (pool) is donated like every program that
         rebinds it; appends and growth never clone it."""
         if (s_c, n_priv) not in self._paged_graft_jit:
-            import functools
+            bs = self.block_size
+
+            def serve_paged_graft(cache, slot_cache, blk_ids, m0, slot):
+                return splice_pool_blocks(
+                    cache, slot_cache, blk_ids, m0, slot, block_size=bs
+                )
 
             self._paged_graft_jit[(s_c, n_priv)] = jax.jit(
-                functools.partial(
-                    splice_pool_blocks, block_size=self.block_size
-                ),
-                donate_argnums=(0,),
+                serve_paged_graft, donate_argnums=(0,)
             )
         return self._paged_graft_jit[(s_c, n_priv)]
 
@@ -1189,7 +1285,7 @@ class ServingEngine:
         if self._verify_jit is None:
             m = self._paged_model()
 
-            def fn(params, cache, tile):
+            def serve_verify(params, cache, tile):
                 logits, cache = _verify_step(m, params, cache, tile)
                 preds = jnp.argmax(
                     logits.astype(jnp.float32), axis=-1
@@ -1198,7 +1294,7 @@ class ServingEngine:
 
             # Donate the cache (pool included) — same two-pools-live
             # audit contract as the decode program.
-            self._verify_jit = jax.jit(fn, donate_argnums=(1,))
+            self._verify_jit = jax.jit(serve_verify, donate_argnums=(1,))
         return self._verify_jit
 
     def _rewind_fn(self):
@@ -1208,9 +1304,10 @@ class ServingEngine:
         K/V are simply abandoned past the cursor). Freed tail blocks
         are returned host-side by ``step()``'s release loop."""
         if self._rewind_jit is None:
-            self._rewind_jit = jax.jit(
-                rewind_cache_indices, donate_argnums=(0,)
-            )
+            def serve_rewind(cache, new_idx):
+                return rewind_cache_indices(cache, new_idx)
+
+            self._rewind_jit = jax.jit(serve_rewind, donate_argnums=(0,))
         return self._rewind_jit
 
     def _draft_fn(self):
@@ -1225,14 +1322,14 @@ class ServingEngine:
             dm, _ = self._draft
             k, w = self.spec_k, self.spec_window
 
-            def fn(params, windows, lengths):
+            def serve_draft(params, windows, lengths):
                 out = generate(
                     dm, params, windows, max_new_tokens=k,
                     temperature=0.0, prompt_lengths=lengths,
                 )
                 return out[:, w:]
 
-            self._draft_jit = jax.jit(fn)
+            self._draft_jit = jax.jit(serve_draft)
         return self._draft_jit
 
     def _propose(self) -> dict[int, np.ndarray]:
@@ -1298,7 +1395,8 @@ class ServingEngine:
         try:
             with self._trace_ctx():
                 drafts = np.asarray(jax.device_get(
-                    self._draft_fn()(
+                    self._call(
+                        "draft", None, self._draft_fn(),
                         self._draft[1],
                         jnp.asarray(windows),
                         jnp.asarray(lens),
@@ -1353,29 +1451,30 @@ class ServingEngine:
             n_prop += int(d.size)
         self._m_spec_proposed.inc(n_prop)
         self.stats["spec_proposed"] += n_prop
-        t0 = time.perf_counter()
-        fn = self._verify_fn()
-        with self._trace_ctx():
-            preds, self.cache = fn(
-                self.params, self.cache, jnp.asarray(tile)
-            )
-        preds = np.asarray(jax.device_get(preds))
-        dt = time.perf_counter() - t0
         n_active = int(self._active.sum())
+        t0 = time.perf_counter()
+        with self._span("verify", active=n_active, proposed=n_prop, k=k):
+            with self._span("dispatch"), self._trace_ctx():
+                preds, self.cache = self._call(
+                    "verify", None, self._verify_fn(),
+                    self.params, self.cache, jnp.asarray(tile),
+                )
+            with self._span("fetch"):
+                preds = np.asarray(jax.device_get(preds))
+        dt = time.perf_counter() - t0
+        with self._span("emit_tokens"):
+            self._emit_verified(drafts, tile, preds, t0, dt)
+
+    def _emit_verified(self, drafts, tile, preds, t0, dt) -> None:
+        """The host half of a verify step: accept, emit, retire, roll
+        back (``_spec_verify``'s `emit_tokens` span)."""
         self.stats["decode_verify"] += 1
         self.stats["decode_steps"] += 1
-        self.stats["slot_steps"] += n_active
+        self.stats["slot_steps"] += int(self._active.sum())
         self._m_decodes.inc()
         self._m_spec_verifies.inc()
-        self._phase(
-            "verify", t0=t0, dur_s=dt, trace=self._engine_trace,
-            active=n_active, proposed=n_prop, k=k,
-        )
         self.watchdog.beat()
-        if self.telemetry.enabled:
-            for name, v in _hbm_gib().items():
-                (self._m_hbm_used if name == "hbm_in_use_gib"
-                 else self._m_hbm_peak).set(v)
+        self._sample_hbm()
 
         bs = self.block_size
         for slot in range(self.num_slots):
@@ -1459,8 +1558,9 @@ class ServingEngine:
         # land in the trash block regardless.
         new_idx = np.where(self._active, self._len - 1, 0).astype(np.int32)
         with self._trace_ctx():
-            self.cache = self._rewind_fn()(
-                self.cache, jnp.asarray(new_idx)
+            self.cache = self._call(
+                "rewind", None, self._rewind_fn(),
+                self.cache, jnp.asarray(new_idx),
             )
         self._m_pool_util.set(self.pool_utilization())
 
@@ -1659,7 +1759,10 @@ class ServingEngine:
                     "serve.grow", CacheGrowError,
                     msg=f"injected grow failure {self.bucket}->{target}",
                 )
-                grown = self._grow_fn(self.bucket, target)(self.cache)
+                grown = self._call(
+                    "grow", (self.bucket, target),
+                    self._grow_fn(self.bucket, target), self.cache,
+                )
             except Exception as e:
                 self._m_grow_failures.inc()
                 self.stats["grow_failures"] += 1
@@ -1727,8 +1830,13 @@ class ServingEngine:
             req, res, sub,
             block_size=self.block_size if self.paged else 0,
             bucket_for=self._bucket_for, params=self.params,
-            prefill_fn=self._prefill_fn,
-            seeded_fn=self._prefill_seeded_fn,
+            prefill_fn=lambda s_p: functools.partial(
+                self._call, "prefill", s_p, self._prefill_fn(s_p)
+            ),
+            seeded_fn=lambda s_p, s_c: functools.partial(
+                self._call, "prefill_seeded", (s_p, s_c),
+                self._prefill_seeded_fn(s_p, s_c),
+            ),
             seed_cache=self._seed_for(req, res),
         )
 
@@ -1744,8 +1852,9 @@ class ServingEngine:
         if m == 0:
             return None
         s_c = self._bucket_for(int(req.prompt.size))
-        return self._seed_fn(s_c, m)(
-            self.cache, jnp.asarray(res["shared"], jnp.int32)
+        return self._call(
+            "seed", (s_c, m), self._seed_fn(s_c, m),
+            self.cache, jnp.asarray(res["shared"], jnp.int32),
         )
 
     def _graft_package(
@@ -1766,7 +1875,9 @@ class ServingEngine:
             # slot cache: ``m`` for a full bucketed cache, 0 when the
             # scheduler pre-sliced the cross-partition transfer down to
             # the private window.
-            self.cache = self._paged_graft_fn(s_c, n_g - m)(
+            self.cache = self._call(
+                "paged_graft", (s_c, n_g - m),
+                self._paged_graft_fn(s_c, n_g - m),
                 self.cache,
                 slot_cache,
                 jnp.asarray(res["priv"][: n_g - m], jnp.int32),
@@ -1783,8 +1894,10 @@ class ServingEngine:
                 self.cache = self._empty_cache(slot_cache, s_p)
                 self.bucket = s_p
             self._ensure_bucket(max(s_p, l + 1))
-            self.cache = self._graft_fn(s_p, self.bucket)(
-                self.cache, slot_cache, jnp.int32(slot)
+            self.cache = self._call(
+                "graft", (s_p, self.bucket),
+                self._graft_fn(s_p, self.bucket),
+                self.cache, slot_cache, jnp.int32(slot),
             )
 
     def _try_admit(
@@ -1808,25 +1921,32 @@ class ServingEngine:
             trace=req.trace, parent=req.span, slot=slot,
         )
         try:
-            faults.maybe_raise("serve.prefill", key=req.id)
-            with self._trace_ctx():
-                if self.paged and self.cache is None:
-                    self._init_paged_cache()
-                tok, slot_cache, s_p, s_c, m, l_suf = self._prefill_package(
-                    req, res, sub
-                )
-                t_graft = time.perf_counter()
-                self._graft_package(slot, req, res, slot_cache, s_p, s_c, m)
-                self._phase(
-                    "graft", t0=t_graft,
-                    dur_s=time.perf_counter() - t_graft,
-                    trace=req.trace, parent=req.span,
-                    slot=slot, bucket=self.bucket,
-                    **({"blocks": blocks_for_tokens(l, self.block_size) - m,
-                        "shared": m} if self.paged
-                       else {}),
-                )
-            tok = int(jax.device_get(tok)[0])
+            # The request's `prefill` span runs from here until its first
+            # token is on the host: the prefill program, the graft (a
+            # span of its own inside it) and the token's fetch.
+            with self._span(
+                "prefill", trace=req.trace, parent=req.span,
+                slot=slot, request=req.id,
+            ) as span:
+                faults.maybe_raise("serve.prefill", key=req.id)
+                with self._trace_ctx():
+                    if self.paged and self.cache is None:
+                        self._init_paged_cache()
+                    tok, slot_cache, s_p, s_c, m, l_suf = (
+                        self._prefill_package(req, res, sub)
+                    )
+                    span.set(**self._prefill_attrs(s_p, m))
+                    with self._span(
+                        "graft", trace=req.trace, parent=req.span, slot=slot,
+                        **({"blocks": blocks_for_tokens(l, self.block_size)
+                            - m, "shared": m} if self.paged else {}),
+                    ) as graft:
+                        self._graft_package(
+                            slot, req, res, slot_cache, s_p, s_c, m
+                        )
+                        graft.set(bucket=self.bucket)
+                tok = int(jax.device_get(tok)[0])
+            dt = time.perf_counter() - t0
         except Exception as e:
             # Quarantine: typed resolution + counter + a loud log with
             # the cause — systemic breakage (every request failing) shows
@@ -1852,20 +1972,27 @@ class ServingEngine:
             self._complete_unadmitted(req, "error")
             return False
         self._finish_admit(
-            slot, req, res, tok,
-            t0=t0, dt=time.perf_counter() - t0, s_p=s_p, m=m, l_suf=l_suf,
+            slot, req, res, tok, dt=dt, s_p=s_p, m=m, l_suf=l_suf,
         )
         return True
 
+    def _prefill_attrs(self, s_p: int, m: int) -> dict:
+        """What a `prefill` span says beside its slot and request."""
+        if not self.paged:
+            return {"bucket": s_p}
+        return {"bucket": s_p, "prefix_hit": m > 0,
+                "tokens_saved": m * self.block_size}
+
     def _finish_admit(
         self, slot: int, req: ServeRequest, res: dict | None, tok: int,
-        *, t0: float, dt: float, s_p: int, m: int, l_suf: int,
+        *, dt: float, s_p: int, m: int, l_suf: int,
     ) -> None:
         """Admission bookkeeping shared by the colocated path
         (``_try_admit``) and the disaggregated handoff
-        (``admit_handoff``): stats, SLO observations, prefix publication,
-        and slot activation. ``dt`` is the TTFT this engine charges the
-        request (prefill + splice, however they were scheduled)."""
+        (``admit_handoff``), after either has recorded the `prefill`
+        span: stats, SLO observations, prefix publication, and slot
+        activation. ``dt`` is the TTFT this engine charges the request
+        (prefill + splice, however they were scheduled)."""
         l = int(req.prompt.size)
         bs = self.block_size if self.paged else 0
         self.stats[f"prefill_{s_p}"] += 1
@@ -1896,13 +2023,6 @@ class ServingEngine:
             # Publish this prompt's full-block chains for later
             # admissions (refcounted by the cache itself).
             self._register_prefix(req.prompt, self._slot_blocks[slot])
-        self._phase(
-            "prefill", t0=t0, dur_s=dt, trace=req.trace,
-            parent=req.span,
-            slot=slot, bucket=s_p, request=req.id,
-            **({"prefix_hit": m > 0, "tokens_saved": m * bs}
-               if self.paged else {}),
-        )
         self.watchdog.beat()
 
         self._req[slot] = req
@@ -1961,10 +2081,15 @@ class ServingEngine:
         # honest interval is [splice_start - prefill_s, now] (it may
         # overlap other requests' spans — concurrent prefill is the
         # point of the split).
+        s_p = self._bucket_for(l - m * bs)
+        self._phase(
+            "prefill", t0=t0 - prefill_s, dur_s=prefill_s + dt_splice,
+            trace=req.trace, parent=req.span, slot=slot, request=req.id,
+            **self._prefill_attrs(s_p, m),
+        )
         self._finish_admit(
             slot, req, res, tok,
-            t0=t0 - prefill_s, dt=prefill_s + dt_splice,
-            s_p=self._bucket_for(l - m * bs), m=m, l_suf=l - m * bs,
+            dt=prefill_s + dt_splice, s_p=s_p, m=m, l_suf=l - m * bs,
         )
 
     def park_slot(self, slot: int) -> dict:
@@ -2043,7 +2168,10 @@ class ServingEngine:
         self._tables_dirty = True
         new_idx = np.where(self._active, self._len - 1, 0).astype(np.int32)
         with self._trace_ctx():
-            self.cache = self._rewind_fn()(self.cache, jnp.asarray(new_idx))
+            self.cache = self._call(
+                "rewind", None, self._rewind_fn(),
+                self.cache, jnp.asarray(new_idx),
+            )
         self.stats["resumed"] += 1
         self._phase(
             "resume", t0=time.perf_counter(), dur_s=0.0,
@@ -2234,6 +2362,7 @@ class ServingEngine:
         self._verify_jit = None
         self._rewind_jit = None
         self._draft_jit = None
+        self._ran.clear()
         self._tables_dirty = True
         for slot, p in parked:
             self.resume_parked(p, slot)
@@ -2330,94 +2459,56 @@ class ServingEngine:
         """Admit into free slots, run ONE decode iteration over the slot
         array, retire finished rows. Returns requests completed during
         this step (possibly at admission, for 1-token budgets; typed
-        shed/deadline/error resolutions ride along)."""
-        self._m_queue.set(len(self._queue))
-        self._admit()
+        shed/deadline/error resolutions ride along).
+
+        On the record: one engine-lane `step` span per call, whose
+        children (`admit`, `append_blocks` / `propose`, `decode` or
+        `verify` with `dispatch` and `fetch` inside, `emit_tokens`) leave
+        only the few lines between them uncovered — `step`'s self time
+        is host time that no phase owns."""
+        with self._span("step", trace=self._engine_trace) as span:
+            self._step_span = span
+            try:
+                self._step()
+            finally:
+                self._step_span = None
+            return self._drain_completed()
+
+    def _step(self) -> None:
+        depth = len(self._queue)
+        self._m_queue.set(depth)
+        with self._span("admit", queue=depth) as span:
+            before = self.stats["admitted"]
+            self._admit()
+            span.set(admitted=self.stats["admitted"] - before)
         # Typed completions resolved since the last step (shed at
         # submit) and during this admission round (expired/quarantined).
         self._completed.extend(self._early)
         self._early.clear()
         self._m_occupancy.set(float(self._active.sum()) / self.num_slots)
         if not self._active.any():
-            return self._drain_completed()
+            return
 
         # Speculative proposal round (ISSUE 11): drafts per slot for
         # this step's verify tile — BEFORE the block-append loop, which
         # must cover each row's draft write positions too.
         drafts: dict[int, np.ndarray] = {}
         if self.paged and self.spec_mode != "off":
-            drafts = self._propose()
+            with self._span("propose") as span:
+                drafts = self._propose()
+                span.set(slots=len(drafts))
 
         if self.paged:
-            # Paged growth: a row crossing a block boundary APPENDS one
-            # reserved block to its table — a host-side int write plus a
-            # table push, never a device-side cache clone. The
-            # reservation made at admission guarantees a free block, so
-            # the only failure left is the injected serve.grow fault
-            # (kept on the same degrade-per-row contract as bucketed
-            # growth: the crossing row retires typed, the batch lives).
-            # A speculating row additionally covers its draft write
-            # positions (idx .. idx + n_drafts — within the worst-case
-            # reservation because drafts are capped at budget - 1);
-            # rejected drafts hand their tail blocks back after the
-            # verify step.
-            for slot in np.flatnonzero(self._active):
-                extra = len(drafts.get(int(slot), ()))
-                need = (
-                    int(self._len[slot]) - 1 + extra
-                ) // self.block_size + 1
-                while len(self._slot_blocks[slot]) < need:
-                    try:
-                        faults.maybe_raise(
-                            "serve.grow", CacheGrowError,
-                            msg=f"injected block-append failure slot {slot}",
-                        )
-                        bid = self._free.pop()
-                    except Exception as e:
-                        self._m_grow_failures.inc()
-                        self.stats["grow_failures"] += 1
-                        from frl_distributed_ml_scaffold_tpu.utils.logging import (
-                            get_logger,
-                        )
-
-                        get_logger().warning(
-                            "serving: block append failed for slot %d "
-                            "(%s: %s); retiring it, batch keeps decoding",
-                            slot, type(e).__name__, e,
-                        )
-                        drafts.pop(int(slot), None)
-                        self._retire(int(slot), "error")
-                        break
-                    self._reserved_future -= 1
-                    self._slot_future[slot] -= 1
-                    self._ref[bid] += 1
-                    # (No peak sample here: an append converts one
-                    # reservation into one held block — demand is
-                    # unchanged, the admission-time sample covers it.)
-                    self._slot_blocks[slot].append(bid)
-                    self._tables[slot, len(self._slot_blocks[slot]) - 1] = bid
-                    self._tables_dirty = True
-                    self.stats["block_append"] += 1
-                    self._m_block_appends.inc()
-                    self._phase(
-                        "block_append", t0=time.perf_counter(), dur_s=0.0,
-                        trace=self._engine_trace, slot=int(slot), block=bid,
-                    )
-            self._m_pool_util.set(self.pool_utilization())
+            with self._span("append_blocks") as span:
+                span.set(appended=self._append_blocks(drafts))
             if not self._active.any():
-                return self._drain_completed()
-            if self._tables_dirty:
-                self.cache = {
-                    **self.cache,
-                    "block_tables": jnp.asarray(self._tables),
-                }
-                self._tables_dirty = False
+                return
             if drafts:
                 # At least one slot speculates: the whole batch rides
                 # the ONE verify program (slots without drafts
                 # single-step inside it — the mixed-batch contract).
                 self._spec_verify(drafts)
-                return self._drain_completed()
+                return
         else:
             # Bucket must hold every active row's next write position: an
             # active row holds cache_index == _len - 1 (prefill sets idx=l
@@ -2447,23 +2538,107 @@ class ServingEngine:
                 for s in victims:
                     self._retire(int(s), "error")
                 if not self._active.any():
-                    return self._drain_completed()
+                    return
 
-        self._rng, sub = jax.random.split(self._rng)
+        n_active = int(self._active.sum())
         t0 = time.perf_counter()
-        fn = (
-            self._paged_decode_fn() if self.paged
-            else self._decode_fn(self.bucket)
-        )
-        with self._trace_ctx():
-            nxt, self.cache = fn(
-                self.params,
-                self.cache,
-                jnp.asarray(self._last_tok),
-                sub,
-            )
-        nxt = np.asarray(jax.device_get(nxt))
+        # One engine-lane span per slot-array decode program, from before
+        # its dispatch until its tokens are on the host...
+        with self._span("decode", bucket=self.bucket, active=n_active):
+            # `dispatch` returns when the program is enqueued; `fetch` is
+            # where the host waits for the device.
+            with self._span("dispatch"), self._trace_ctx():
+                self._rng, sub = jax.random.split(self._rng)
+                if self.paged:
+                    program, key, fn = (
+                        "paged_decode", None, self._paged_decode_fn()
+                    )
+                else:
+                    program, key, fn = (
+                        "decode", self.bucket, self._decode_fn(self.bucket)
+                    )
+                nxt, self.cache = self._call(
+                    program, key, fn,
+                    self.params,
+                    self.cache,
+                    jnp.asarray(self._last_tok),
+                    sub,
+                )
+            with self._span("fetch"):
+                nxt = np.asarray(jax.device_get(nxt))
         dt = time.perf_counter() - t0
+        with self._span("emit_tokens"):
+            self._emit_decoded(nxt, n_active, t0, dt)
+
+    def _append_blocks(self, drafts: dict[int, np.ndarray]) -> int:
+        """Paged growth (``step``'s `append_blocks` span): a row crossing
+        a block boundary APPENDS one reserved block to its table — a
+        host-side int write plus a table push, never a device-side cache
+        clone. The reservation made at admission guarantees a free
+        block, so the only failure left is the injected serve.grow fault
+        (kept on the same degrade-per-row contract as bucketed growth:
+        the crossing row retires typed, the batch lives). A speculating
+        row additionally covers its draft write positions (idx .. idx +
+        n_drafts — within the worst-case reservation because drafts are
+        capped at budget - 1); rejected drafts hand their tail blocks
+        back after the verify step. Returns the blocks appended."""
+        appended = 0
+        for slot in np.flatnonzero(self._active):
+            extra = len(drafts.get(int(slot), ()))
+            need = (
+                int(self._len[slot]) - 1 + extra
+            ) // self.block_size + 1
+            while len(self._slot_blocks[slot]) < need:
+                try:
+                    faults.maybe_raise(
+                        "serve.grow", CacheGrowError,
+                        msg=f"injected block-append failure slot {slot}",
+                    )
+                    bid = self._free.pop()
+                except Exception as e:
+                    self._m_grow_failures.inc()
+                    self.stats["grow_failures"] += 1
+                    from frl_distributed_ml_scaffold_tpu.utils.logging import (
+                        get_logger,
+                    )
+
+                    get_logger().warning(
+                        "serving: block append failed for slot %d "
+                        "(%s: %s); retiring it, batch keeps decoding",
+                        slot, type(e).__name__, e,
+                    )
+                    drafts.pop(int(slot), None)
+                    self._retire(int(slot), "error")
+                    break
+                self._reserved_future -= 1
+                self._slot_future[slot] -= 1
+                self._ref[bid] += 1
+                # (No peak sample here: an append converts one
+                # reservation into one held block — demand is
+                # unchanged, the admission-time sample covers it.)
+                self._slot_blocks[slot].append(bid)
+                self._tables[slot, len(self._slot_blocks[slot]) - 1] = bid
+                self._tables_dirty = True
+                appended += 1
+                self.stats["block_append"] += 1
+                self._m_block_appends.inc()
+                self._phase(
+                    "block_append", t0=time.perf_counter(), dur_s=0.0,
+                    trace=self._engine_trace, slot=int(slot), block=bid,
+                )
+        self._m_pool_util.set(self.pool_utilization())
+        if self._tables_dirty and self._active.any():
+            self.cache = {
+                **self.cache,
+                "block_tables": jnp.asarray(self._tables),
+            }
+            self._tables_dirty = False
+        return appended
+
+    def _emit_decoded(self, nxt, n_active: int, t0: float, dt: float) -> None:
+        """The host half of a decode step (``step``'s `emit_tokens`
+        span): counters, then per live row the token's bookkeeping, its
+        `decode_tick`, and whatever retires it."""
         self.stats[
             "decode_paged" if self.paged else f"decode_{self.bucket}"
         ] += 1
@@ -2472,22 +2647,11 @@ class ServingEngine:
         # one invocation per active slot, emitting one token each — the
         # denominator serve_bench's decode-invocations-per-token column
         # (and the speculative reduction ratio) reads from.
-        self.stats["slot_steps"] += int(self._active.sum())
-        self.stats["step_tokens"] += int(self._active.sum())
+        self.stats["slot_steps"] += n_active
+        self.stats["step_tokens"] += n_active
         self._m_decodes.inc()
-        # One engine-lane span per slot-array decode program...
-        self._phase(
-            "decode", t0=t0, dur_s=dt, trace=self._engine_trace,
-            bucket=self.bucket, active=int(self._active.sum()),
-        )
         self.watchdog.beat()
-        if self.telemetry.enabled:
-            # memory_stats() is a per-device PJRT runtime call — real cost
-            # on a ~ms decode step, so the disabled path must skip the
-            # query itself, not just the no-op gauge write.
-            for k, v in _hbm_gib().items():
-                (self._m_hbm_used if k == "hbm_in_use_gib"
-                 else self._m_hbm_peak).set(v)
+        self._sample_hbm()
 
         for slot in range(self.num_slots):
             if not self._active[slot]:
@@ -2520,4 +2684,3 @@ class ServingEngine:
             if self._expired(req):
                 self._m_deadline.inc()
                 self._retire(slot, "deadline")
-        return self._drain_completed()

@@ -102,6 +102,11 @@ class Span:
             self.attrs = {**self.attrs, **attrs}
         self._tracer._finish(self, time.perf_counter())
 
+    def set(self, **attrs: Any) -> None:
+        """Attrs that are only known inside the span (how many requests an
+        ``admit`` admitted); they land in the record when it closes."""
+        self.attrs = {**self.attrs, **attrs}
+
     def __enter__(self) -> "Span":
         self._token = _CURRENT.set(self)
         if self._tracer.annotate:
@@ -123,6 +128,10 @@ class Span:
         if self._token is not None:
             _CURRENT.reset(self._token)
             self._token = None
+        if exc and exc[0] is not None:
+            # The span an exception leaves says so (a quarantined
+            # request's `prefill`), instead of reading as a short success.
+            self.set(error=exc[0].__name__)
         self.end()
 
 
@@ -137,6 +146,9 @@ class _NullSpan:
     parent_id = None
 
     def end(self, **attrs: Any) -> None:
+        pass
+
+    def set(self, **attrs: Any) -> None:
         pass
 
     def __enter__(self) -> "_NullSpan":

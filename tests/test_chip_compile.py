@@ -20,6 +20,7 @@ would then skip).
 from __future__ import annotations
 
 import importlib
+import re
 
 import jax
 import jax.numpy as jnp
@@ -63,34 +64,65 @@ def one_chip():
     compilation_cache.reset_cache()
 
 
-def _compile_has_kernel(one_chip, fn, *shapes) -> None:
+def _compile_has_kernel(one_chip, fn, *shapes, names, attention=True) -> None:
     """Compile ``fn`` for the described chip — raises what the chip's
-    compiler would raise — and require the Pallas kernel in the program."""
+    compiler would raise — and require each Pallas kernel in the program
+    under its own name: a device trace calls a kernel by the name of its
+    custom-call instruction (``%attn_flash_fwd.1 = ... custom-call(``), and
+    the benchmark's kernel metrics find it by that. Every attention kernel's
+    name starts with ``attn_`` (the two accepted rooflines match
+    ``^%attn[\\w.]*``), and no other kernel's does."""
     args = [
         jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
         for shape, dtype in shapes
     ]
-    compiled = jax.jit(fn).lower(*args).compile()
-    assert "tpu_custom_call" in compiled.as_text()
+    text = jax.jit(fn).lower(*args).compile().as_text()
+    assert "tpu_custom_call" in text
+    calls = re.findall(
+        r"^\s*(?:ROOT )?%([\w.\-]+) = .*custom-call\(.*tpu_custom_call",
+        text, re.M,
+    )
+    found = {re.sub(r"\.\d+$", "", c) for c in calls}
+    assert found == set(names), (found, names)
+    assert all(n.startswith("attn_") == attention for n in found), found
+
+
+def _scoped(fn, scope="attn"):
+    """``fn`` inside a named scope, as a kernel sits inside its flax module
+    (GPT's is called ``attn``, which is what the kernels were called on the
+    device before they had names). A transform wraps the outermost scope it
+    finds — ``jvp(attn)/attn_flash_fwd`` — so the kernel's own name comes
+    through ``jax.grad`` whole; with no scope around it, it would not."""
+    def scoped(*args):
+        with jax.named_scope(scope):
+            return fn(*args)
+
+    return scoped
 
 
 BF16, F32, I8, I32 = jnp.bfloat16, jnp.float32, jnp.int8, jnp.int32
 QKV = ((B, S, H, D), BF16)
 
 
+@_scoped
 def _flash(q, k, v):
     return flash_attention(q, k, v, causal=True, interpret=False)
 
 
 def test_flash_attention_forward(one_chip):
-    _compile_has_kernel(one_chip, _flash, QKV, QKV, QKV)
+    _compile_has_kernel(
+        one_chip, _flash, QKV, QKV, QKV, names=["attn_flash_fwd"]
+    )
 
 
 def test_flash_attention_backward(one_chip):
     grad = jax.grad(
         lambda q, k, v: _flash(q, k, v).astype(F32).sum(), argnums=(0, 1, 2)
     )
-    _compile_has_kernel(one_chip, grad, QKV, QKV, QKV)
+    _compile_has_kernel(
+        one_chip, grad, QKV, QKV, QKV,
+        names=["attn_flash_fwd", "attn_flash_dq", "attn_flash_dkv"],
+    )
 
 
 @pytest.mark.parametrize(
@@ -105,9 +137,10 @@ def test_decode_attention(one_chip, seq, dtype):
     kv = ((B, seq, H, D), dtype)
     _compile_has_kernel(
         one_chip,
-        lambda q, k, v, n: da.decode_attention(
-            q, k, v, n, impl="flash", interpret=False),
+        _scoped(lambda q, k, v, n: da.decode_attention(
+            q, k, v, n, impl="flash", interpret=False)),
         ((B, H, D), dtype), kv, kv, ((B,), I32),
+        names=["attn_decode"],
     )
 
 
@@ -115,10 +148,11 @@ def test_decode_attention_int8_cache(one_chip):
     kv, sc = ((B, S, H, D), I8), ((B, S, H), BF16)
     _compile_has_kernel(
         one_chip,
-        lambda q, k, v, n, ks, vs: da.decode_attention(
+        _scoped(lambda q, k, v, n, ks, vs: da.decode_attention(
             q, k, v, n, k_scale=ks, v_scale=vs, impl="flash",
-            interpret=False),
+            interpret=False)),
         ((B, H, D), BF16), kv, kv, ((B,), I32), sc, sc,
+        names=["attn_decode_quant"],
     )
 
 
@@ -138,9 +172,10 @@ def test_paged_decode_attention(one_chip, block):
     pool, lens, tables, _ = _pool_shapes(block, BF16)
     _compile_has_kernel(
         one_chip,
-        lambda q, k, v, n, t: da.paged_decode_attention(
-            q, k, v, n, t, impl="flash", interpret=False),
+        _scoped(lambda q, k, v, n, t: da.paged_decode_attention(
+            q, k, v, n, t, impl="flash", interpret=False)),
         ((B, H, D), BF16), pool, pool, lens, tables,
+        names=["attn_paged_decode"],
     )
 
 
@@ -149,10 +184,11 @@ def test_paged_decode_attention_int8_pool(one_chip, block):
     pool, lens, tables, scales = _pool_shapes(block, I8)
     _compile_has_kernel(
         one_chip,
-        lambda q, k, v, n, t, ks, vs: da.paged_decode_attention(
+        _scoped(lambda q, k, v, n, t, ks, vs: da.paged_decode_attention(
             q, k, v, n, t, k_scale=ks, v_scale=vs, impl="flash",
-            interpret=False),
+            interpret=False)),
         ((B, H, D), BF16), pool, pool, lens, tables, scales, scales,
+        names=["attn_paged_decode_quant"],
     )
 
 
@@ -162,9 +198,10 @@ def test_paged_verify_attention(one_chip, block):
     pool, lens, tables, _ = _pool_shapes(block, BF16)
     _compile_has_kernel(
         one_chip,
-        lambda q, k, v, n, t: da.paged_verify_attention(
-            q, k, v, n, t, impl="flash", interpret=False),
+        _scoped(lambda q, k, v, n, t: da.paged_verify_attention(
+            q, k, v, n, t, impl="flash", interpret=False)),
         ((B, T_VERIFY, H, D), BF16), pool, pool, lens, tables,
+        names=["attn_paged_verify"],
     )
 
 
@@ -178,12 +215,13 @@ def test_fused_bn_backward(one_chip, shape):
     passes behind a custom VJP)."""
     c = shape[-1]
     grad = jax.grad(
-        lambda x, scale, bias: fused_bn_train(  # -> (y, mean, var)
-            x, scale, bias, interpret=False)[0].astype(F32).sum(),
+        _scoped(lambda x, scale, bias: fused_bn_train(  # -> (y, mean, var)
+            x, scale, bias, interpret=False)[0].astype(F32).sum(), "bn"),
         argnums=(0, 1, 2),
     )
     _compile_has_kernel(
-        one_chip, grad, (shape, BF16), ((c,), F32), ((c,), F32)
+        one_chip, grad, (shape, BF16), ((c,), F32), ((c,), F32),
+        names=["bn_bwd_reduce", "bn_bwd_dx"], attention=False,
     )
 
 
@@ -196,4 +234,6 @@ def test_fused_adamw(one_chip):
         state = tx.init({"w": p})._replace(mu={"w": mu}, nu={"w": nu})
         return tx.fused_apply({"w": g}, state, {"w": p})
 
-    _compile_has_kernel(one_chip, apply, w, w, w, w)
+    _compile_has_kernel(
+        one_chip, apply, w, w, w, w, names=["fused_adamw"], attention=False
+    )

@@ -1804,3 +1804,116 @@ def test_disagg_sequential_latency_after_preemption_no_livelock(gpt):
         np.testing.assert_array_equal(done[rid].tokens, np.asarray(ref)[0])
     assert eng.stats["parked"] >= 1 and eng.stats["resumed"] >= 1
     eng.close()
+
+
+# ------------------------------------------- programs on the record (ISSUE 27)
+
+ENGINE_PROGRAMS = (
+    "decode", "prefill", "graft", "grow",
+    "paged_decode", "prefill_seeded", "seed", "paged_graft", "init_cache",
+    "verify", "rewind", "draft",
+)
+
+
+def _record_calls(eng, into: dict) -> None:
+    """Every engine program runs through ``_call``: keep each one's jitted
+    function and the shapes of its first call's arguments (taken before the
+    call — some are donated)."""
+    call = eng._call
+
+    def recording(program, key, fn, *args):
+        into.setdefault(program, (fn, jax.tree.map(
+            lambda x: jax.ShapeDtypeStruct(jnp.shape(x), jnp.result_type(x)),
+            args,
+        )))
+        return call(program, key, fn, *args)
+
+    eng._call = recording
+
+
+@pytest.fixture(scope="module")
+def engine_programs(gpt, gpt_draft):
+    """One bucketed engine that grows, one paged engine that hits its prefix
+    cache and one that speculates with a draft model: between them every
+    program the engine jits."""
+    model, params, _ = gpt
+    draft, dparams = gpt_draft
+    seen: dict = {}
+    rng = np.random.default_rng(27)
+    shared = rng.integers(0, 64, size=10).astype(np.int32)
+
+    eng = ServingEngine(model, params, num_slots=2, temperature=0.0)
+    _record_calls(eng, seen)
+    eng.submit(shared[:3], 12)  # outgrows its first bucket
+    eng.run()
+    eng.close()
+
+    eng = ServingEngine(
+        model, params, num_slots=2, temperature=0.0, kv_block_size=8
+    )
+    _record_calls(eng, seen)
+    eng.submit(shared, 3)
+    eng.run()
+    eng.submit(np.concatenate([shared[:8], shared[:3]]), 3)  # one block hits
+    eng.run()
+    assert eng.stats["prefix_hits"] == 1
+    eng.close()
+
+    eng = ServingEngine(
+        model, params, num_slots=2, temperature=0.0, kv_block_size=8,
+        speculate="draft", speculate_k=2,
+        draft_model=draft, draft_params=dparams,
+    )
+    _record_calls(eng, seen)
+    eng.submit(shared, 6)
+    eng.run()
+    eng.close()
+    return seen
+
+
+@pytest.mark.parametrize("program", ENGINE_PROGRAMS)
+def test_engine_program_is_jitted_under_its_own_name(engine_programs, program):
+    """A device trace's XLA Modules line calls a program by its jitted
+    function's name: every engine program has one of its own
+    (``jit_serve_<program>``), and none is the anonymous ``jit_fn``."""
+    assert set(engine_programs) == set(ENGINE_PROGRAMS)
+    fn, shapes = engine_programs[program]
+    text = fn.lower(*shapes).as_text()
+    assert re.search(rf"module @jit_serve_{program}\b", text), text[:200]
+
+
+def _builds(eng) -> list[tuple[str, str]]:
+    return [
+        (s["program"], s["key"]) for s in eng.tracing.drain()
+        if s["name"] == "program_build"
+    ]
+
+
+def test_program_build_recorded_once_per_new_shape(gpt):
+    """The step that traced and compiled (or loaded) a program says so: a
+    request of a shape already served builds nothing, a new prompt bucket
+    builds its prefill once (and the graft shape that goes with it), and
+    the counter agrees with the spans."""
+    model, params, _ = gpt
+    eng = ServingEngine(model, params, num_slots=2, temperature=0.0)
+    rng = np.random.default_rng(5)
+    prompt = lambda n: rng.integers(0, 64, size=n).astype(np.int32)
+
+    eng.submit(prompt(5), 3)
+    eng.run()
+    first = _builds(eng)
+    assert ("prefill", "8") in first and ("decode", "8") in first
+    assert len(set(first)) == len(first)
+
+    eng.submit(prompt(6), 3)  # same buckets: nothing to build
+    eng.run()
+    assert _builds(eng) == []
+
+    eng.submit(prompt(12), 2)  # a new prompt bucket
+    eng.run()
+    new = _builds(eng)
+    assert [b for b in new if b[0] == "prefill"] == [("prefill", "16")]
+    assert {b[0] for b in new} <= {"prefill", "graft", "grow", "decode"}
+    counted = eng.telemetry.snapshot()["serve_program_builds_total"]
+    assert counted == len(first) + len(new) == eng.stats["program_builds"]
+    eng.close()

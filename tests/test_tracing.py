@@ -289,6 +289,141 @@ def test_tracing_off_token_identical_with_bounded_overhead(gpt):
     assert med_on <= 3.0 * max(med_off, 1e-9), (med_on, med_off)
 
 
+#: What `step`'s children may leave uncovered: the few lines between them
+#: (gauge writes, the drain of the completed list), on a loaded CI host.
+STEP_SLACK_S = 2e-3
+
+
+def _children(spans, parent):
+    return sorted(
+        (s for s in spans if s.get("parent") == parent["span"]),
+        key=lambda s: s["t0_s"],
+    )
+
+
+def _end(span):
+    return span["t0_s"] + span["dur_s"]
+
+
+@pytest.mark.parametrize(
+    "eng_kw",
+    [{}, {"kv_block_size": 8},
+     {"kv_block_size": 8, "speculate": "ngram", "speculate_k": 2}],
+    ids=["bucketed", "paged", "speculative"],
+)
+def test_step_span_is_covered_by_its_children(gpt, eng_kw):
+    """The serving step on the record: every `step` span that ran a device
+    program is covered by its children (`admit`, `append_blocks`,
+    `propose`, `decode` / `verify`, `emit_tokens`) to within a stated
+    slack, they do not overlap, and `dispatch` and `fetch` tile the
+    `decode` / `verify` between them. (`program_build`, the one child of
+    `step` that lies INSIDE its siblings, is left out of the tiling.)"""
+    model, params = gpt
+    work = _workload(n=6, seed=21)
+    eng, _ = _serve(model, params, work, **eng_kw)
+    try:
+        eng.tracing.drain()
+        for prompt, n_new in work:
+            eng.submit(prompt, n_new)
+        eng.run()
+        spans = [
+            s for s in eng.tracing.spans() if s["name"] != "program_build"
+        ]
+        lanes = {s["trace"] for s in spans if s["name"] == "step"}
+        assert len(lanes) == 1  # the engine lane
+        steps = [s for s in spans if s["name"] == "step"]
+        ran = 0
+        for step in steps:
+            kids = _children(spans, step)
+            assert all(k["trace"] == step["trace"] for k in kids)
+            names = [k["name"] for k in kids]
+            assert names[0] == "admit" and set(kids[0]) >= {"queue", "admitted"}
+            program = [k for k in kids if k["name"] in ("decode", "verify")]
+            if not program:
+                continue
+            ran += 1
+            assert len(program) == 1 and names[-1] == "emit_tokens"
+            if eng_kw:
+                assert "append_blocks" in names
+                assert "appended" in kids[names.index("append_blocks")]
+            # Covered, in order, without overlap.
+            assert step["t0_s"] <= kids[0]["t0_s"]
+            for a, b in zip(kids, kids[1:]):
+                assert _end(a) <= b["t0_s"] + 1e-9, (a, b)
+            assert _end(kids[-1]) <= _end(step) + 1e-9
+            covered = sum(k["dur_s"] for k in kids)
+            assert step["dur_s"] - covered < STEP_SLACK_S, (step, names)
+            # dispatch + fetch tile the program's span.
+            parts = _children(spans, program[0])
+            assert [p["name"] for p in parts] == ["dispatch", "fetch"]
+            assert program[0]["t0_s"] <= parts[0]["t0_s"]
+            assert _end(parts[0]) <= parts[1]["t0_s"] + 1e-9
+            assert _end(parts[1]) <= _end(program[0]) + 1e-9
+            assert program[0]["dur_s"] - parts[0]["dur_s"] - parts[1]["dur_s"] < 1e-3
+        assert ran >= 3
+    finally:
+        eng.close()
+
+
+def test_spans_the_benchmark_reads_keep_their_fields(gpt):
+    """`benchmarks/lib/readers.py` reads `active` and `dur_s` off every
+    `decode` span (decode_occupancy, mfu.decode) and `request` and `dur_s`
+    off every `prefill` span (mfu.prefill): both kept their names, fields
+    and extents when they became scoped spans. A `prefill` still holds its
+    request's `graft`; a `decode` still ends after the tokens' fetch."""
+    model, params = gpt
+    work = _workload(n=4, seed=9)
+    eng, done = _serve(model, params, work)
+    try:
+        spans = eng.tracing.spans()
+        decodes = [s for s in spans if s["name"] == "decode"]
+        prefills = [s for s in spans if s["name"] == "prefill"]
+        assert len(prefills) == len(work)
+        assert len(decodes) == eng.stats["decode_steps"]
+        for s in decodes:
+            assert 1 <= s["active"] <= eng.num_slots and "bucket" in s
+            assert s["dur_s"] > 0.0 and s["cat"] == "serve"
+        for s in prefills:
+            assert s["request"] in done and "bucket" in s and "slot" in s
+            grafts = [
+                g for g in spans
+                if g["name"] == "graft" and g["trace"] == s["trace"]
+            ]
+            assert len(grafts) == 1
+            assert s["t0_s"] <= grafts[0]["t0_s"]
+            assert _end(grafts[0]) <= _end(s) + 1e-9
+        # Every token after a request's first comes out of one decode span.
+        n_ticks = sum(len(c.tokens) - c.prompt_len - 1 for c in done.values())
+        assert sum(s["active"] for s in decodes) == n_ticks
+    finally:
+        eng.close()
+
+
+def test_annotating_tracer_with_profiler_off_changes_nothing(gpt):
+    """A tracer built ``annotate=True`` writes the engine's scoped phases
+    into the profiler's host plane; with no profile running the
+    annotations are inert: same tokens, same span tree, nothing raised."""
+    model, params = gpt
+    work = _workload(n=5, seed=17)
+    runs = {}
+    for annotate in (False, True):
+        eng, done = _serve(
+            model, params, work,
+            tracer=Tracer(capacity=1 << 16, annotate=annotate),
+        )
+        runs[annotate] = (
+            {rid: c.tokens for rid, c in done.items()},
+            sorted(s["name"] for s in eng.tracing.spans()),
+        )
+        eng.close()
+    assert sorted(runs[True][0]) == sorted(runs[False][0])
+    for rid, tokens in runs[True][0].items():
+        np.testing.assert_array_equal(tokens, runs[False][0][rid])
+    assert runs[True][1] == runs[False][1]
+    assert {"step", "admit", "decode", "dispatch", "fetch",
+            "emit_tokens"} <= set(runs[True][1])
+
+
 def test_engine_timeline_phases_survive_external_tracer(gpt):
     """telemetry.jsonl's phase records (PR 7 contract) must not depend on
     tracing state: with a caller-supplied DISABLED tracer the engine
