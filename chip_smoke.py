@@ -439,6 +439,9 @@ def phase_kernels(fields: dict, *, batch: int = 8, heads: int = 16,
     import jax.numpy as jnp
     import optax
 
+    from frl_distributed_ml_scaffold_tpu.models.generation import (
+        slot_blocks_to_pool,
+    )
     from frl_distributed_ml_scaffold_tpu.ops.flash_attention import (
         flash_attention,
     )
@@ -526,40 +529,47 @@ def phase_kernels(fields: dict, *, batch: int = 8, heads: int = 16,
         qd, k8, v8, kv_len, ks, vs), 2e-2)
 
     # Paged decode and the verify tile (the engine's path): the same K/V
-    # cut into pool blocks behind a shuffled block table.
+    # cut into pool blocks behind a shuffled block table, stored as the
+    # model stores them — all layers stacked, lane-dense [L, N, bs, H*D],
+    # a block's int8 scales one row [L, N, H*bs] — and read at layer 1 of
+    # 2 (layer 0 holds zeros).
+    layer = 1
     for bs in block_sizes:
         m = seq // bs
         perm = jax.random.permutation(next(keys), batch * m) + 1  # 0=trash
         tables = perm.reshape(batch, m).astype(jnp.int32)
 
-        def pool(x):
-            blocks = x.reshape(batch * m, bs, *x.shape[2:])
-            out = jnp.zeros((batch * m + 1, *blocks.shape[1:]), x.dtype)
-            return out.at[perm].set(blocks)
+        def pool(name, x):
+            rows = slot_blocks_to_pool(
+                name, x.reshape(batch * m, bs, *x.shape[2:])
+            )
+            out = jnp.zeros((2, batch * m + 1, *rows.shape[1:]), x.dtype)
+            return out.at[layer, perm].set(rows)
 
-        kp, vp = pool(k), pool(v)
+        kp, vp = pool("key_pool", k), pool("value_pool", v)
         out = jit_checked(
             f"paged_decode_bs{bs}",
             lambda q, k, v, l, t: da.paged_decode_attention(
-                q, k, v, l, t, impl="flash", interpret=interpret),
+                q, k, v, l, t, layer, impl="flash", interpret=interpret),
             qd, kp, vp, kv_len, tables,
         )
         check(f"paged_decode_bs{bs}", out, da.dense_paged_decode_attention(
-            qd, kp, vp, kv_len, tables), 2e-2)
+            qd, kp, vp, kv_len, tables, layer), 2e-2)
         # ...which is also what the contiguous reference gives.
         check(f"paged_vs_contiguous_bs{bs}", out,
               da.dense_decode_attention(qd, k, v, kv_len), 2e-2)
-        kp8, vp8, ksp, vsp = pool(k8), pool(v8), pool(ks), pool(vs)
+        kp8, vp8 = pool("key_pool", k8), pool("value_pool", v8)
+        ksp, vsp = pool("key_pool_scale", ks), pool("value_pool_scale", vs)
         out = jit_checked(
             f"paged_decode_int8_bs{bs}",
             lambda q, k, v, l, t, a, b: da.paged_decode_attention(
-                q, k, v, l, t, k_scale=a, v_scale=b, impl="flash",
+                q, k, v, l, t, layer, k_scale=a, v_scale=b, impl="flash",
                 interpret=interpret),
             qd, kp8, vp8, kv_len, tables, ksp, vsp,
         )
         check(f"paged_decode_int8_bs{bs}", out,
               da.dense_paged_decode_attention(
-                  qd, kp8, vp8, kv_len, tables, ksp, vsp), 2e-2)
+                  qd, kp8, vp8, kv_len, tables, layer, ksp, vsp), 2e-2)
         qv = jax.random.normal(
             next(keys), (batch, verify_len, heads, head_dim), bf16
         )
@@ -567,11 +577,11 @@ def phase_kernels(fields: dict, *, batch: int = 8, heads: int = 16,
         out = jit_checked(
             f"paged_verify_bs{bs}",
             lambda q, k, v, l, t: da.paged_verify_attention(
-                q, k, v, l, t, impl="flash", interpret=interpret),
+                q, k, v, l, t, layer, impl="flash", interpret=interpret),
             qv, kp, vp, lens_v, tables,
         )
         check(f"paged_verify_bs{bs}", out, da.dense_paged_verify_attention(
-            qv, kp, vp, lens_v, tables), 2e-2)
+            qv, kp, vp, lens_v, tables, layer), 2e-2)
         del kp, vp, kp8, vp8, ksp, vsp, out
 
     # Fused AdamW vs optax.adamw at an MLP weight's shape.

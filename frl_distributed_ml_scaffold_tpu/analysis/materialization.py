@@ -7,6 +7,16 @@ a forbidden dimension — is a finding.  Program INPUTS (params, caches)
 are exempt by construction: only eqn outvars are walked, so a big weight
 passing through untouched never trips the budget, exactly like the
 original pin's "seq_len appears only in the wpe PARAM" carve-out.
+
+What this pass CANNOT see: it reads the jaxpr, not what the device's
+compiler makes of it. The paged serving programs passed their cache-copy
+budget here while the chip moved gigabytes a step: the passes over the KV
+pool (layout transposes around a scatter and a kernel call, a scanned
+leaf sliced out of its stack and written back) were put in by the TPU's
+compiler and appear in no jaxpr (PERF.md §6, PR 28). The programs' compiled
+HLO is pinned in tests/test_chip_compile.py
+(``test_pool_program_leaves_the_pool_in_place``): a budget kept here is
+necessary, not sufficient.
 """
 
 from __future__ import annotations

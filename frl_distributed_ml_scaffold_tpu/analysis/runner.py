@@ -704,7 +704,7 @@ def build_paged_decode_step_program(
     from frl_distributed_ml_scaffold_tpu.models.generation import (
         _decode_step,
     )
-    from frl_distributed_ml_scaffold_tpu.models.gpt import GPT
+    from frl_distributed_ml_scaffold_tpu.models.gpt import GPT, init_paged_cache
     from frl_distributed_ml_scaffold_tpu.precision import get_policy
 
     model = GPT(
@@ -723,13 +723,7 @@ def build_paged_decode_step_program(
             train=False,
         )["params"]
     )
-    _, cache_vars = jax.eval_shape(
-        lambda p, t: m.apply(
-            {"params": p}, t, decode=True, mutable=["cache"]
-        ),
-        params, tok,
-    )
-    cache = cache_vars["cache"]
+    cache = jax.eval_shape(lambda: init_paged_cache(m, num_slots))
 
     jaxpr = jax.make_jaxpr(
         lambda p, c, t: _decode_step(m, p, c, t[:, 0])
@@ -759,7 +753,7 @@ def build_verify_step_program(
     from frl_distributed_ml_scaffold_tpu.models.generation import (
         _verify_step,
     )
-    from frl_distributed_ml_scaffold_tpu.models.gpt import GPT
+    from frl_distributed_ml_scaffold_tpu.models.gpt import GPT, init_paged_cache
     from frl_distributed_ml_scaffold_tpu.precision import get_policy
 
     model = GPT(
@@ -779,13 +773,7 @@ def build_verify_step_program(
             train=False,
         )["params"]
     )
-    _, cache_vars = jax.eval_shape(
-        lambda p, t: m.apply(
-            {"params": p}, t, decode=True, mutable=["cache"]
-        ),
-        params, tok,
-    )
-    cache = cache_vars["cache"]
+    cache = jax.eval_shape(lambda: init_paged_cache(m, num_slots))
 
     jaxpr = jax.make_jaxpr(
         lambda p, c, t: _verify_step(m, p, c, t)
@@ -925,7 +913,7 @@ def build_handoff_program(
         next_cache_bucket,
         splice_pool_blocks,
     )
-    from frl_distributed_ml_scaffold_tpu.models.gpt import GPT
+    from frl_distributed_ml_scaffold_tpu.models.gpt import GPT, init_paged_cache
     from frl_distributed_ml_scaffold_tpu.precision import get_policy
 
     model = GPT(
@@ -944,13 +932,7 @@ def build_handoff_program(
         )["params"]
     )
     mp = model.clone(kv_block_size=block_size, kv_pool_blocks=pool_blocks)
-    _, pool_vars = jax.eval_shape(
-        lambda p, t: mp.apply(
-            {"params": p}, t, decode=True, mutable=["cache"]
-        ),
-        params, tok,
-    )
-    pool_cache = pool_vars["cache"]
+    pool_cache = jax.eval_shape(lambda: init_paged_cache(mp, num_slots))
     s_c = next_cache_bucket(seq_len, prompt_tokens, floor=block_size)
     mc = model.clone(cache_len=s_c)
     slot_tok = jax.ShapeDtypeStruct((1, 1), jnp.int32)
@@ -994,7 +976,20 @@ def lint_handoff(
       every handoff holds two pools live.
 
     Mutation-gated in tests/test_graft_lint.py (a gather-based handoff
-    mutant must trip)."""
+    mutant must trip).
+
+    All three read the JAXPR (and the lowering's donation markers), not
+    what the TPU's compiler makes of them — and they passed while every
+    graft on the chip transposed the whole donated pool there and back
+    around its scatter, 18-23 ms of device time for ten blocks
+    (PERF.md §5 of PR 27): the copies came from the pool leaf's device
+    layout and are in no jaxpr. What the chip runs is pinned on the
+    compiled HLO, for a described v5e at the serving benchmark's size,
+    in tests/test_chip_compile.py
+    (``test_pool_program_leaves_the_pool_in_place``: no op writes a
+    layer's slice of the pool but the in-place update, temporaries of a
+    few megabytes). Keep both: this lint is the cheap one that runs on
+    every recipe."""
     import jax
     import jax.numpy as jnp
 

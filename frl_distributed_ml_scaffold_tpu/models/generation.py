@@ -351,12 +351,14 @@ def estimate_cache_bytes_per_slot(
 # with a shared pool of fixed-size blocks plus per-row block tables.  The
 # taxonomy below is the paged extension of cache_batch_axis /
 # cache_capacity_axis: pool leaves carry NO row axis (blocks are shared —
-# that is the whole point) and are classified by NAME, because a pool's
-# [N, bs, H, hd] shape is indistinguishable from a slot cache's
-# [B, S, H, hd] by shape alone. Every block-wise cache transform — the
-# engine's block grafts, the prefix-seed gather, capacity accounting —
-# routes through these names, the same lockstep contract as the shape
-# taxonomy.
+# that is the whole point) and are classified by NAME, not by shape. A
+# pool leaf holds all layers and is LANE-DENSE (models/gpt.py
+# ``paged_cache_leaves``): K/V pools ``[L, N, bs, H*hd]`` — a token's row
+# is H*hd contiguous values — and scale pools ``[L, N, H*bs]`` — a
+# block's scales are one row — heads major in both. Every block-wise
+# cache transform — the engine's block grafts, the prefix-seed gather,
+# capacity accounting — routes through these names and the two
+# conversions below, the same lockstep contract as the shape taxonomy.
 
 #: Slot-cache leaf name -> its pool counterpart (the contiguous prefill
 #: cache's leaves map onto pool blocks through this; the scale leaves are
@@ -383,18 +385,36 @@ def blocks_for_tokens(tokens: int, block_size: int) -> int:
 def pool_heads_axis(name: str, leaf) -> int | None:
     """The taxonomy's third question (ISSUE 15): which axis of a POOL
     leaf carries the attention heads — the only axis a serving re-spread
-    may shard. Name-keyed like ``POOL_LEAF_OF`` (pool shapes are
-    ambiguous): K/V pools are ``[..., N, bs, H, hd]`` (heads at
-    ``ndim-2``), scale pools ``[..., N, bs, H]`` (heads at ``ndim-1``);
-    every other leaf (tables, cursors) carries none. The engine's
-    ``respread_pool`` derives its destination layouts through this —
-    the same lockstep contract as the shape taxonomy: a new pool leaf
-    class extends THIS function, not an ad-hoc ndim check."""
-    if name in ("key_pool", "value_pool"):
-        return leaf.ndim - 2
-    if name in ("key_pool_scale", "value_pool_scale"):
-        return leaf.ndim - 1
-    return None
+    may shard. Name-keyed like ``POOL_LEAF_OF``: heads are the MAJOR
+    part of every pool leaf's last axis (K/V rows ``H*hd``, scale rows
+    ``H*bs``), so splitting that axis splits whole heads; every other
+    leaf (tables, cursors) carries none. The engine's ``respread_pool``
+    derives its destination layouts through this — the same lockstep
+    contract as the shape taxonomy: a new pool leaf class extends THIS
+    function, not an ad-hoc ndim check."""
+    return leaf.ndim - 1 if name in SLOT_LEAF_OF else None
+
+
+def _is_scale(name: str) -> bool:
+    return name.endswith("scale")
+
+
+def slot_blocks_to_pool(name: str, blocks):
+    """Slot-cache positions cut into blocks — ``[..., n, bs, H, hd]`` K/V
+    or ``[..., n, bs, H]`` scales (``name``: either side's leaf name) —
+    as the pool stores them: ``[..., n, bs, H*hd]`` (a free reshape) or
+    ``[..., n, H*bs]`` (a block's scales as one row, heads major)."""
+    if _is_scale(name):
+        blocks = jnp.swapaxes(blocks, -1, -2)
+    return blocks.reshape(blocks.shape[:-2] + (-1,))
+
+
+def pool_to_slot_blocks(name: str, rows, heads: int):
+    """The inverse of ``slot_blocks_to_pool``: pool blocks
+    ``[..., n, bs, H*hd]`` / ``[..., n, H*bs]`` back to
+    ``[..., n, bs, H, hd]`` / ``[..., n, bs, H]``."""
+    blocks = rows.reshape(rows.shape[:-1] + (heads, -1))
+    return jnp.swapaxes(blocks, -1, -2) if _is_scale(name) else blocks
 
 
 def pool_leaf_spec(name: str, leaf):
@@ -419,12 +439,15 @@ def splice_pool_blocks(cache, slot_cache, blk_ids, m0, slot, *,
     """The prefill→decode HANDOFF SPLICE (ISSUE 12), over the block-pool
     taxonomy: write one prefilled (contiguous, bucketed) slot cache's
     PRIVATE blocks into their physical pool homes and set the slot's
-    cursor rows. ``blk_ids [n_priv]`` are the destination physical block
-    ids for the logical blocks starting at ``m0`` (shared prefix blocks
-    below ``m0`` are already in the pool and are NOT touched — only the
-    blocks that change owner move, the arXiv 2112.01075 discipline), and
-    ``slot`` is the decode-side row whose ``cache_index``/``pos_index``
-    the splice seeds.
+    cursor rows. The slot cache is ``[L, 1, S, H, hd]`` (scales
+    ``[L, 1, S, H]``), the pool ``[L, N, bs, H*hd]`` (``[L, N, H*bs]``):
+    the slot's blocks are reshaped to pool rows
+    (``slot_blocks_to_pool``). ``blk_ids [n_priv]`` are the destination
+    physical block ids for the logical blocks starting at ``m0`` (shared
+    prefix blocks below ``m0`` are already in the pool and are NOT
+    touched — only the blocks that change owner move, the arXiv
+    2112.01075 discipline), and ``slot`` is the decode-side row whose
+    ``cache_index``/``pos_index`` the splice seeds.
 
     This is the ONLY device work in a prefill→decode handoff: ownership
     itself moves as a host-side block-table row write (a re-own, priced
@@ -450,7 +473,13 @@ def splice_pool_blocks(cache, slot_cache, blk_ids, m0, slot, *,
             chunks = leaf[:, 0].reshape(
                 (leaf.shape[0], n_blk, bs) + leaf.shape[3:]
             )
-            sl = jax.lax.dynamic_slice_in_dim(chunks, m0, n_priv, axis=1)
+            sl = slot_blocks_to_pool(
+                name, jax.lax.dynamic_slice_in_dim(chunks, m0, n_priv, axis=1)
+            )
+            # In place on the donated pool: the leaf's rows are whole
+            # lane tiles, so the device scatters the n_priv blocks and
+            # touches nothing else (pinned on the compiled HLO in
+            # tests/test_chip_compile.py).
             out[pool_path] = pool.at[:, blk_ids].set(sl.astype(pool.dtype))
         elif name == "cache_index":
             out[kp] = out[kp].at[:, slot].set(leaf[:, 0])
@@ -471,7 +500,7 @@ def pool_block_bytes(cache) -> int:
     for path, leaf in jax.tree_util.tree_flatten_with_path(cache)[0]:
         name = getattr(path[-1], "key", None)
         if name in SLOT_LEAF_OF:
-            # [L, N, bs, ...] stacked pool leaf: bytes per (all-layers) block.
+            # [L, N, ...] stacked pool leaf: bytes per (all-layers) block.
             n = leaf.shape[1]
             total += (
                 int(np.prod(leaf.shape, dtype=np.int64)) // n

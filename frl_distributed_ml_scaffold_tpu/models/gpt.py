@@ -330,10 +330,12 @@ def _masked_dense_attention(q, k, v, mask):
     return out.astype(q.dtype)
 
 
-def _constrain_kv_pool(x: jnp.ndarray) -> jnp.ndarray:
-    """Pin a PAGED cache leaf — [N, bs, H, hd] K/V pool blocks or their
-    [N, bs, H] scale pools — model-sharded over the mesh's ``model`` axis
-    (heads on axis 2, the same Megatron split as ``_constrain_kv_cache``)
+def _constrain_kv_pool(x: jnp.ndarray, heads: int) -> jnp.ndarray:
+    """Pin a PAGED cache leaf — the stacked lane-dense K/V pools
+    ``[L, N, bs, H*hd]`` or their scale pools ``[L, N, H*bs]`` —
+    model-sharded over the mesh's ``model`` axis (heads are the MAJOR
+    part of every pool leaf's last dimension, so splitting that
+    dimension is the same Megatron head split as ``_constrain_kv_cache``)
     and REPLICATED over the batch axes: pool blocks are shared across
     slot rows (that is what multiplies concurrency), so a batch-sharded
     pool would scatter a row's blocks across data shards and every table
@@ -343,14 +345,76 @@ def _constrain_kv_pool(x: jnp.ndarray) -> jnp.ndarray:
     env = current_mesh_env()
     if env is None or env.axis_size("model") <= 1:
         return x
-    if x.ndim < 3 or x.shape[2] % env.axis_size("model") != 0:
+    if heads % env.axis_size("model") != 0:
         return x
     from jax.sharding import NamedSharding
 
-    spec = P(None, None, "model", *([None] * (x.ndim - 3)))
+    spec = P(*([None] * (x.ndim - 1)), "model")
     return jax.lax.with_sharding_constraint(
         x, NamedSharding(env.mesh, spec)
     )
+
+
+def paged_cache_leaves(
+    cfg: GPTConfig, dtype: Any, *, block_size: int, pool_blocks: int,
+    batch: int,
+) -> dict[str, tuple[tuple[int, ...], Any]]:
+    """Name -> (shape, dtype) of the attention's PAGED cache leaves, all
+    layers stacked — the one place their shapes are written down
+    (``CausalSelfAttention`` reads its variables by it and
+    ``init_paged_cache`` builds the empty cache from it).
+
+    The K/V pools are LANE-DENSE, ``[L, N, bs, H*hd]``: a token's K row
+    is H*hd contiguous values (heads major), so the minor dimension is a
+    multiple of the 128-lane tile and the device keeps the leaf in the
+    plain row-major layout that the in-place scatter and the Pallas
+    kernel both read. (With a ``[..., H, hd]`` minor pair and hd = 64,
+    half a lane tile, the TPU's compiler moved the block index into the
+    lanes instead and every program that touched the pool transposed it
+    there and back: PERF.md §6, PR 28.) A quantized pool's scales are
+    ``[L, N, H*bs]`` for the same reason: a block's per-(position, head)
+    scales as one row, heads major."""
+    h = cfg.num_heads
+    hd = cfg.hidden_dim // h
+    layers = cfg.num_layers
+    quant = cfg.kv_cache_quant != "none"
+    if quant:
+        from frl_distributed_ml_scaffold_tpu.ops.quantization import lowp_dtype
+
+        dtype = lowp_dtype(cfg.kv_cache_quant)
+    pool = ((layers, pool_blocks, block_size, h * hd), dtype)
+    leaves = {"key_pool": pool, "value_pool": pool}
+    if quant:
+        scale = ((layers, pool_blocks, h * block_size), jnp.bfloat16)
+        leaves.update(key_pool_scale=scale, value_pool_scale=scale)
+    leaves["cache_index"] = ((layers, batch), jnp.int32)
+    return leaves
+
+
+def init_paged_cache(model: "GPT", batch: int) -> Any:
+    """The EMPTY paged decode cache of ``model`` (a ``GPT`` cloned with
+    ``kv_block_size`` / ``kv_pool_blocks``) for ``batch`` slot rows:
+    zero pools, cursors at 0, all-zero block tables (every row on the
+    reserved trash block 0). Unlike the contiguous cache, which a
+    prefill creates on the fly, the paged cache has to EXIST before the
+    first step: the layer loop carries the pools whole
+    (``GPT.__call__``), and a loop cannot carry what its first
+    iteration has yet to create. Traceable: wrap in ``jax.jit`` or
+    ``jax.eval_shape`` as needed."""
+    cfg = model.config
+    attn = paged_cache_leaves(
+        cfg, model.policy.compute_dtype, block_size=model.kv_block_size,
+        pool_blocks=model.kv_pool_blocks, batch=batch,
+    )
+    return {
+        "blocks": {
+            "attn": {n: jnp.zeros(s, d) for n, (s, d) in attn.items()}
+        },
+        "pos_index": jnp.zeros((batch,), jnp.int32),
+        "block_tables": jnp.zeros(
+            (batch, -(-cfg.seq_len // model.kv_block_size)), jnp.int32
+        ),
+    }
 
 
 def _constrain_kv_cache(x: jnp.ndarray) -> jnp.ndarray:
@@ -401,7 +465,8 @@ class CausalSelfAttention(nn.Module):
     # Paged decode cache (ISSUE 10; 0 = contiguous per-row cache): K/V
     # live in a shared pool of kv_pool_blocks fixed-size blocks instead
     # of [B, S] stacks; the per-row block table arrives via the scan
-    # carry (serving/engine.py owns allocation and the tables).
+    # carry (serving/engine.py owns allocation and the tables) and the
+    # layer's index beside it (the pool holds all layers).
     kv_block_size: int = 0
     kv_pool_blocks: int = 0
 
@@ -414,6 +479,7 @@ class CausalSelfAttention(nn.Module):
         decode: bool = False,
         lengths: jnp.ndarray | None = None,
         block_tables: jnp.ndarray | None = None,
+        layer: jnp.ndarray | None = None,
     ) -> jnp.ndarray:
         cfg = self.config
         d = cfg.hidden_dim
@@ -474,34 +540,30 @@ class CausalSelfAttention(nn.Module):
                 # positions >= its private suffix, and the engine's
                 # copy-on-write admission never maps a shared block
                 # there.
-                if block_tables is None:
+                if block_tables is None or layer is None:
                     raise ValueError(
-                        "kv_block_size set but no block_tables reached "
-                        "the attention cache — the decode carry must "
-                        "thread them"
+                        "kv_block_size set but no block_tables / layer "
+                        "index reached the attention cache — the decode "
+                        "loop must thread them"
                     )
-                bs_blk, nb = self.kv_block_size, self.kv_pool_blocks
-                ck = self.variable(
-                    "cache", "key_pool", jnp.zeros,
-                    (nb, bs_blk, h, hd), cache_dtype,
-                )
-                cv = self.variable(
-                    "cache", "value_pool", jnp.zeros,
-                    (nb, bs_blk, h, hd), cache_dtype,
-                )
-                if quant:
-                    ksc = self.variable(
-                        "cache", "key_pool_scale", jnp.zeros,
-                        (nb, bs_blk, h), jnp.bfloat16,
-                    )
-                    vsc = self.variable(
-                        "cache", "value_pool_scale", jnp.zeros,
-                        (nb, bs_blk, h), jnp.bfloat16,
-                    )
-                ci = self.variable(
-                    "cache", "cache_index", jnp.zeros, (b,), jnp.int32
-                )
-                idx = ci.value  # [B]
+                # The pools hold ALL layers ([L, N, bs, H*hd], lane-dense:
+                # ``paged_cache_leaves``) and the layer loop CARRIES them
+                # whole (GPT.__call__): this layer writes its tokens' rows
+                # at ``[layer, block, offset]`` in place and the kernel
+                # reads the stack where it lies. Nothing pool-sized is cut
+                # out, copied or written back (the compiled HLO is pinned
+                # in tests/test_chip_compile.py).
+                bs_blk = self.kv_block_size
+                cache = {
+                    name: self.variable("cache", name, jnp.zeros, shape, dt)
+                    for name, (shape, dt) in paged_cache_leaves(
+                        cfg, self.dtype, block_size=bs_blk,
+                        pool_blocks=self.kv_pool_blocks, batch=b,
+                    ).items()
+                }
+                ck, cv = cache["key_pool"], cache["value_pool"]
+                ci = cache["cache_index"]
+                idx = ci.value[layer]  # [B]
                 # Physical write target for the j-th tile column: block
                 # tbl[(idx + j) // bs], offset (idx + j) % bs. Retired
                 # slots point at the reserved trash block 0 (and their
@@ -523,6 +585,7 @@ class CausalSelfAttention(nn.Module):
                 off = offs % bs_blk
                 k_w = k.astype(self.dtype)  # [B, t, H, hd]
                 v_w = v.astype(self.dtype)
+                scales = {}
                 if quant:
                     from frl_distributed_ml_scaffold_tpu.ops.quantization import (
                         quantize,
@@ -531,60 +594,57 @@ class CausalSelfAttention(nn.Module):
                     # Quantize ONCE per written token over its own head
                     # vector (the PR 6 contract): per-(row, pos, head)
                     # scales over hd, identical to the contiguous path's
-                    # scale at the same position.
-                    qk, sk = quantize(
-                        k_w, cfg.kv_cache_quant, channel_axes=(0, 1, 2)
-                    )
-                    qv, sv = quantize(
-                        v_w, cfg.kv_cache_quant, channel_axes=(0, 1, 2)
-                    )
-                    k_w, v_w = qk, qv
-                    ksc.value = _constrain_kv_pool(
-                        ksc.value.at[phys, off].set(
-                            sk[..., 0].astype(ksc.value.dtype)
+                    # scale at the same position. A block's scales are one
+                    # row, heads major: head i of offset o sits at lane
+                    # i * bs + o.
+                    lanes = jnp.arange(h) * bs_blk + off[..., None]
+
+                    def quantized(x, scale_pool):
+                        x_q, sc = quantize(
+                            x, cfg.kv_cache_quant, channel_axes=(0, 1, 2)
                         )
-                    )
-                    vsc.value = _constrain_kv_pool(
-                        vsc.value.at[phys, off].set(
-                            sv[..., 0].astype(vsc.value.dtype)
+                        scale_pool.value = _constrain_kv_pool(
+                            scale_pool.value.at[
+                                layer, phys[..., None], lanes
+                            ].set(sc[..., 0].astype(jnp.bfloat16)),
+                            h,
                         )
+                        return x_q  # [B, t, H, hd] 1-byte payload
+
+                    k_w = quantized(k_w, cache["key_pool_scale"])
+                    v_w = quantized(v_w, cache["value_pool_scale"])
+                    scales = dict(
+                        k_scale=cache["key_pool_scale"].value,
+                        v_scale=cache["value_pool_scale"].value,
                     )
                 ck.value = _constrain_kv_pool(
-                    ck.value.at[phys, off].set(k_w)
+                    ck.value.at[layer, phys, off].set(k_w.reshape(b, t, d)),
+                    h,
                 )
                 cv.value = _constrain_kv_pool(
-                    cv.value.at[phys, off].set(v_w)
+                    cv.value.at[layer, phys, off].set(v_w.reshape(b, t, d)),
+                    h,
                 )
-                if t == 1:
-                    from frl_distributed_ml_scaffold_tpu.ops.decode_attention import (
-                        paged_decode_attention,
-                    )
+                # ONE kernel for the decode step and the speculative
+                # VERIFY tile (ISSUE 11): all t positions score against
+                # the paged cache in one forward — causal inside the tile
+                # (query j attends logical positions <= idx + j), so
+                # query 0 computes exactly the single-token decode step's
+                # output and greedy acceptance against these logits is
+                # exact. The decode step is its t = 1 tile, under its own
+                # name in a device trace.
+                from frl_distributed_ml_scaffold_tpu.ops.decode_attention import (
+                    paged_verify_attention,
+                )
 
-                    y = paged_decode_attention(
-                        q[:, 0], ck.value, cv.value, idx + 1,
-                        block_tables,
-                        k_scale=ksc.value if quant else None,
-                        v_scale=vsc.value if quant else None,
-                        impl=cfg.decode_attention,
-                    )[:, None]
-                else:
-                    # Speculative VERIFY tile (ISSUE 11): all t = k+1
-                    # positions score against the paged cache in ONE
-                    # forward — causal inside the tile (query j attends
-                    # logical positions <= idx + j), so query 0 computes
-                    # exactly the single-token decode step's output and
-                    # greedy acceptance against these logits is exact.
-                    from frl_distributed_ml_scaffold_tpu.ops.decode_attention import (
-                        paged_verify_attention,
-                    )
-
-                    y = paged_verify_attention(
-                        q, ck.value, cv.value, idx + t, block_tables,
-                        k_scale=ksc.value if quant else None,
-                        v_scale=vsc.value if quant else None,
-                        impl=cfg.decode_attention,
-                    )
-                ci.value = idx + t
+                y = paged_verify_attention(
+                    q, ck.value, cv.value, idx + t, block_tables, layer,
+                    impl=cfg.decode_attention,
+                    name="attn_paged_decode" if t == 1
+                    else "attn_paged_verify",
+                    **scales,
+                )
+                ci.value = ci.value.at[layer].set(idx + t)
                 y = y.reshape(b, t, d)
                 y = nn.Dense(
                     d, dtype=self.dtype, name="out", dot_general=out_dg
@@ -774,12 +834,14 @@ class Block(nn.Module):
     kv_pool_blocks: int = 0
 
     @nn.compact
-    def __call__(self, carry, _unused):
+    def __call__(self, carry, layer):
         # Decode mode threads the per-row prompt lengths through the scan
         # carry (a traced array cannot be a module attribute); they are
         # loop-invariant. Paged decode additionally threads the per-row
-        # block tables the same way (every layer reads the same tables;
-        # the pools themselves are per-layer cache vars).
+        # block tables the same way (every layer reads the same tables)
+        # and scans over ``layer``, this block's index: the pools hold
+        # all layers and are carried whole, as the ``cache`` collection
+        # (None on every other path).
         tables = None
         if self.decode and self.kv_block_size > 0:
             x, aux_loss, lengths, tables = carry
@@ -794,7 +856,7 @@ class Block(nn.Module):
             kv_block_size=self.kv_block_size,
             kv_pool_blocks=self.kv_pool_blocks, name="attn"
         )(y, train=train, decode=self.decode, lengths=lengths,
-          block_tables=tables)
+          block_tables=tables, layer=layer)
         # Named for block_remat="save_attn": saving this one [B,T,D] tensor
         # per layer lets the per-block recompute skip the attention sublayer
         # (the quadratic part). A no-op unless a checkpoint policy asks.
@@ -855,8 +917,9 @@ class GPT(nn.Module):
     # kv_block_size > 0 stores K/V in a shared pool of kv_pool_blocks
     # fixed-size blocks addressed through a per-row ``block_tables``
     # cache var ([B, ceil(seq_len/block_size)] int32, engine-owned) —
-    # single-token decode steps only; prefill stays contiguous and the
-    # engine grafts it into the pool block-wise.
+    # single-token decode steps and verify tiles only; prefill stays
+    # contiguous and the engine grafts it into the pool block-wise. The
+    # cache is built by ``init_paged_cache`` before the first step.
     kv_block_size: int = 0
     kv_pool_blocks: int = 0
 
@@ -884,6 +947,15 @@ class GPT(nn.Module):
                 "(speculative decoding, ISSUE 11) — ragged lengths do "
                 "not apply; prefill stays contiguous and the engine "
                 "grafts it block-wise into the pool"
+            )
+        paged = decode and self.kv_block_size > 0
+        if paged and not self.has_variable("cache", "block_tables"):
+            raise ValueError(
+                "paged decode needs its cache to exist before the first "
+                "step: the layer loop carries the KV pools whole and "
+                "cannot carry what its first iteration would create — "
+                "build the empty cache with "
+                "models.gpt.init_paged_cache(model, batch)"
             )
 
         wte = nn.Embed(
@@ -965,10 +1037,19 @@ class GPT(nn.Module):
             if decode:
                 # Decode keeps its own plain scan: hooks/remat are
                 # training-path rewrites and never mix with the caches.
+                # The contiguous cache is scanned with the layers (each
+                # layer owns its [B, S, H, hd] slice). The PAGED cache
+                # is CARRIED: one stacked pool that every layer updates
+                # in place at [layer, block, offset] — scanned in and out,
+                # each layer's pool would be sliced out of the stack,
+                # copied, and written back (gigabytes a decode step).
                 stack_cls = nn.scan(
                     Block,
                     length=cfg.num_layers,
-                    variable_axes={"params": 0, "cache": 0},
+                    variable_axes=(
+                        {"params": 0} if paged else {"params": 0, "cache": 0}
+                    ),
+                    variable_carry="cache" if paged else False,
                     split_rngs={"params": True, "dropout": True},
                 )
             else:
@@ -988,19 +1069,17 @@ class GPT(nn.Module):
                 self.kv_pool_blocks if decode else 0,
                 name="blocks",
             )
-            if decode and self.kv_block_size > 0:
+            if paged:
                 # Paged decode: the block tables are a MODEL-level cache
                 # var (one copy, not per-layer — every layer reads the
                 # same row→block mapping), threaded to the scanned blocks
                 # through the carry like `lens`. The engine writes them
                 # host-side between steps; the model only reads.
-                m_blocks = -(-cfg.seq_len // self.kv_block_size)
-                tbl = self.variable(
-                    "cache", "block_tables", jnp.zeros,
-                    (b, m_blocks), jnp.int32,
+                tbl = self.get_variable("cache", "block_tables")
+                carry0 = (x, jnp.zeros((), jnp.float32), lens, tbl)
+                (x, aux_loss, _, _), _ = blocks(
+                    carry0, jnp.arange(cfg.num_layers, dtype=jnp.int32)
                 )
-                carry0 = (x, jnp.zeros((), jnp.float32), lens, tbl.value)
-                (x, aux_loss, _, _), _ = blocks(carry0, None)
             elif decode:
                 # `lens` from the position block above — one defaulting
                 # site for the whole decode trace.

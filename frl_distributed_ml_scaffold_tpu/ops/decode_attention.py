@@ -48,20 +48,25 @@ wide-dtype cache-shaped intermediate materializes in a quantized decode
 step.
 
 Paged KV cache (ISSUE 10, ROADMAP item 1): the serving engine stores K/V
-in a fixed POOL of fixed-size blocks ``[N, bs, H, D]`` shared by every
-slot, with a per-row block table ``[B, M]`` mapping each row's logical
-block j to a physical pool block (serving/engine.py owns allocation,
-refcounts, and shared-prefix reuse). ``paged_decode_attention`` extends
-the split-KV kernel through the SAME scalar-prefetch path: the block
-table rides the prefetch channel next to the per-row lengths, so the
-K/V index maps gather block-by-block — chunk j of row b DMAs pool block
-``table[b, j]``, clamped to the row's last occupied block exactly like
-the contiguous kernel clamps its chunk index. Nothing is ever gathered
-into a contiguous logical view: the dense fallback streams bounded
-``[B, bs, H, D]`` chunks (one ``jnp.take`` per table column) through the
-same online-softmax ``lax.scan``, so no full-``seq_len`` array — and no
+in a fixed POOL of fixed-size blocks shared by every slot, with a per-row
+block table ``[B, M]`` mapping each row's logical block j to a physical
+pool block (serving/engine.py owns allocation, refcounts, and
+shared-prefix reuse). The pool is ONE leaf for all layers and LANE-DENSE,
+``[L, N, bs, H*D]``: a token's K row is H*D contiguous values, so the
+device keeps the leaf row-major and the kernel reads it where it lies
+(models/gpt.py ``paged_cache_leaves`` has the why). ``paged_decode_attention``
+extends the split-KV kernel through the SAME scalar-prefetch path: the
+block table and the layer index ride the prefetch channel next to the
+per-row lengths, so the K/V index maps gather block-by-block — chunk j of
+row b DMAs block ``table[b, j]`` of the layer, clamped to the row's last
+occupied block exactly like the contiguous kernel clamps its chunk index.
+Nothing is ever gathered into a contiguous logical view and no layer's
+slice of the pool is cut out: the dense fallback streams bounded
+``[B, bs, H*D]`` chunks (one gather per table column) through the same
+online-softmax ``lax.scan``, so no full-``seq_len`` array — and no
 pool-sized copy — materializes per step (graft-lint's paged decode
-program pins both).
+program pins both in the jaxpr, tests/test_chip_compile.py in the
+compiled HLO).
 
 Speculative verify tile (ISSUE 11): speculative decoding proposes k
 draft tokens per row and the TARGET model scores all k+1 positions in
@@ -69,7 +74,7 @@ one batched forward — the whole point is that the pool read (the
 bandwidth bill decode pays) is amortized over k+1 query positions
 instead of one. ``paged_verify_attention`` runs THE paged kernel over a
 small q TILE ``[B, T, H, D]`` (single-token paged decode is its T=1
-tile: Mosaic refuses a q_len=1 kernel's mat-vec) with causal masking
+tile) with causal masking
 inside the chunk loop: query position t of a row whose total occupancy
 (tile included) is ``kv_len`` attends logical positions
 ``< kv_len - T + 1 + t`` — position 0 sees exactly what a single-token
@@ -202,103 +207,49 @@ def dense_decode_attention_quant(
     return (acc / jnp.maximum(l, 1e-30)).astype(q.dtype)
 
 
-def dense_paged_decode_attention(
-    q: jax.Array,
-    k_pool: jax.Array,
-    v_pool: jax.Array,
-    kv_len: jax.Array,
-    block_tables: jax.Array,
-    k_scale: jax.Array | None = None,
-    v_scale: jax.Array | None = None,
-) -> jax.Array:
-    """Reference decode attention over a PAGED cache: q ``[B, H, D]``
-    against pool blocks ``[N, bs, H, D]`` addressed through per-row block
-    tables ``[B, M]`` (row b's logical positions ``[j*bs, (j+1)*bs)``
-    live in pool block ``block_tables[b, j]``), keys at logical positions
-    >= ``kv_len[b]`` masked out. With ``k_scale``/``v_scale``
-    (``[N, bs, H]``) the pool is quantized and the scales fold into the
-    score strip / probability row per chunk.
-
-    Deliberately NOT "gather the logical cache, call the contiguous
-    reference": that materializes an ``M*bs >= seq_len``-wide tensor
-    every decode step — exactly the full-context array the block pool
-    exists to avoid (and the graft-lint mutation gate for the paged
-    program). Instead the table columns stream through an online-softmax
-    ``lax.scan``: each iteration gathers ONE bounded ``[B, bs, H, D]``
-    block per row (``jnp.take`` on the physical ids — gather at the
-    boundary, the arXiv 2112.01075 discipline) and merges with the
-    standard log-sum-exp rescale. fp32 softmax throughout (the decode
-    numerics contract)."""
-    _, bs, h, d = k_pool.shape
-    quant = k_scale is not None
-    q32 = q.astype(jnp.float32)
-    inv = 1.0 / np.sqrt(d)
-    cols = block_tables.astype(jnp.int32).T  # [M, B] physical ids per step
-
-    def step(carry, phys):
-        m, l, acc, j = carry
-        k_c = jnp.take(k_pool, phys, axis=0)  # [B, bs, H, D] — bounded
-        v_c = jnp.take(v_pool, phys, axis=0)
-        sc = jnp.einsum(
-            "bhd,bchd->bhc", q32, k_c.astype(jnp.float32)
-        )
-        if quant:
-            k_s = jnp.take(k_scale, phys, axis=0).astype(jnp.float32)
-            sc = sc * jnp.moveaxis(k_s, 1, 2)  # scale per (b, h, pos)
-        sc = sc * inv
-        kpos = j * bs + jnp.arange(bs)
-        mask = kpos[None, None, :] < kv_len[:, None, None]
-        sc = jnp.where(mask, sc, _NEG_INF)
-        m_new = jnp.maximum(m, sc.max(axis=-1, keepdims=True))
-        p = jnp.where(mask, jnp.exp(sc - m_new), 0.0)
-        alpha = jnp.exp(m - m_new)
-        l = l * alpha + p.sum(axis=-1, keepdims=True)
-        if quant:
-            v_s = jnp.take(v_scale, phys, axis=0).astype(jnp.float32)
-            p = p * jnp.moveaxis(v_s, 1, 2)  # fold v scales into the probs
-        acc = acc * alpha + jnp.einsum(
-            "bhc,bchd->bhd", p, v_c.astype(jnp.float32)
-        )
-        return (m_new, l, acc, j + 1), None
-
-    b = q.shape[0]
-    carry0 = (
-        jnp.full((b, h, 1), _NEG_INF, jnp.float32),
-        jnp.zeros((b, h, 1), jnp.float32),
-        jnp.zeros((b, h, d), jnp.float32),
-        jnp.int32(0),
-    )
-    (m, l, acc, _), _ = jax.lax.scan(step, carry0, cols)
-    return (acc / jnp.maximum(l, 1e-30)).astype(q.dtype)
-
-
 def dense_paged_verify_attention(
     q: jax.Array,
     k_pool: jax.Array,
     v_pool: jax.Array,
     kv_len: jax.Array,
     block_tables: jax.Array,
+    layer,
     k_scale: jax.Array | None = None,
     v_scale: jax.Array | None = None,
 ) -> jax.Array:
-    """Reference VERIFY-TILE attention over a paged cache (ISSUE 11):
-    q ``[B, T, H, D]`` — the row's last accepted token plus T-1 draft
-    tokens, whose K/V have already been written into the pool at logical
-    positions ``kv_len - T .. kv_len - 1`` — against pool blocks
-    addressed through the block tables. CAUSAL inside the tile: query t
-    attends logical positions ``< kv_len - T + 1 + t``, so position 0
-    scores exactly like a single-token decode step and each draft
-    position additionally sees the drafts before it.
+    """Reference attention over a PAGED cache, for a small query tile
+    (ISSUE 11): q ``[B, T, H, D]`` — the row's last accepted token plus
+    T-1 draft tokens, whose K/V have already been written into the pool
+    at logical positions ``kv_len - T .. kv_len - 1`` — against layer
+    ``layer`` of the stacked lane-dense pools ``[L, N, bs, H*D]``,
+    addressed through per-row block tables ``[B, M]`` (row b's logical
+    positions ``[j*bs, (j+1)*bs)`` live in pool block
+    ``block_tables[b, j]``). CAUSAL inside the tile: query t attends
+    logical positions ``< kv_len - T + 1 + t``, so position 0 scores
+    exactly like a single-token decode step and each draft position
+    additionally sees the drafts before it. With ``k_scale``/``v_scale``
+    (``[L, N, H*bs]``: a block's scales are one row, heads major) the
+    pool is quantized and the scales fold into the score strip /
+    probability rows per chunk.
 
-    Streams one bounded ``[B, bs, H, D]`` block per table column through
-    the same online-softmax ``lax.scan`` as the q_len=1 reference — the
-    no-logical-view contract is unchanged; the tile only widens the
-    score strip to ``[B, H, T, bs]``. fp32 softmax throughout."""
-    _, bs, h, d = k_pool.shape
-    b, t, _, _ = q.shape
+    Deliberately NOT "gather the logical cache, call the contiguous
+    reference": that materializes an ``M*bs >= seq_len``-wide tensor
+    every decode step — exactly the full-context array the block pool
+    exists to avoid (and the graft-lint mutation gate for the paged
+    program). Instead the table columns stream through an online-softmax
+    ``lax.scan``: each iteration gathers ONE bounded ``[B, bs, H*D]``
+    block per row straight out of the stack (``pool[layer, phys]`` —
+    gather at the boundary, the arXiv 2112.01075 discipline; the layer's
+    slice of the pool is never cut out) and merges with the standard
+    log-sum-exp rescale; the tile only widens the score strip to
+    ``[B, H, T, bs]``. fp32 softmax throughout (the decode numerics
+    contract)."""
+    bs = k_pool.shape[2]
+    b, t, h, d = q.shape
     quant = k_scale is not None
     q32 = q.astype(jnp.float32)
     inv = 1.0 / np.sqrt(d)
+    layer = jnp.asarray(layer, jnp.int32)
     cols = block_tables.astype(jnp.int32).T  # [M, B] physical ids per step
     # Per-(row, query) occupancy: query t of row b covers base[b] + t.
     base = kv_len.astype(jnp.int32) - (t - 1)  # length at query 0
@@ -306,14 +257,15 @@ def dense_paged_verify_attention(
 
     def step(carry, phys):
         m, l, acc, j = carry
-        k_c = jnp.take(k_pool, phys, axis=0)  # [B, bs, H, D] — bounded
-        v_c = jnp.take(v_pool, phys, axis=0)
+        # [B, bs, H*D] -> [B, bs, H, D]: bounded, and a free reshape.
+        k_c = k_pool[layer, phys].reshape(b, bs, h, d)
+        v_c = v_pool[layer, phys].reshape(b, bs, h, d)
         sc = jnp.einsum(
             "bthd,bchd->bhtc", q32, k_c.astype(jnp.float32)
         )  # [B, H, T, bs]
         if quant:
-            k_s = jnp.take(k_scale, phys, axis=0).astype(jnp.float32)
-            sc = sc * jnp.transpose(k_s, (0, 2, 1))[:, :, None, :]
+            k_s = k_scale[layer, phys].astype(jnp.float32)  # [B, H*bs]
+            sc = sc * k_s.reshape(b, h, 1, bs)
         sc = sc * inv
         kpos = j * bs + jnp.arange(bs)
         mask = kpos[None, None, None, :] < qlen[:, None, :, None]
@@ -323,8 +275,8 @@ def dense_paged_verify_attention(
         alpha = jnp.exp(m - m_new)
         l = l * alpha + p.sum(axis=-1, keepdims=True)
         if quant:
-            v_s = jnp.take(v_scale, phys, axis=0).astype(jnp.float32)
-            p = p * jnp.transpose(v_s, (0, 2, 1))[:, :, None, :]
+            v_s = v_scale[layer, phys].astype(jnp.float32)
+            p = p * v_s.reshape(b, h, 1, bs)
         acc = acc * alpha + jnp.einsum(
             "bhtc,bchd->bhtd", p, v_c.astype(jnp.float32)
         )
@@ -339,6 +291,26 @@ def dense_paged_verify_attention(
     (m, l, acc, _), _ = jax.lax.scan(step, carry0, cols)
     out = (acc / jnp.maximum(l, 1e-30)).astype(q.dtype)  # [B, H, T, D]
     return jnp.swapaxes(out, 1, 2)  # [B, T, H, D]
+
+
+def dense_paged_decode_attention(
+    q: jax.Array,
+    k_pool: jax.Array,
+    v_pool: jax.Array,
+    kv_len: jax.Array,
+    block_tables: jax.Array,
+    layer,
+    k_scale: jax.Array | None = None,
+    v_scale: jax.Array | None = None,
+) -> jax.Array:
+    """Reference single-token decode over a paged cache: q ``[B, H, D]``,
+    keys at logical positions >= ``kv_len[b]`` masked out — the T=1 tile
+    of ``dense_paged_verify_attention``, as the kernel's decode step is
+    the T=1 tile of the verify kernel."""
+    return dense_paged_verify_attention(
+        q[:, None], k_pool, v_pool, kv_len, block_tables, layer,
+        k_scale, v_scale,
+    )[:, 0]
 
 
 # ------------------------------------------------------------------ kernel
@@ -445,45 +417,103 @@ def _decode_kernel_quant(len_ref, q_ref, k_ref, ks_ref, v_ref, vs_ref,
         o_ref[0] = (acc_ref[:] / l_safe).astype(o_ref.dtype)
 
 
-def _paged_verify_kernel(len_ref, tbl_ref, q_ref, k_ref, v_ref, o_ref,
-                         m_ref, l_ref, acc_ref, *, block_k, q_len, scale):
+def _paged_verify_kernel(len_ref, tbl_ref, layer_ref, q_ref, *refs,
+                         block_k, q_len, heads, scale, quant):
     """THE paged kernel — one (batch row, logical block) program over a
-    small [T, H, D] query tile; single-token decode is the T=1 tile (a
-    dedicated q_len=1 kernel would be a batched mat-vec whose left
-    operand has no free dimension, which Mosaic refuses). The block
-    table is consumed by the INDEX MAPS (it rides the scalar-prefetch
-    channel, so the physical block id is known before the body runs and
-    the DMA fetches pool block ``tbl_ref[b, j]`` directly). Pool blocks
-    arrive in their storage layout ``(bs, H, D)``, so the dots batch
-    over the MIDDLE heads dim instead of transposing the pool; scores
-    are [H, T, Bk]. The causal mask is applied INSIDE the chunk loop —
-    query t of a row at total occupancy ``len_ref[b]`` admits keys at
-    logical positions ``< len - (T-1) + t`` (for T=1: ``< len``).
-    Running max/denominator/accumulator carry the T dim in VMEM
-    scratch."""
+    small query tile; single-token decode is the T=1 tile (a dedicated
+    q_len=1 kernel would be a batched mat-vec whose left operand has no
+    free dimension, which Mosaic refuses). The block table and the layer
+    index are consumed by the INDEX MAPS (they ride the scalar-prefetch
+    channel, so the DMA fetches block ``tbl_ref[b, j]`` of layer
+    ``layer_ref[0]`` straight out of the stacked pool).
+
+    Everything is LANE-DENSE: a pool block arrives as it is stored,
+    ``(bs, H*D)`` — a token's K row is H*D contiguous values — and Mosaic
+    refuses to split that minor dimension into ``(H, D)`` inside a
+    kernel. So the heads are separated by a 0/1 mask instead of a
+    reshape: row ``t*H + h`` of ``qh`` holds query t with every lane
+    outside head h zeroed, and ONE ``[T*H, H*D] x [bs, H*D]^T`` product
+    gives all heads' scores ``[T*H, bs]`` (the zeros take the other
+    heads' lanes out of the contraction); ``p @ v_blk`` then gives
+    ``[T*H, H*D]``, of which row ``t*H + h`` is wanted in head h's lanes
+    only, and the same mask picks those at the end. The causal mask is
+    applied INSIDE the chunk loop — query t of a row at total occupancy
+    ``len_ref[b]`` admits keys at logical positions ``< len - (T-1) + t``
+    (for T=1: ``< len``). Running max / denominator / accumulator live
+    in VMEM scratch, fp32.
+
+    ``quant``: the pool is 1-byte; blocks are upcast in VMEM and the
+    per-(position, head) scales — a block's are ONE lane-dense row
+    ``(1, H*bs)``, heads major, spread to ``[H, bs]`` by a mask and a
+    0/1 product for the same reason — fold into the score strip /
+    probability rows after the dots: the same per-chunk dequantize
+    contract as ``_decode_kernel_quant``."""
+    if quant:
+        k_ref, ks_ref, v_ref, vs_ref, o_ref, qh_ref, m_ref, l_ref, acc_ref = refs
+    else:
+        k_ref, v_ref, o_ref, qh_ref, m_ref, l_ref, acc_ref = refs
     b_, j = pl.program_id(0), pl.program_id(1)
     n_k = pl.num_programs(1)
     length = len_ref[b_]
+    rows, f = qh_ref.shape  # T*H, H*D
+    hd = f // heads
+
+    def tile(piece):  # [H, ...] per query position -> [T*H, ...]
+        return jnp.concatenate([piece(t) for t in range(q_len)], axis=0)
+
+    def own_lanes(width):  # [H, H*width]: lane belongs to the row's head
+        lo = lax.broadcasted_iota(jnp.int32, (heads, heads * width), 0) * width
+        lane = lax.broadcasted_iota(jnp.int32, (heads, heads * width), 1)
+        return (lane >= lo) & (lane < lo + width)
+
+    def head_mask():  # [T*H, H*D]
+        own = own_lanes(hd)
+        return tile(lambda t: own)
+
+    def scales(ref):  # the block's scale row, heads major -> [T*H, Bk]
+        # The DMA brings the aligned group of _SCALE_ROWS pool rows that
+        # holds the block's (``_paged_index_map``); pick it out.
+        jj = jnp.minimum(j, jnp.maximum((length - 1) // block_k, 0))
+        mine = tbl_ref[b_, jj] % _SCALE_ROWS
+        group = ref[:].astype(jnp.float32)  # (_SCALE_ROWS, H*Bk)
+        at = lax.broadcasted_iota(jnp.int32, group.shape, 0)
+        row = jnp.where(at == mine, group, 0.0).sum(axis=0, keepdims=True)
+        row = jnp.where(own_lanes(block_k), row, 0.0)  # (H, H*Bk)
+        x = lax.broadcasted_iota(jnp.int32, (heads * block_k, block_k), 0)
+        c = lax.broadcasted_iota(jnp.int32, (heads * block_k, block_k), 1)
+        # one nonzero term a sum: exact at any matmul precision (the
+        # scales are stored in bf16).
+        per_head = jnp.dot(
+            row, ((x & (block_k - 1)) == c).astype(jnp.float32),
+            preferred_element_type=jnp.float32,
+        )  # [H, Bk]
+        return tile(lambda t: per_head)
 
     @pl.when(j == 0)
     def _init():
         m_ref[:] = jnp.full_like(m_ref, _NEG_INF)
         l_ref[:] = jnp.zeros_like(l_ref)
         acc_ref[:] = jnp.zeros_like(acc_ref)
+        q = q_ref[0].astype(jnp.float32)  # (T, H*D)
+        q_rows = tile(lambda t: jnp.broadcast_to(q[t:t + 1], (heads, f)))
+        qh_ref[:] = jnp.where(head_mask(), q_rows, 0.0).astype(qh_ref.dtype)
 
     @pl.when(j * block_k < length)
     def _step():
-        q = q_ref[0]  # (T, H, D)
-        k_blk = k_ref[0]  # (Bk, H, D) — pool-block storage layout
-        v_blk = v_ref[0]
-        # (T, H, D) x (Bk, H, D) -> (H, T, Bk): batch over H, contract D.
+        k_blk, v_blk = k_ref[0], v_ref[0]  # (Bk, H*D), as stored
+        if quant:
+            k_blk = k_blk.astype(jnp.float32)  # VMEM upcast
+            v_blk = v_blk.astype(jnp.float32)
+        # (T*H, H*D) x (Bk, H*D)^T -> (T*H, Bk)
         s = lax.dot_general(
-            q, k_blk,
-            dimension_numbers=(((2,), (2,)), ((1,), (1,))),
+            qh_ref[:], k_blk,
+            dimension_numbers=(((1,), (1,)), ((), ())),
             preferred_element_type=jnp.float32,
         ) * scale
-        kpos = j * block_k + lax.broadcasted_iota(jnp.int32, s.shape, 2)
-        tpos = lax.broadcasted_iota(jnp.int32, s.shape, 1)
+        if quant:
+            s = s * scales(ks_ref)
+        kpos = j * block_k + lax.broadcasted_iota(jnp.int32, s.shape, 1)
+        tpos = tile(lambda t: jnp.full((heads, 1), t, jnp.int32))
         s = jnp.where(kpos < length - (q_len - 1) + tpos, s, _NEG_INF)
         m = m_ref[:]
         m_new = jnp.maximum(m, s.max(axis=-1, keepdims=True))
@@ -491,71 +521,26 @@ def _paged_verify_kernel(len_ref, tbl_ref, q_ref, k_ref, v_ref, o_ref,
         alpha = jnp.exp(m - m_new)
         m_ref[:] = m_new
         l_ref[:] = l_ref[:] * alpha + p.sum(axis=-1, keepdims=True)
-        # (H, T, Bk) x (Bk, H, D) -> (H, T, D): batch H, contract Bk.
-        acc_ref[:] = acc_ref[:] * alpha + lax.dot_general(
+        if quant:
+            p = p * scales(vs_ref)
+        # (T*H, Bk) x (Bk, H*D) -> (T*H, H*D)
+        acc_ref[:] = acc_ref[:] * alpha + jnp.dot(
             p.astype(v_blk.dtype), v_blk,
-            dimension_numbers=(((2,), (0,)), ((0,), (1,))),
             preferred_element_type=jnp.float32,
         )
 
     @pl.when(j == n_k - 1)
     def _finish():
-        l_safe = jnp.maximum(l_ref[:], 1e-30)
-        o_ref[0] = jnp.swapaxes(acc_ref[:] / l_safe, 0, 1).astype(
-            o_ref.dtype
+        own = jnp.where(
+            head_mask(), acc_ref[:] / jnp.maximum(l_ref[:], 1e-30), 0.0
         )
-
-
-def _paged_verify_kernel_quant(len_ref, tbl_ref, q_ref, k_ref, ks_ref,
-                               v_ref, vs_ref, o_ref, m_ref, l_ref, acc_ref,
-                               *, block_k, q_len, scale):
-    """Quantized-pool sibling: 1-byte blocks upcast in VMEM, the
-    per-(position, head) scales fold into the [H, T, Bk] score strip /
-    probability rows after the dots — same per-chunk dequantize contract
-    as ``_decode_kernel_quant``, addressed through the block table."""
-    b_, j = pl.program_id(0), pl.program_id(1)
-    n_k = pl.num_programs(1)
-    length = len_ref[b_]
-
-    @pl.when(j == 0)
-    def _init():
-        m_ref[:] = jnp.full_like(m_ref, _NEG_INF)
-        l_ref[:] = jnp.zeros_like(l_ref)
-        acc_ref[:] = jnp.zeros_like(acc_ref)
-
-    @pl.when(j * block_k < length)
-    def _step():
-        q = q_ref[0].astype(jnp.float32)  # (T, H, D)
-        k_blk = k_ref[0].astype(jnp.float32)  # (Bk, H, D) — VMEM upcast
-        v_blk = v_ref[0].astype(jnp.float32)
-        k_s = ks_ref[0]  # (Bk, H) fp32 scales
-        v_s = vs_ref[0]
-        s = lax.dot_general(
-            q, k_blk,
-            dimension_numbers=(((2,), (2,)), ((1,), (1,))),
-            preferred_element_type=jnp.float32,
-        ) * jnp.swapaxes(k_s, 0, 1)[:, None, :] * scale
-        kpos = j * block_k + lax.broadcasted_iota(jnp.int32, s.shape, 2)
-        tpos = lax.broadcasted_iota(jnp.int32, s.shape, 1)
-        s = jnp.where(kpos < length - (q_len - 1) + tpos, s, _NEG_INF)
-        m = m_ref[:]
-        m_new = jnp.maximum(m, s.max(axis=-1, keepdims=True))
-        p = jnp.exp(s - m_new)
-        alpha = jnp.exp(m - m_new)
-        m_ref[:] = m_new
-        l_ref[:] = l_ref[:] * alpha + p.sum(axis=-1, keepdims=True)
-        acc_ref[:] = acc_ref[:] * alpha + lax.dot_general(
-            p * jnp.swapaxes(v_s, 0, 1)[:, None, :], v_blk,
-            dimension_numbers=(((2,), (0,)), ((0,), (1,))),
-            preferred_element_type=jnp.float32,
-        )
-
-    @pl.when(j == n_k - 1)
-    def _finish():
-        l_safe = jnp.maximum(l_ref[:], 1e-30)
-        o_ref[0] = jnp.swapaxes(acc_ref[:] / l_safe, 0, 1).astype(
-            o_ref.dtype
-        )
+        o_ref[0] = jnp.concatenate(
+            [
+                own[t * heads:(t + 1) * heads].sum(axis=0, keepdims=True)
+                for t in range(q_len)
+            ],
+            axis=0,
+        ).astype(o_ref.dtype)
 
 
 def _kv_index_map(block_k):
@@ -642,30 +627,31 @@ def _flash_decode_quant(q, k, k_scale, v, v_scale, kv_len, *, block_k,
     )(kv_len, q, k, k_scale, v, v_scale)
 
 
-def _paged_kv_index_map(block_k):
-    """The block-table gather: logical block j of row b DMAs POOL block
-    ``tbl_ref[b, j]``. Blocks entirely past the row's occupancy re-
-    reference the last occupied block (their compute is skipped by
-    ``pl.when``) — the same clamp discipline as ``_kv_index_map``, with
-    the table lookup composed on top. Both the lengths and the table
-    ride the scalar-prefetch channel, so the physical id is available
-    to the DMA before the kernel body runs."""
+#: A block's scales are ONE row of the ``[L, N, H*bs]`` scale pool, and
+#: Mosaic moves rows in aligned groups of eight: the DMA fetches the group
+#: and the kernel picks its row.
+_SCALE_ROWS = 8
 
-    def index_map(b_, j, len_ref, tbl_ref):
+
+def _paged_index_map(block_k, rows=1):
+    """The block-table gather: logical block j of row b DMAs block
+    ``tbl_ref[b, j]`` of layer ``layer_ref[0]`` out of the STACKED pool,
+    where it lies — a K/V pool ``[L, N, bs, H*D]`` (``rows`` 1: the
+    block itself) or a scale pool ``[L, N, H*bs]`` (``rows``
+    ``_SCALE_ROWS``: the aligned group of rows that holds the block's).
+    Blocks entirely past the row's occupancy re-reference the last
+    occupied block (their compute is skipped by ``pl.when``) — the same
+    clamp discipline as ``_kv_index_map``, with the table lookup
+    composed on top. Lengths, table and layer all ride the
+    scalar-prefetch channel, so the physical address is available to
+    the DMA before the kernel body runs."""
+
+    def index_map(b_, j, len_ref, tbl_ref, layer_ref):
         last = jnp.maximum((len_ref[b_] - 1) // block_k, 0)
-        jj = jnp.minimum(j, last)
-        return (tbl_ref[b_, jj], 0, 0, 0)
-
-    return index_map
-
-
-def _paged_scale_index_map(block_k):
-    """The ``[N, bs, H]`` scale pools' twin of ``_paged_kv_index_map``."""
-
-    def index_map(b_, j, len_ref, tbl_ref):
-        last = jnp.maximum((len_ref[b_] - 1) // block_k, 0)
-        jj = jnp.minimum(j, last)
-        return (tbl_ref[b_, jj], 0, 0)
+        blk = tbl_ref[b_, jnp.minimum(j, last)]
+        if rows > 1:
+            return (layer_ref[0], blk // rows, 0)
+        return (layer_ref[0], blk, 0, 0)
 
     return index_map
 
@@ -814,213 +800,65 @@ def decode_attention(
     return fn(q, k, v, kv_len, k_scale, v_scale)
 
 
-def _local_paged_decode(q, k_pool, v_pool, kv_len, tables, *, impl,
-                        interpret, k_scale=None, v_scale=None):
-    """Paged decode attention on LOCAL (already per-shard) arrays; the
-    paged twin of ``_local_decode`` with the same impl routing and
-    fallback contract."""
-    quant = k_scale is not None
-
-    def dense():
-        return dense_paged_decode_attention(
-            q, k_pool, v_pool, kv_len, tables, k_scale, v_scale
-        )
-
-    if impl == "dense":
-        return dense()
-    if impl != "flash":
-        raise KeyError(
-            f"unknown decode_attention impl {impl!r} (dense | flash)"
-        )
-    if interpret is None:
-        interpret = FORCE_INTERPRET
-    bs, d = k_pool.shape[1], q.shape[-1]
-    # The pool block IS the kernel chunk: it must be a tileable size on
-    # its own (the contiguous kernel gets to pick a divisor; a paged
-    # kernel cannot re-chunk across physical blocks).
-    tileable = bs >= 8 and (bs & (bs - 1)) == 0 and d % 32 == 0
-    if not tileable:
-        if jax.default_backend() == "tpu":
-            _warn_fallback(
-                "paged flash-decode falling back to dense: block geometry "
-                f"(bs={bs}, head_dim={d}) is not tileable (need a "
-                "power-of-two block size >= 8 and head_dim % 32 == 0)"
-            )
-        return dense()
-    if interpret is None:
-        if jax.default_backend() != "tpu":
-            return dense()
-        interpret = False
-    lens = jnp.maximum(kv_len.astype(jnp.int32), 1)
-    tbl = tables.astype(jnp.int32)
-    # Single-token decode is the T=1 verify tile (``_paged_verify_kernel``).
-    qt = q[:, None]
-    if quant:
-        o = _flash_paged_verify(
-            qt, k_pool, v_pool, lens, tbl, interpret=interpret,
-            name="attn_paged_decode",
-            k_scale=k_scale.astype(jnp.float32),
-            v_scale=v_scale.astype(jnp.float32),
-        )
-    else:
-        o = _flash_paged_verify(
-            qt, k_pool, v_pool, lens, tbl, interpret=interpret,
-            name="attn_paged_decode",
-        )
-    return o[:, 0]
-
-
-def paged_decode_attention(
-    q: jax.Array,
-    k_pool: jax.Array,
-    v_pool: jax.Array,
-    kv_len: jax.Array,
-    block_tables: jax.Array,
-    *,
-    k_scale: jax.Array | None = None,
-    v_scale: jax.Array | None = None,
-    impl: str = "flash",
-    interpret: bool | None = None,
-) -> jax.Array:
-    """Single-token decode attention over a PAGED (block-pool) KV cache —
-    the paged sibling of ``decode_attention`` and the one entry point the
-    block-table decode path (models/gpt.py paged branch, serving engine)
-    routes through.
-
-    q ``[B, H, D]``; pools ``[N, bs, H, D]`` (block-major storage — the
-    layout serving/engine.py grafts prefilled blocks into); ``kv_len
-    [B]`` int32 logical occupancy; ``block_tables [B, M]`` int32 mapping
-    logical block j of row b to a physical pool block. With
-    ``k_scale``/``v_scale`` (``[N, bs, H]``, both or neither) the pool
-    is quantized and every branch dequantizes per block.
-
-    Sharding: the pool carries NO batch axis — blocks are shared across
-    rows (that is the whole point), so under a live ``model`` axis the
-    pool shards over HEADS only (``P(None, None, 'model', None)``, the
-    paged analog of the ``_constrain_kv_cache`` layout) and is
-    replicated over the batch axes, while q / lengths / tables shard
-    over batch when divisible. Each shard then attends its local heads
-    of its local rows against its full local-head pool — zero
-    collectives here, same as the contiguous path.
-    """
-    from frl_distributed_ml_scaffold_tpu.dist.mesh import (
-        BATCH_AXES,
-        current_mesh_env,
-        shard_map_unchecked,
-    )
-
-    if (k_scale is None) != (v_scale is None):
-        raise ValueError(
-            "k_scale and v_scale must be passed together (a quantized "
-            "pool quantizes both of its halves)"
-        )
-    env = current_mesh_env()
-    m = env.axis_size("model") if env is not None else 1
-    h = q.shape[1]
-    if env is None or m <= 1 or h % m != 0:
-        return _local_paged_decode(
-            q, k_pool, v_pool, kv_len, block_tables, impl=impl,
-            interpret=interpret, k_scale=k_scale, v_scale=v_scale,
-        )
-    batch = BATCH_AXES if q.shape[0] % env.batch_axis_size == 0 else None
-    q_spec = P(batch, "model", None)
-    pool_spec = P(None, None, "model", None)
-    tbl_spec = P(batch, None)
-    if k_scale is None:
-        fn = shard_map_unchecked(
-            functools.partial(
-                _local_paged_decode, impl=impl, interpret=interpret
-            ),
-            mesh=env.mesh,
-            in_specs=(q_spec, pool_spec, pool_spec, P(batch), tbl_spec),
-            out_specs=q_spec,
-        )
-        return fn(q, k_pool, v_pool, kv_len, block_tables)
-    sc_spec = P(None, None, "model")
-    fn = shard_map_unchecked(
-        lambda q_, k_, v_, l_, t_, ks_, vs_: _local_paged_decode(
-            q_, k_, v_, l_, t_, impl=impl, interpret=interpret,
-            k_scale=ks_, v_scale=vs_,
-        ),
-        mesh=env.mesh,
-        in_specs=(q_spec, pool_spec, pool_spec, P(batch), tbl_spec,
-                  sc_spec, sc_spec),
-        out_specs=q_spec,
-    )
-    return fn(q, k_pool, v_pool, kv_len, block_tables, k_scale, v_scale)
-
-
-# ------------------------------------------------------ speculative verify
-
-
-def _flash_paged_verify(q, k_pool, v_pool, kv_len, tables, *, interpret,
-                        name, k_scale=None, v_scale=None):
-    """q ``[B, T, H, D]``, pools ``[N, bs, H, D]`` (+ optional
-    ``[N, bs, H]`` fp32 scales), tables ``[B, M]`` int32 ->
-    ``[B, T, H, D]``. Grid is (rows, logical blocks); block_k == the
-    pool's block size; the scratch accumulators carry the T dim. The
-    kernel serves a decode step (T=1) and a verify tile: its caller
+def _flash_paged_verify(q, k_pool, v_pool, kv_len, tables, layer, *,
+                        interpret, name, k_scale=None, v_scale=None):
+    """q ``[B, T, H, D]``, stacked pools ``[L, N, bs, H*D]`` (+ optional
+    ``[L, N, H*bs]`` scales), tables ``[B, M]`` int32, ``layer`` int32
+    ``[1]`` -> ``[B, T, H, D]``. Grid is (rows, logical blocks); block_k
+    == the pool's block size; the scratch accumulators carry the T dim.
+    The kernel serves a decode step (T=1) and a verify tile: its caller
     names it (``attn_paged_decode`` / ``attn_paged_verify``; the
     quantized pool's kernel adds ``_quant``), and that is what a device
     trace calls it."""
     b, t, h, d = q.shape
-    _, bs, _, _ = k_pool.shape
-    n_k = tables.shape[1]
-    q_spec = pl.BlockSpec((1, t, h, d), lambda b_, j, *_refs: (b_, 0, 0, 0))
-    kv_spec = pl.BlockSpec((1, bs, h, d), _paged_kv_index_map(bs))
-    scratch = [
-        pltpu.VMEM((h, t, 1), jnp.float32),  # running max
-        pltpu.VMEM((h, t, 1), jnp.float32),  # running denom
-        pltpu.VMEM((h, t, d), jnp.float32),  # output accumulator
-    ]
-    if k_scale is None:
-        grid_spec = pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=2,
-            grid=(b, n_k),
-            in_specs=[q_spec, kv_spec, kv_spec],
-            out_specs=q_spec,
-            scratch_shapes=scratch,
-        )
-        return pl.pallas_call(
-            functools.partial(
-                _paged_verify_kernel, block_k=bs, q_len=t,
-                scale=1.0 / np.sqrt(d),
-            ),
-            grid_spec=grid_spec,
-            out_shape=jax.ShapeDtypeStruct(q.shape, q.dtype),
-            interpret=interpret,
-            name=name,
-        )(kv_len, tables, q, k_pool, v_pool)
-    sc_spec = pl.BlockSpec((1, bs, h), _paged_scale_index_map(bs))
-    grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=2,
-        grid=(b, n_k),
-        in_specs=[q_spec, kv_spec, sc_spec, kv_spec, sc_spec],
-        out_specs=q_spec,
-        scratch_shapes=scratch,
+    bs, f = k_pool.shape[2], h * d
+    quant = k_scale is not None
+    q_spec = pl.BlockSpec((1, t, f), lambda b_, j, *_refs: (b_, 0, 0))
+    kv_spec = pl.BlockSpec((None, 1, bs, f), _paged_index_map(bs))
+    sc_spec = pl.BlockSpec(
+        (None, _SCALE_ROWS, h * bs), _paged_index_map(bs, _SCALE_ROWS)
     )
-    return pl.pallas_call(
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=3,
+        grid=(b, tables.shape[1]),
+        in_specs=[q_spec]
+        + ([kv_spec, sc_spec, kv_spec, sc_spec] if quant
+           else [kv_spec, kv_spec]),
+        out_specs=q_spec,
+        scratch_shapes=[
+            # the query tile spread over heads; fp32 against a 1-byte pool
+            pltpu.VMEM((t * h, f), jnp.float32 if quant else q.dtype),
+            pltpu.VMEM((t * h, 1), jnp.float32),  # running max
+            pltpu.VMEM((t * h, 1), jnp.float32),  # running denom
+            pltpu.VMEM((t * h, f), jnp.float32),  # output accumulator
+        ],
+    )
+    pools = (
+        (k_pool, k_scale, v_pool, v_scale) if quant else (k_pool, v_pool)
+    )
+    out = pl.pallas_call(
         functools.partial(
-            _paged_verify_kernel_quant, block_k=bs, q_len=t,
-            scale=1.0 / np.sqrt(d),
+            _paged_verify_kernel, block_k=bs, q_len=t, heads=h,
+            scale=1.0 / np.sqrt(d), quant=quant,
         ),
         grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct(q.shape, q.dtype),
+        out_shape=jax.ShapeDtypeStruct((b, t, f), q.dtype),
         interpret=interpret,
-        name=name + "_quant",
-    )(kv_len, tables, q, k_pool, k_scale, v_pool, v_scale)
+        name=name + ("_quant" if quant else ""),
+    )(kv_len, tables, layer, q.reshape(b, t, f), *pools)
+    return out.reshape(b, t, h, d)
 
 
-def _local_paged_verify(q, k_pool, v_pool, kv_len, tables, *, impl,
-                        interpret, k_scale=None, v_scale=None):
-    """Verify-tile attention on LOCAL (already per-shard) arrays; the
-    tile twin of ``_local_paged_decode`` with the same impl routing and
+def _local_paged_verify(q, k_pool, v_pool, kv_len, tables, layer,
+                        k_scale=None, v_scale=None, *, impl, interpret,
+                        name):
+    """Paged tile attention on LOCAL (already per-shard) arrays; the
+    paged twin of ``_local_decode`` with the same impl routing and
     fallback contract."""
-    quant = k_scale is not None
 
     def dense():
         return dense_paged_verify_attention(
-            q, k_pool, v_pool, kv_len, tables, k_scale, v_scale
+            q, k_pool, v_pool, kv_len, tables, layer, k_scale, v_scale
         )
 
     if impl == "dense":
@@ -1031,32 +869,31 @@ def _local_paged_verify(q, k_pool, v_pool, kv_len, tables, *, impl,
         )
     if interpret is None:
         interpret = FORCE_INTERPRET
-    bs, d = k_pool.shape[1], q.shape[-1]
-    tileable = bs >= 8 and (bs & (bs - 1)) == 0 and d % 32 == 0
+    bs, f = k_pool.shape[2], k_pool.shape[3]
+    # The pool block IS the kernel chunk: it must be a tileable size on
+    # its own (the contiguous kernel gets to pick a divisor; a paged
+    # kernel cannot re-chunk across physical blocks), and a token's row
+    # must fill whole 128-lane tiles.
+    tileable = bs >= 8 and (bs & (bs - 1)) == 0 and f % 128 == 0
     if not tileable:
         if jax.default_backend() == "tpu":
             _warn_fallback(
-                "paged verify falling back to dense: block geometry "
-                f"(bs={bs}, head_dim={d}) is not tileable (need a "
-                "power-of-two block size >= 8 and head_dim % 32 == 0)"
+                "paged flash-decode falling back to dense: block geometry "
+                f"(bs={bs}, heads*head_dim={f}) is not tileable (need a "
+                "power-of-two block size >= 8 and heads*head_dim % 128 "
+                "== 0)"
             )
         return dense()
     if interpret is None:
         if jax.default_backend() != "tpu":
+            # Identical numerics, no interpreter slowdown — the same
+            # silent off-TPU contract as flash_attention.
             return dense()
         interpret = False
-    lens = jnp.maximum(kv_len.astype(jnp.int32), 1)
-    tbl = tables.astype(jnp.int32)
-    if quant:
-        return _flash_paged_verify(
-            q, k_pool, v_pool, lens, tbl, interpret=interpret,
-            name="attn_paged_verify",
-            k_scale=k_scale.astype(jnp.float32),
-            v_scale=v_scale.astype(jnp.float32),
-        )
     return _flash_paged_verify(
-        q, k_pool, v_pool, lens, tbl, interpret=interpret,
-        name="attn_paged_verify",
+        q, k_pool, v_pool, jnp.maximum(kv_len.astype(jnp.int32), 1),
+        tables.astype(jnp.int32), jnp.asarray(layer, jnp.int32).reshape(1),
+        interpret=interpret, name=name, k_scale=k_scale, v_scale=v_scale,
     )
 
 
@@ -1066,28 +903,50 @@ def paged_verify_attention(
     v_pool: jax.Array,
     kv_len: jax.Array,
     block_tables: jax.Array,
+    layer,
     *,
     k_scale: jax.Array | None = None,
     v_scale: jax.Array | None = None,
     impl: str = "flash",
     interpret: bool | None = None,
+    name: str = "attn_paged_verify",
 ) -> jax.Array:
-    """Speculative VERIFY-TILE attention over a paged KV cache (ISSUE
-    11) — the small-q-tile sibling of ``paged_decode_attention`` and the
-    one entry point the verify step (models/gpt.py paged branch with
-    t > 1, serving engine ``_verify_fn``) routes through.
+    """Attention of a small query TILE over the PAGED (block-pool) KV
+    cache — the one entry point the block-table decode path (models/gpt.py
+    paged branch, serving engine) routes through: the speculative verify
+    step (ISSUE 11) with T = k+1, and through ``paged_decode_attention``
+    every plain decode step as its T=1 tile.
 
-    q ``[B, T, H, D]`` — T = k+1 positions per row (last accepted token
-    + k drafts), whose K/V have already been scattered into the pool at
+    q ``[B, T, H, D]`` — T positions per row (last accepted token + T-1
+    drafts), whose K/V have already been scattered into the pool at
     logical positions ``kv_len - T .. kv_len - 1``; ``kv_len [B]`` is
-    each row's TOTAL occupancy including the tile. Causality is per
-    query position inside the tile: query t attends logical positions
-    ``< kv_len - T + 1 + t``, so query 0 computes exactly what a
-    single-token decode step would and every draft position additionally
-    sees the drafts before it — which is what makes greedy acceptance
-    exact (token-identity with ``generate()``). Sharding is identical to
-    the q_len=1 entry: the pool shards over heads only and is replicated
-    over batch; q/lengths/tables ride the batch axes."""
+    each row's TOTAL occupancy including the tile; ``block_tables
+    [B, M]`` int32 maps logical block j of row b to a physical pool
+    block. The pools are the model's cache leaves AS THEY ARE STORED:
+    all layers stacked, lane-dense, ``[L, N, bs, H*D]`` (a token's K row
+    is H*D contiguous values, heads major), and ``layer`` (an int32
+    scalar, traced inside the layer loop) says which layer's blocks to
+    read — the kernel's index maps address the stack directly, so no
+    layer's slice of the pool is ever cut out or copied. With
+    ``k_scale``/``v_scale`` (``[L, N, H*bs]``: a block's per-(position,
+    head) scales as one row, heads major; both or neither) the pool is
+    quantized and every branch dequantizes per block.
+
+    Causality is per query position inside the tile: query t attends
+    logical positions ``< kv_len - T + 1 + t``, so query 0 computes
+    exactly what a single-token decode step would and every draft
+    position additionally sees the drafts before it — which is what
+    makes greedy acceptance exact (token-identity with ``generate()``).
+
+    Sharding: the pool carries NO batch axis — blocks are shared across
+    rows (that is the whole point), so under a live ``model`` axis the
+    pool shards over HEADS only — the major part of its last dimension,
+    ``P(None, None, None, 'model')``, the paged analog of the
+    ``_constrain_kv_cache`` layout — and is replicated over the batch
+    axes, while q / lengths / tables shard over batch when divisible.
+    Each shard then attends its local heads of its local rows against
+    its full local-head pool — zero collectives here, same as the
+    contiguous path."""
     from frl_distributed_ml_scaffold_tpu.dist.mesh import (
         BATCH_AXES,
         current_mesh_env,
@@ -1099,37 +958,45 @@ def paged_verify_attention(
             "k_scale and v_scale must be passed together (a quantized "
             "pool quantizes both of its halves)"
         )
+    local = functools.partial(
+        _local_paged_verify, impl=impl, interpret=interpret, name=name
+    )
+    args = (q, k_pool, v_pool, kv_len, block_tables,
+            jnp.asarray(layer, jnp.int32))
+    if k_scale is not None:
+        args += (k_scale, v_scale)
     env = current_mesh_env()
     m = env.axis_size("model") if env is not None else 1
-    h = q.shape[2]
-    if env is None or m <= 1 or h % m != 0:
-        return _local_paged_verify(
-            q, k_pool, v_pool, kv_len, block_tables, impl=impl,
-            interpret=interpret, k_scale=k_scale, v_scale=v_scale,
-        )
+    if env is None or m <= 1 or q.shape[2] % m != 0:
+        return local(*args)
     batch = BATCH_AXES if q.shape[0] % env.batch_axis_size == 0 else None
     q_spec = P(batch, None, "model", None)
-    pool_spec = P(None, None, "model", None)
-    tbl_spec = P(batch, None)
-    if k_scale is None:
-        fn = shard_map_unchecked(
-            functools.partial(
-                _local_paged_verify, impl=impl, interpret=interpret
-            ),
-            mesh=env.mesh,
-            in_specs=(q_spec, pool_spec, pool_spec, P(batch), tbl_spec),
-            out_specs=q_spec,
-        )
-        return fn(q, k_pool, v_pool, kv_len, block_tables)
-    sc_spec = P(None, None, "model")
-    fn = shard_map_unchecked(
-        lambda q_, k_, v_, l_, t_, ks_, vs_: _local_paged_verify(
-            q_, k_, v_, l_, t_, impl=impl, interpret=interpret,
-            k_scale=ks_, v_scale=vs_,
-        ),
+    # Heads are the major part of every pool leaf's last dimension.
+    pool_spec, sc_spec = P(None, None, None, "model"), P(None, None, "model")
+    in_specs = (q_spec, pool_spec, pool_spec, P(batch), P(batch, None), P())
+    return shard_map_unchecked(
+        local,
         mesh=env.mesh,
-        in_specs=(q_spec, pool_spec, pool_spec, P(batch), tbl_spec,
-                  sc_spec, sc_spec),
+        in_specs=in_specs + (sc_spec,) * (len(args) - len(in_specs)),
         out_specs=q_spec,
-    )
-    return fn(q, k_pool, v_pool, kv_len, block_tables, k_scale, v_scale)
+    )(*args)
+
+
+def paged_decode_attention(
+    q: jax.Array,
+    k_pool: jax.Array,
+    v_pool: jax.Array,
+    kv_len: jax.Array,
+    block_tables: jax.Array,
+    layer,
+    **kw,
+) -> jax.Array:
+    """Single-token decode attention over the paged KV cache: q
+    ``[B, H, D]`` against every key at a logical position ``< kv_len[b]``
+    — the T=1 tile of ``paged_verify_attention`` (same pools, tables,
+    layer index, quantization, sharding and fallback contract), under
+    the kernel name ``attn_paged_decode``."""
+    return paged_verify_attention(
+        q[:, None], k_pool, v_pool, kv_len, block_tables, layer,
+        name="attn_paged_decode", **kw,
+    )[:, 0]

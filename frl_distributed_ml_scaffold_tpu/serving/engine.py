@@ -79,9 +79,11 @@ from frl_distributed_ml_scaffold_tpu.models.generation import (
     generate,
     next_cache_bucket,
     pool_block_bytes,
+    pool_to_slot_blocks,
     rewind_cache_indices,
     splice_pool_blocks,
 )
+from frl_distributed_ml_scaffold_tpu.models.gpt import init_paged_cache
 from frl_distributed_ml_scaffold_tpu.telemetry import (
     Histogram,
     MetricsRegistry,
@@ -1152,20 +1154,15 @@ class ServingEngine:
         )
 
     def _init_paged_cache(self) -> None:
-        """Zero pool + tables + bookkeeping, shaped by the paged model's
-        own cache structure (eval_shape — nothing runs), so the engine
-        never hardcodes the cache tree. All-zero tables point every row
-        at the trash block 0."""
+        """Zero pool + tables + bookkeeping, built by the paged model's
+        own ``init_paged_cache`` (the engine never hardcodes the cache
+        tree; the layer loop carries the pool, so it exists before the
+        first step). All-zero tables point every row at the trash
+        block 0."""
         m = self._paged_model()
-        tok = jax.ShapeDtypeStruct((self.num_slots, 1), jnp.int32)
-        shapes = jax.eval_shape(
-            lambda p, t: m.apply(
-                {"params": p}, t, decode=True, mutable=["cache"]
-            )[1]["cache"],
-            self.params, tok,
-        )
+
         def serve_init_cache():
-            return jax.tree.map(lambda s: jnp.zeros(s.shape, s.dtype), shapes)
+            return init_paged_cache(m, self.num_slots)
 
         with self._trace_ctx():
             self.cache = self._call(
@@ -1211,9 +1208,13 @@ class ServingEngine:
         """Gather ``m`` shared pool blocks into the leading positions of
         a fresh slot cache at capacity ``s_c`` (indices seeded to
         ``m*block_size``): exactly the blocks that change hands move —
-        never a logical-cache materialization (gather at the boundary)."""
+        never a logical-cache materialization (gather at the boundary).
+        Pool leaves are ``[L, N, bs, H*hd]`` (scales ``[L, N, H*bs]``),
+        slot leaves ``[L, 1, S, H, hd]`` (``[L, 1, S, H]``): the gathered
+        blocks are reshaped (``generation.pool_to_slot_blocks``)."""
         if (s_c, m) not in self._seed_jit:
             bs = self.block_size
+            heads = self.model.config.num_heads
 
             def serve_seed(cache, ids):
                 from flax.traverse_util import flatten_dict, unflatten_dict
@@ -1223,12 +1224,14 @@ class ServingEngine:
                 for kp, leaf in flat.items():
                     name = kp[-1]
                     if name in SLOT_LEAF_OF:
-                        # [L, N, bs, ...] -> [L, m, bs, ...] gather ->
-                        # [L, 1, m*bs, ...] contiguous prefix, padded to
-                        # the slot-cache capacity.
-                        g = jnp.take(leaf, ids, axis=1)
+                        # [L, N, ...] -> [L, m, ...] gather -> [L, m, bs,
+                        # H(, hd)] -> [L, 1, m*bs, ...] contiguous prefix,
+                        # padded to the slot-cache capacity.
+                        g = pool_to_slot_blocks(
+                            name, jnp.take(leaf, ids, axis=1), heads
+                        )
                         contig = g.reshape(
-                            (leaf.shape[0], 1, m * bs) + leaf.shape[3:]
+                            (leaf.shape[0], 1, m * bs) + g.shape[3:]
                         )
                         pad = [(0, 0)] * contig.ndim
                         pad[2] = (0, s_c - m * bs)

@@ -24,6 +24,7 @@ import re
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 import pytest
 from jax.sharding import SingleDeviceSharding
 
@@ -157,37 +158,39 @@ def test_decode_attention_int8_cache(one_chip):
 
 
 def _pool_shapes(block: int, dtype):
+    """Stacked lane-dense pools of two layers, as models/gpt.py stores them."""
     n_blocks, table = B * S // block + 1, S // block
     return (
-        ((n_blocks, block, H, D), dtype),
+        ((2, n_blocks, block, H * D), dtype),
         ((B,), I32),
         ((B, table), I32),
-        ((n_blocks, block, H), BF16),
+        ((), I32),  # the layer index, traced inside the layer loop
+        ((2, n_blocks, H * block), BF16),
     )
 
 
 @pytest.mark.parametrize("block", [16, 64])
 def test_paged_decode_attention(one_chip, block):
     """Single-token decode over the block pool — the serving engine's path."""
-    pool, lens, tables, _ = _pool_shapes(block, BF16)
+    pool, lens, tables, layer, _ = _pool_shapes(block, BF16)
     _compile_has_kernel(
         one_chip,
-        _scoped(lambda q, k, v, n, t: da.paged_decode_attention(
-            q, k, v, n, t, impl="flash", interpret=False)),
-        ((B, H, D), BF16), pool, pool, lens, tables,
+        _scoped(lambda q, k, v, n, t, l: da.paged_decode_attention(
+            q, k, v, n, t, l, impl="flash", interpret=False)),
+        ((B, H, D), BF16), pool, pool, lens, tables, layer,
         names=["attn_paged_decode"],
     )
 
 
 @pytest.mark.parametrize("block", [16, 64])
 def test_paged_decode_attention_int8_pool(one_chip, block):
-    pool, lens, tables, scales = _pool_shapes(block, I8)
+    pool, lens, tables, layer, scales = _pool_shapes(block, I8)
     _compile_has_kernel(
         one_chip,
-        _scoped(lambda q, k, v, n, t, ks, vs: da.paged_decode_attention(
-            q, k, v, n, t, k_scale=ks, v_scale=vs, impl="flash",
+        _scoped(lambda q, k, v, n, t, l, ks, vs: da.paged_decode_attention(
+            q, k, v, n, t, l, k_scale=ks, v_scale=vs, impl="flash",
             interpret=False)),
-        ((B, H, D), BF16), pool, pool, lens, tables, scales, scales,
+        ((B, H, D), BF16), pool, pool, lens, tables, layer, scales, scales,
         names=["attn_paged_decode_quant"],
     )
 
@@ -195,14 +198,191 @@ def test_paged_decode_attention_int8_pool(one_chip, block):
 @pytest.mark.parametrize("block", [16, 64])
 def test_paged_verify_attention(one_chip, block):
     """The speculative verify tile (k=3 drafts + the last accepted token)."""
-    pool, lens, tables, _ = _pool_shapes(block, BF16)
+    pool, lens, tables, layer, _ = _pool_shapes(block, BF16)
     _compile_has_kernel(
         one_chip,
-        _scoped(lambda q, k, v, n, t: da.paged_verify_attention(
-            q, k, v, n, t, impl="flash", interpret=False)),
-        ((B, T_VERIFY, H, D), BF16), pool, pool, lens, tables,
+        _scoped(lambda q, k, v, n, t, l: da.paged_verify_attention(
+            q, k, v, n, t, l, impl="flash", interpret=False)),
+        ((B, T_VERIFY, H, D), BF16), pool, pool, lens, tables, layer,
         names=["attn_paged_verify"],
     )
+
+
+# ----------------------------------------------- the KV pool stays in place
+#
+# The serving engine's three programs that hold the paged KV pool, compiled
+# as the engine builds them at the serving benchmark's size, and read as the
+# chip would run them. graft-lint's serving:* programs and the cache-copy
+# budget of analysis/materialization.py read the JAXPR; they passed while
+# the chip moved gigabytes a step, because the passes over the pool were put
+# in by the TPU's compiler: a [.., H, hd] minor pair with hd = 64 made it
+# keep the block index in the lanes, and every program transposed the pool
+# there and back (PERF.md section 6, PR 28).
+
+POOL_LAYERS, POOL_SLOTS, POOL_BLOCKS, POOL_BS = 24, 48, 1281, 16
+#: Elements of ONE layer's slice of a K/V pool: no op may write that many.
+POOL_SLICE = POOL_BLOCKS * POOL_BS * H * D
+#: Ops that move nothing. (The compiler's own prefetch of a weight, the
+#: embedding, into faster memory is let through below: no pass over the pool.)
+_MOVES_NOTHING = {"parameter", "get-tuple-element", "tuple", "bitcast", "while"}
+_UPDATES = ("scatter", "dynamic-update-slice")
+_UPDATE_ROOT = re.compile(
+    r"ROOT %[\w.\-]+ = .+? (?:" + "|".join(_UPDATES) + r")\("
+)
+
+
+def _pool_sized_ops(text: str) -> list[str]:
+    """Instructions of the optimised HLO, outside fused computations, whose
+    output holds an array of POOL_SLICE elements or more and that are
+    neither free nor the in-place update itself (a scatter /
+    dynamic-update-slice, or the fusion whose root is one)."""
+    bodies = dict(re.findall(
+        r"^(?:ENTRY )?%([\w.\-]+) \(.*?\{\n(.*?)^\}", text, re.M | re.S
+    ))
+    fused = set(re.findall(r"calls=%([\w.\-]+)", text))
+    found = []
+    for name, body in bodies.items():
+        if name in fused:
+            continue
+        for line in body.splitlines():
+            m = re.match(
+                r"\s*(?:ROOT )?%[\w.\-]+ = (.+?) ([a-z][\w\-]*)\(", line
+            )
+            if m is None:
+                continue
+            out, op = m.groups()
+            sizes = [
+                int(np.prod([int(d) for d in dims.split(",") if d] or [1]))
+                for dims in re.findall(r"\w+\[([\d,]*)\]", out)
+            ]
+            if max(sizes, default=0) < POOL_SLICE or op in _MOVES_NOTHING:
+                continue
+            if op in _UPDATES:
+                continue
+            called = re.search(r"calls=%([\w.\-]+)", line)
+            if op == "fusion" and called and _UPDATE_ROOT.search(
+                bodies[called.group(1)]
+            ):
+                continue
+            if op in ("copy-start", "copy-done") and (
+                f",{POOL_BLOCKS}," not in out
+            ):
+                continue  # the prefetch of a weight: no array of pool blocks
+            found.append(f"{op} -> {out[:60]}")
+    return found
+
+
+@pytest.fixture(scope="module")
+def pool_programs(one_chip):
+    """quant -> {program: compiled}: ``serve_paged_decode``,
+    ``serve_verify`` and ``serve_paged_graft`` from the engine's own
+    builders (a real ``ServingEngine`` over abstract GPT-2-medium weights;
+    nothing runs), compiled for the described chip. The host is a CPU, so
+    the attention router would take its dense route: the kernel route is
+    forced, as on the chip."""
+    from frl_distributed_ml_scaffold_tpu.config.schema import GPTConfig
+    from frl_distributed_ml_scaffold_tpu.models.gpt import (
+        GPT,
+        init_paged_cache,
+    )
+    from frl_distributed_ml_scaffold_tpu.precision import get_policy
+    from frl_distributed_ml_scaffold_tpu.serving import ServingEngine
+
+    def on_chip(tree):
+        return jax.tree.map(
+            lambda s: jax.ShapeDtypeStruct(s.shape, s.dtype, sharding=one_chip),
+            tree,
+        )
+
+    def build(quant):
+        policy = get_policy("bf16")
+        model = GPT(
+            GPTConfig(
+                num_layers=POOL_LAYERS, hidden_dim=H * D, num_heads=H,
+                seq_len=S, vocab_size=50257, dropout=0.0,
+                decode_attention="flash", kv_cache_quant=quant,
+            ),
+            policy,
+        )
+        params = jax.tree.map(
+            lambda s: jax.ShapeDtypeStruct(s.shape, policy.param_dtype),
+            jax.eval_shape(
+                lambda: model.init(
+                    {"params": jax.random.key(0)},
+                    jnp.zeros((1, 8), I32), train=False,
+                )["params"]
+            ),
+        )
+        eng = ServingEngine(
+            model, params, num_slots=POOL_SLOTS, temperature=0.0,
+            kv_block_size=POOL_BS, kv_pool_blocks=POOL_BLOCKS,
+            speculate="ngram", speculate_k=T_VERIFY - 1,
+        )
+        cache = jax.eval_shape(
+            lambda: init_paged_cache(eng._paged_model(), POOL_SLOTS)
+        )
+        # A 150-token prompt: a slot cache of 256 positions, ten blocks.
+        slot_model, n_priv = eng._model_at(256), 10
+        slot_cache = jax.eval_shape(
+            lambda p, t: slot_model.apply(
+                {"params": p}, t, decode=True, mutable=["cache"]
+            )[1]["cache"],
+            params, jax.ShapeDtypeStruct((1, 8), I32),
+        )
+        i32 = lambda *shape: jax.ShapeDtypeStruct(shape, I32)  # noqa: E731
+        programs = {
+            "serve_paged_decode": (
+                eng._paged_decode_fn(),
+                (params, cache, i32(POOL_SLOTS),
+                 jax.eval_shape(lambda: jax.random.key(0))),
+            ),
+            "serve_verify": (
+                eng._verify_fn(), (params, cache, i32(POOL_SLOTS, T_VERIFY))
+            ),
+            "serve_paged_graft": (
+                eng._paged_graft_fn(256, n_priv),
+                (cache, slot_cache, i32(n_priv), i32(), i32()),
+            ),
+        }
+        return {
+            name: fn.lower(*on_chip(args)).compile()
+            for name, (fn, args) in programs.items()
+        }
+
+    was = da.FORCE_INTERPRET
+    da.FORCE_INTERPRET = False
+    try:
+        yield {quant: build(quant) for quant in ("none", "int8")}
+    finally:
+        da.FORCE_INTERPRET = was
+
+
+@pytest.mark.parametrize("quant", ["none", "int8"], ids=["bf16", "int8-pool"])
+@pytest.mark.parametrize(
+    "program", ["serve_paged_decode", "serve_verify", "serve_paged_graft"]
+)
+def test_pool_program_leaves_the_pool_in_place(pool_programs, program, quant):
+    """No op writes a layer's slice of the pool or more, other than the
+    in-place update; the K/V pools arrive in the plain row-major layout
+    that the scatter and the kernel read; the program's temporaries are a
+    few megabytes (2.64 GB and 2.03 GB before PR 28)."""
+    compiled = pool_programs[quant][program]
+    text = compiled.as_text()
+    assert _pool_sized_ops(text) == []
+    dtype = "s8" if quant == "int8" else "bf16"
+    shape = f"{dtype}[{POOL_LAYERS},{POOL_BLOCKS},{POOL_BS},{H * D}]"
+    pools = re.findall(
+        re.escape(shape) + r"\{([\d,]+)", text.split("\n", 1)[0]
+    )
+    assert pools and set(pools) == {"3,2,1,0"}, pools
+    assert compiled.memory_analysis().temp_size_in_bytes < 64e6
+    if program != "serve_paged_graft":
+        suffix = "_quant" if quant == "int8" else ""
+        kernel = {"serve_paged_decode": "attn_paged_decode",
+                  "serve_verify": "attn_paged_verify"}[program] + suffix
+        assert re.search(
+            rf"%{kernel}[\w.]* = .*custom-call\(.*tpu_custom_call", text
+        ), f"{kernel} is not in {program}"
 
 
 @pytest.mark.parametrize(

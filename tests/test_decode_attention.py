@@ -256,35 +256,54 @@ def test_quant_dense_chunk_is_strictly_smaller_than_bucket():
 # ------------------------------------------------------------ paged cache
 
 
+#: The pools hold all layers; the fixtures put the cache in this one and
+#: garbage in the others, so an entry that read another layer would show.
+LAYERS, LAYER = 3, 1
+
+
 def _paged_from_contiguous(k, v, bs, n_blocks, seed=0, scales=None):
     """Scatter a contiguous [B, S, H, D] cache into pool blocks through a
     random (non-trivial) block table — the layout serving/engine.py
     grafts into, built here by hand so the op gates do not depend on the
-    engine."""
+    engine: stacked lane-dense pools ``[L, N, bs, H*D]`` (a block's
+    scales one row ``[L, N, H*bs]``, heads major), the cache in layer
+    ``LAYER`` and garbage in every other layer."""
     rng = np.random.default_rng(seed)
     b, s, h, d = k.shape
     m_tbl = s // bs
     assert b * m_tbl <= n_blocks - 1, "pool too small for the fixture"
     perm = rng.permutation(np.arange(1, n_blocks))[: b * m_tbl]
     tables = jnp.asarray(perm.reshape(b, m_tbl), jnp.int32)
-    k_pool = jnp.zeros((n_blocks, bs, h, d), k.dtype)
-    v_pool = jnp.zeros((n_blocks, bs, h, d), v.dtype)
+    junk = 100 if jnp.issubdtype(k.dtype, jnp.integer) else 1e6
+
+    def pool(x, row):  # [B, S, ...] -> [L, N, *row]
+        out = np.full((LAYERS, n_blocks) + row, junk, np.float32)
+        out[LAYER] = 0
+        blocks = np.asarray(x, np.float32).reshape((b, m_tbl, bs) + x.shape[2:])
+        if x.ndim == 3:  # scales [.., bs, H] -> one row, heads major
+            blocks = np.swapaxes(blocks, -1, -2)
+        out[LAYER, np.asarray(tables)] = blocks.reshape((b, m_tbl) + row)
+        return jnp.asarray(out, x.dtype)
+
+    k_pool, v_pool = pool(k, (bs, h * d)), pool(v, (bs, h * d))
     sc_pools = None
     if scales is not None:
-        ks, vs = scales
-        ksp = jnp.zeros((n_blocks, bs, h), ks.dtype)
-        vsp = jnp.zeros((n_blocks, bs, h), vs.dtype)
-    for bb in range(b):
-        for j in range(m_tbl):
-            pid = int(tables[bb, j])
-            k_pool = k_pool.at[pid].set(k[bb, j * bs : (j + 1) * bs])
-            v_pool = v_pool.at[pid].set(v[bb, j * bs : (j + 1) * bs])
-            if scales is not None:
-                ksp = ksp.at[pid].set(ks[bb, j * bs : (j + 1) * bs])
-                vsp = vsp.at[pid].set(vs[bb, j * bs : (j + 1) * bs])
-    if scales is not None:
-        sc_pools = (ksp, vsp)
+        sc_pools = tuple(pool(x, (h * bs,)) for x in scales)
     return k_pool, v_pool, tables, sc_pools
+
+
+def _paged_decode_kernel(q, k_pool, v_pool, lens, tables, **kw):
+    return da.paged_decode_attention(
+        q, k_pool, v_pool, lens, tables, LAYER, impl="flash",
+        interpret=True, **kw,
+    )
+
+
+def _paged_verify_kernel(q, k_pool, v_pool, lens, tables, **kw):
+    return da.paged_verify_attention(
+        q, k_pool, v_pool, lens, tables, LAYER, impl="flash",
+        interpret=True, **kw,
+    )
 
 
 @pytest.mark.fast
@@ -307,14 +326,12 @@ def test_paged_decode_matches_contiguous_across_occupancies(s):
         )
         ref = da.dense_decode_attention(q, k, v, lens)
         out = da.dense_paged_decode_attention(
-            q, k_pool, v_pool, lens, tables
+            q, k_pool, v_pool, lens, tables, LAYER
         )
         np.testing.assert_allclose(
             np.asarray(ref), np.asarray(out), atol=2e-6, rtol=2e-6
         )
-        kern = da._local_paged_decode(
-            q, k_pool, v_pool, lens, tables, impl="flash", interpret=True
-        )
+        kern = _paged_decode_kernel(q, k_pool, v_pool, lens, tables)
         np.testing.assert_allclose(
             np.asarray(kern), np.asarray(out), atol=2e-6, rtol=2e-6
         )
@@ -337,14 +354,13 @@ def test_paged_quant_decode_matches_quant_dense():
         )
         ref = da.dense_decode_attention_quant(q, kq, vq, lens, ks, vs)
         out = da.dense_paged_decode_attention(
-            q, kqp, vqp, lens, tables, ksp, vsp
+            q, kqp, vqp, lens, tables, LAYER, ksp, vsp
         )
         np.testing.assert_allclose(
             np.asarray(ref), np.asarray(out), atol=3e-6, rtol=3e-6
         )
-        kern = da._local_paged_decode(
-            q, kqp, vqp, lens, tables, impl="flash", interpret=True,
-            k_scale=ksp, v_scale=vsp,
+        kern = _paged_decode_kernel(
+            q, kqp, vqp, lens, tables, k_scale=ksp, v_scale=vsp
         )
         np.testing.assert_allclose(
             np.asarray(kern), np.asarray(out), atol=3e-6, rtol=3e-6
@@ -361,7 +377,9 @@ def test_paged_decode_ignores_unreferenced_and_dead_blocks():
     q, k, v = _make(b, s, h, d, jnp.float32)
     lens = jnp.asarray([5, 23], jnp.int32)
     k_pool, v_pool, tables, _ = _paged_from_contiguous(k, v, bs, 32)
-    clean = da.dense_paged_decode_attention(q, k_pool, v_pool, lens, tables)
+    clean = da.dense_paged_decode_attention(
+        q, k_pool, v_pool, lens, tables, LAYER
+    )
     # Garbage in every block a row's OCCUPIED prefix does not reach:
     # row 0 occupies 5 tokens (block 0 of its table), row 1 occupies 23
     # (blocks 0..2) — everything else in the pool is fair game.
@@ -372,17 +390,15 @@ def test_paged_decode_ignores_unreferenced_and_dead_blocks():
     dirty_k, dirty_v = k_pool, v_pool
     for pid in range(32):
         if pid not in live:
-            dirty_k = dirty_k.at[pid].set(1e6)
-            dirty_v = dirty_v.at[pid].set(-1e6)
+            dirty_k = dirty_k.at[LAYER, pid].set(1e6)
+            dirty_v = dirty_v.at[LAYER, pid].set(-1e6)
     # Positions past occupancy INSIDE the last live block too.
-    dirty = da.dense_paged_decode_attention(q, dirty_k, dirty_v, lens, tables)
+    dirty = da.dense_paged_decode_attention(
+        q, dirty_k, dirty_v, lens, tables, LAYER
+    )
     np.testing.assert_array_equal(np.asarray(clean), np.asarray(dirty))
-    kern_clean = da._local_paged_decode(
-        q, k_pool, v_pool, lens, tables, impl="flash", interpret=True
-    )
-    kern_dirty = da._local_paged_decode(
-        q, dirty_k, dirty_v, lens, tables, impl="flash", interpret=True
-    )
+    kern_clean = _paged_decode_kernel(q, k_pool, v_pool, lens, tables)
+    kern_dirty = _paged_decode_kernel(q, dirty_k, dirty_v, lens, tables)
     np.testing.assert_array_equal(
         np.asarray(kern_clean), np.asarray(kern_dirty)
     )
@@ -390,19 +406,20 @@ def test_paged_decode_ignores_unreferenced_and_dead_blocks():
 
 @pytest.mark.fast
 def test_paged_untileable_block_falls_back_to_dense():
-    """Block geometries outside the kernel contract (block < 8, head_dim
-    not sublane-aligned) must take the identical-numerics streamed dense
-    path, not miscompute — the ``_local_decode`` fallback contract."""
+    """Block geometries outside the kernel contract (block < 8, a token's
+    row of heads * head_dim values not whole 128-lane tiles) must take
+    the identical-numerics streamed dense path, not miscompute — the
+    ``_local_decode`` fallback contract."""
     b, h = 2, 2
     for bs, d in ((4, 64), (8, 16)):
         s = 8 * bs
         q, k, v = _make(b, s, h, d, jnp.float32)
         lens = jnp.asarray([3, s], jnp.int32)
         k_pool, v_pool, tables, _ = _paged_from_contiguous(k, v, bs, 32)
-        out = da._local_paged_decode(
-            q, k_pool, v_pool, lens, tables, impl="flash", interpret=True
+        out = _paged_decode_kernel(q, k_pool, v_pool, lens, tables)
+        ref = da.dense_paged_decode_attention(
+            q, k_pool, v_pool, lens, tables, LAYER
         )
-        ref = da.dense_paged_decode_attention(q, k_pool, v_pool, lens, tables)
         np.testing.assert_array_equal(np.asarray(out), np.asarray(ref))
 
 
@@ -413,16 +430,16 @@ def test_paged_dense_fallback_streams_bounded_chunks():
     carries the M*bs logical-context dim) at any block size — the same
     bounded-chunk discipline as the quantized fallback, which is what
     the graft-lint paged program pin relies on."""
-    b, h, d = 2, 2, 32
+    b, h, d = 2, 2, 24  # h*d is no logical-context size below
     for bs, m_tbl in ((8, 8), (16, 32)):
         s = bs * m_tbl
         n_blocks = 2 * b * m_tbl + 1
         q = jnp.zeros((b, h, d), jnp.float32)
-        k_pool = jnp.zeros((n_blocks, bs, h, d), jnp.float32)
+        k_pool = jnp.zeros((LAYERS, n_blocks, bs, h * d), jnp.float32)
         tables = jnp.zeros((b, m_tbl), jnp.int32)
         lens = jnp.asarray([1, s], jnp.int32)
         jaxpr = jax.make_jaxpr(
-            lambda *a: da.dense_paged_decode_attention(*a)
+            lambda *a: da.dense_paged_decode_attention(*a, LAYER)
         )(q, k_pool, k_pool, lens, tables)
         pins.assert_no_dim_materialized(
             jaxpr, s,
@@ -452,7 +469,9 @@ def test_paged_verify_matches_per_position_decode(t):
         k, v, bs, b * (s // bs) + 5, seed=t
     )
     lens = jnp.asarray([t + 1, 29, s], jnp.int32)  # total incl. tile
-    out = da.dense_paged_verify_attention(q, k_pool, v_pool, lens, tables)
+    out = da.dense_paged_verify_attention(
+        q, k_pool, v_pool, lens, tables, LAYER
+    )
     for j in range(t):
         ref = da.dense_decode_attention(
             q[:, j], k, v, lens - (t - 1) + j
@@ -461,9 +480,7 @@ def test_paged_verify_matches_per_position_decode(t):
             np.asarray(out[:, j]), np.asarray(ref), atol=2e-6, rtol=2e-6,
             err_msg=f"verify position {j} diverged from its decode step",
         )
-    kern = da._local_paged_verify(
-        q, k_pool, v_pool, lens, tables, impl="flash", interpret=True
-    )
+    kern = _paged_verify_kernel(q, k_pool, v_pool, lens, tables)
     np.testing.assert_allclose(
         np.asarray(kern), np.asarray(out), atol=2e-6, rtol=2e-6
     )
@@ -491,7 +508,7 @@ def test_paged_verify_quant_matches_quant_reference():
     ksp, vsp = sc
     lens = jnp.asarray([t, 21, s], jnp.int32)
     out = da.dense_paged_verify_attention(
-        q, kqp, vqp, lens, tables, ksp, vsp
+        q, kqp, vqp, lens, tables, LAYER, ksp, vsp
     )
     for j in range(t):
         ref = da.dense_decode_attention_quant(
@@ -500,13 +517,72 @@ def test_paged_verify_quant_matches_quant_reference():
         np.testing.assert_allclose(
             np.asarray(out[:, j]), np.asarray(ref), atol=1e-5, rtol=1e-5,
         )
-    kern = da._local_paged_verify(
-        q, kqp, vqp, lens, tables, impl="flash", interpret=True,
-        k_scale=ksp, v_scale=vsp,
+    kern = _paged_verify_kernel(
+        q, kqp, vqp, lens, tables, k_scale=ksp, v_scale=vsp
     )
     np.testing.assert_allclose(
         np.asarray(kern), np.asarray(out), atol=1e-5, rtol=1e-5
     )
+
+
+@pytest.mark.fast
+@pytest.mark.parametrize("t", [1, 4], ids=lambda t: f"T{t}")
+@pytest.mark.parametrize("pool", ["bf16", "int8"])
+def test_paged_kernel_reads_the_stacked_pool_where_it_lies(pool, t):
+    """ISSUE 28 op gate: the kernel entry as the model calls it — the
+    STACKED lane-dense pool ``[L, N, bs, H*D]`` (int8: with its
+    ``[L, N, H*bs]`` scale rows) plus a TRACED layer index, under jit —
+    against the streamed dense reference, in the serving precisions
+    (bf16 pool, int8 pool), for the decode step (T=1) and a verify tile,
+    at ragged occupancies, with a retired row whose table is all trash
+    block 0 and whose cursor has run on, and with a pool whose block
+    count is no multiple of the scale rows' DMA group."""
+    b, s, h, d, bs = 4, 64, 4, 64, 8
+    rng = np.random.default_rng(11 + t)
+    k = jnp.asarray(rng.normal(size=(b, s, h, d)), jnp.float32)
+    v = jnp.asarray(rng.normal(size=(b, s, h, d)), jnp.float32)
+    q = jnp.asarray(rng.normal(size=(b, t, h, d)), jnp.bfloat16)
+    sc = None
+    if pool == "int8":
+        from frl_distributed_ml_scaffold_tpu.ops.quantization import quantize
+
+        k, ks = quantize(k, "int8", channel_axes=(0, 1, 2))
+        v, vs = quantize(v, "int8", channel_axes=(0, 1, 2))
+        sc = (ks[..., 0].astype(jnp.bfloat16), vs[..., 0].astype(jnp.bfloat16))
+    else:
+        k, v = k.astype(jnp.bfloat16), v.astype(jnp.bfloat16)
+    k_pool, v_pool, tables, sc = _paged_from_contiguous(
+        k, v, bs, b * (s // bs) + 6, seed=t, scales=sc
+    )
+    assert k_pool.shape[1] % da._SCALE_ROWS != 0
+    # Row 3 is RETIRED: every logical block is the trash block 0 (which
+    # holds whatever was last written there) and its length ran past it.
+    tables = tables.at[3].set(0)
+    lens = jnp.asarray([t, 29, s, 37], jnp.int32)  # total incl. the tile
+    kw = {} if sc is None else dict(k_scale=sc[0], v_scale=sc[1])
+
+    def both(layer):
+        ref = da.dense_paged_verify_attention(
+            q, k_pool, v_pool, lens, tables, layer, *kw.values()
+        )
+        kern = da.paged_verify_attention(
+            q, k_pool, v_pool, lens, tables, layer, impl="flash",
+            interpret=True, **kw,
+        )
+        return ref, kern
+
+    ref, kern = jax.jit(both)(jnp.int32(LAYER))
+    assert np.isfinite(np.asarray(ref, np.float32)).all()
+    np.testing.assert_allclose(
+        np.asarray(kern, np.float32), np.asarray(ref, np.float32),
+        atol=2e-2, rtol=2e-2,  # bf16 outputs: one rounding of O(1) values
+    )
+    if t == 1:
+        one = da.paged_decode_attention(
+            q[:, 0], k_pool, v_pool, lens, tables, LAYER, impl="flash",
+            interpret=True, **kw,
+        )
+        np.testing.assert_array_equal(np.asarray(one), np.asarray(kern[:, 0]))
 
 
 @pytest.mark.fast
@@ -516,16 +592,16 @@ def test_paged_verify_dense_fallback_streams_bounded_chunks():
     verify fallback still streams one bounded block per table column
     (no intermediate carries the M*bs logical-context dim), which is
     what the graft-lint serving:verify_step_paged pin relies on."""
-    b, h, d, t = 2, 2, 32, 3
+    b, h, d, t = 2, 2, 24, 3
     for bs, m_tbl in ((8, 8), (16, 32)):
         s = bs * m_tbl
         n_blocks = 2 * b * m_tbl + 1
         q = jnp.zeros((b, t, h, d), jnp.float32)
-        k_pool = jnp.zeros((n_blocks, bs, h, d), jnp.float32)
+        k_pool = jnp.zeros((LAYERS, n_blocks, bs, h * d), jnp.float32)
         tables = jnp.zeros((b, m_tbl), jnp.int32)
         lens = jnp.asarray([t, s], jnp.int32)
         jaxpr = jax.make_jaxpr(
-            lambda *a: da.dense_paged_verify_attention(*a)
+            lambda *a: da.dense_paged_verify_attention(*a, LAYER)
         )(q, k_pool, k_pool, lens, tables)
         pins.assert_no_dim_materialized(
             jaxpr, s,

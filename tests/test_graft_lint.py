@@ -480,7 +480,7 @@ def test_paged_decode_step_lint_clean_and_mutations_trip():
 
     # Mutation (a): clone-per-grow — pad the pool one block wider.
     def clone_per_grow(c):
-        kp = c["blocks"]["attn"]["key_pool"]  # [L, N, bs, H, hd]
+        kp = c["blocks"]["attn"]["key_pool"]  # [L, N, bs, H*hd]
         pad = [(0, 0)] * kp.ndim
         pad[1] = (0, 1)
         return jnp.pad(kp, pad)
@@ -495,12 +495,12 @@ def test_paged_decode_step_lint_clean_and_mutations_trip():
 
     # Mutation (b): gather the logical cache view out of the pool.
     def gather_logical(c):
-        kp = c["blocks"]["attn"]["key_pool"]  # [L, N, bs, H, hd]
+        kp = c["blocks"]["attn"]["key_pool"]  # [L, N, bs, H*hd]
         tbl = c["block_tables"]  # [B, M]
-        g = jnp.take(kp, tbl, axis=1)  # [L, B, M, bs, H, hd]
-        l, _, _, h, hd = kp.shape
+        g = jnp.take(kp, tbl, axis=1)  # [L, B, M, bs, H*hd]
+        l, _, bs, f = kp.shape
         b, m = tbl.shape
-        return g.reshape(l, b, m * kp.shape[2], h, hd)  # full context
+        return g.reshape(l, b, m * bs, f)  # full context
 
     gather_jaxpr = jax.make_jaxpr(gather_logical)(cache)
     with pytest.raises(AssertionError, match=str(seq_len)):
@@ -545,9 +545,9 @@ def test_verify_step_lint_clean_and_mutations_trip():
     # Mutation (a): verify the tile against the gathered logical view —
     # a [B, T, M*bs]-scored step materializes the full context.
     def gathered_scores(c, t):
-        kp = c["blocks"]["attn"]["key_pool"]  # [L, N, bs, H, hd]
+        kp = c["blocks"]["attn"]["key_pool"]  # [L, N, bs, H*hd]
         tbl = c["block_tables"]  # [B, M]
-        g = jnp.take(kp[0], tbl, axis=0)  # [B, M, bs, H, hd]
+        g = jnp.take(kp[0], tbl, axis=0)  # [B, M, bs, H*hd]
         b, m = tbl.shape
         logical = g.reshape(b, m * kp.shape[2], -1)  # full context
         q = jnp.zeros((b, t.shape[1], logical.shape[-1]), jnp.float32)
@@ -613,14 +613,16 @@ def test_handoff_lint_clean_and_gather_mutation_trips():
     # Mutation: the gather-based handoff — materialize the logical view,
     # splice the slot cache into it, scatter the WHOLE pool back.
     def gather_handoff(c, sc):
-        kp = c["blocks"]["attn"]["key_pool"]  # [L, N, bs, H, hd]
+        kp = c["blocks"]["attn"]["key_pool"]  # [L, N, bs, H*hd]
         tbl = c["block_tables"]  # [B, M]
-        g = jnp.take(kp, tbl, axis=1)  # [L, B, M, bs, H, hd]
-        l, _, bs, h, hd = kp.shape
+        g = jnp.take(kp, tbl, axis=1)  # [L, B, M, bs, H*hd]
+        l, _, bs, f = kp.shape
         b, m = tbl.shape
-        logical = g.reshape(l, b, m * bs, h, hd)  # the full-context copy
+        logical = g.reshape(l, b, m * bs, f)  # the full-context copy
         sk = sc["blocks"]["attn"]["cached_key"]  # [L, 1, s_c, H, hd]
-        logical = logical.at[:, 0, : sk.shape[2]].set(sk[:, 0])
+        logical = logical.at[:, 0, : sk.shape[2]].set(
+            sk[:, 0].reshape(l, sk.shape[2], f)
+        )
         return logical
 
     mut_jaxpr = jax.make_jaxpr(gather_handoff)(pool_cache, slot_cache)

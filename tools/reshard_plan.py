@@ -111,7 +111,6 @@ def plan_restore():
 
 def plan_respread(from_model: int, to_model: int):
     import jax
-    import jax.numpy as jnp
     from jax.sharding import NamedSharding, PartitionSpec as P
 
     from frl_distributed_ml_scaffold_tpu import redistribute
@@ -122,16 +121,11 @@ def plan_respread(from_model: int, to_model: int):
     from frl_distributed_ml_scaffold_tpu.models.generation import (
         pool_leaf_spec,
     )
+    from frl_distributed_ml_scaffold_tpu.models.gpt import init_paged_cache
 
     base, params = _twin()
     model = base.clone(kv_block_size=8, kv_pool_blocks=9)
-    tok = jax.ShapeDtypeStruct((2, 1), jnp.int32)
-    cache = jax.eval_shape(
-        lambda p, t: model.apply(
-            {"params": p}, t, decode=True, mutable=["cache"]
-        )[1]["cache"],
-        params, tok,
-    )
+    cache = jax.eval_shape(lambda: init_paged_cache(model, 2))
     src_env = build_mesh(
         MeshConfig(data=1, model=from_model),
         devices=jax.devices()[:from_model],
