@@ -3,6 +3,7 @@ the peaks table, the exact percentile, seeds, and the result line."""
 
 from __future__ import annotations
 
+import importlib
 import json
 import math
 import os
@@ -57,6 +58,19 @@ def sized(cfg_file: dict, key: str, rehearse: bool) -> dict:
     with the rehearsal preset's changes laid over it in a rehearsal."""
     over = cfg_file["rehearse"].get(key, {}) if rehearse else {}
     return dict(cfg_file.get(key, {}), **over)
+
+
+def reference_of(cfg_file: dict):
+    """The configuration's plain reference: the module under reference/ that
+    its file names. There is no default: which equations a configuration is
+    held to is the file's to say."""
+    name = cfg_file.get("reference")
+    if not name:
+        raise SystemExit(
+            "the configuration's file has no `reference` key: it has to name its plain "
+            "reference, a module under benchmarks/reference/ (README.md, \"The reference's "
+            "interface\")")
+    return importlib.import_module("reference." + name)
 
 
 def limits(cfg_file: dict, rehearse: bool) -> dict:

@@ -10,26 +10,48 @@ engine; after it closes nothing new is sent and what is in flight drains.
 
 from __future__ import annotations
 
+import dataclasses
 import os
 import time
 
 import numpy as np
 
 from . import correct, trace as tracelib
-from .common import ROOT, limits, log, memory_peak_bytes, percentile, phase, sized
+from .common import ROOT, limits, log, memory_peak_bytes, percentile, phase, reference_of, sized
 from .traffic import serve_schedule, warmup_prompt_lengths
 from .weights import flat, make_params
 
 TRACE_FROM_S = 4.0  # the traced part of the window starts here
 TRACE_FOR_S = 3.0
 SYNC_MARK = "bench_clock_sync"
+# The model family a file's `model_overrides` states where it states one; else
+# this one, the program's decoder-only language models (config/schema.py).
+DEFAULT_FAMILY = "gpt"
+# The served comparison pads a sampled request to the shortest of these shares
+# of `seq_len` that holds it, and takes the reference's head in blocks of rows
+# of at most this many logits: no [seq_len, vocab] array is made.
+PAD_LADDER = (8, 4, 2, 1)
+HEAD_BLOCK_LOGITS = 2**26
+
+
+def build_model(cfg_file: dict, sizes: dict, policy):
+    """The program's model from the file's `model` + `model_overrides`, built
+    by the program's own loader, which picks the class by `family`."""
+    from frl_distributed_ml_scaffold_tpu.config import ExperimentConfig, config_from_dict
+    from frl_distributed_ml_scaffold_tpu.models import create_model
+
+    group = {"family": DEFAULT_FAMILY, **sizes, **cfg_file["model_overrides"]}
+    model_cfg = config_from_dict(ExperimentConfig, {"model": group}).model
+    unknown = sorted(set(group) - {f.name for f in dataclasses.fields(model_cfg)})
+    if unknown:  # the loader passes over a key it does not know
+        raise SystemExit(f"`model` / `model_overrides` hold keys that the program's "
+                         f"{type(model_cfg).__name__} lacks: {unknown}")
+    return create_model(model_cfg, policy)
 
 
 def build_engine(cell: dict, seed: int, rehearse: bool, traced: bool):
     import jax
     import jax.numpy as jnp
-    from frl_distributed_ml_scaffold_tpu.config.schema import GPTConfig
-    from frl_distributed_ml_scaffold_tpu.models.gpt import GPT
     from frl_distributed_ml_scaffold_tpu.precision import get_policy
     from frl_distributed_ml_scaffold_tpu.serving import ServingEngine
     from frl_distributed_ml_scaffold_tpu.telemetry import MetricsRegistry, Tracer
@@ -38,7 +60,7 @@ def build_engine(cell: dict, seed: int, rehearse: bool, traced: bool):
     sizes = sized(cfg_file, "model", rehearse)
     eng_kw = sized(cfg_file, "engine", rehearse)
     policy = get_policy(cfg_file["policy"])
-    model = GPT(GPTConfig(**sizes, **cfg_file["model_overrides"]), policy)
+    model = build_model(cfg_file, sizes, policy)
     shapes = jax.eval_shape(
         lambda: model.init({"params": jax.random.key(0)},
                            jnp.zeros((1, 8), jnp.int32), train=False)["params"])
@@ -201,33 +223,60 @@ def window_metrics(res: dict, seconds: float) -> dict:
     }
 
 
-def served_gap(cell_sizes: dict, params, sample: list, lowp: bool = False):
-    """Widest gap by which a served token's logit lies below the reference's
-    best, over every served token of the sampled requests. With `lowp` the
-    token judged is the one the lower precision puts first (the control)."""
+def _head_columns(ref, pflat, feats, picked, model: dict, lowp: bool):
+    """Per position of `feats` [T, D]: the reference's best logit, the row it
+    sits in, and the logit of row `picked` [T], with the head taken in blocks
+    of rows (the last block steps back so that it ends with the vocabulary)."""
     import jax
     import jax.numpy as jnp
-    from reference import gpt2
 
-    t_max = cell_sizes["seq_len"]
+    t, v = feats.shape[0], model["vocab_size"]
+    n = min(v, max(1, HEAD_BLOCK_LOGITS // t))
+    starts = jnp.minimum(jnp.arange(-(-v // n)) * n, v - n)
+
+    def block(carry, lo):
+        best, at, of_picked = carry
+        lg = ref.head(pflat, feats, lo, n, model, lowp=lowp)
+        top, here = lg.argmax(-1), lg.max(-1)
+        better = here > best  # strictly: of equal logits the first row stays, as in argmax
+        inside = (picked >= lo) & (picked < lo + n)
+        mine = jnp.take_along_axis(lg, jnp.clip(picked - lo, 0, n - 1)[:, None], -1)[:, 0]
+        return (jnp.where(better, here, best), jnp.where(better, lo + top, at),
+                jnp.where(inside, mine, of_picked)), None
+
+    first = (jnp.full((t,), -jnp.inf, jnp.float32), jnp.zeros((t,), jnp.int32),
+             jnp.zeros((t,), jnp.float32))
+    return jax.lax.scan(block, first, starts)[0]
+
+
+def served_gap(ref, model: dict, params, sample: list, lowp: bool = False):
+    """Widest gap by which a served token's logit lies below the reference's
+    best, over every served token of the sampled requests. With `lowp` the
+    token judged is the one the lower precision puts first (the control).
+    `ref` is the configuration's reference module, `model` its `model` group."""
+    import jax
+    import jax.numpy as jnp
+
     pflat = flat(params)
-    kw = dict(heads=cell_sizes["num_heads"], eps=cell_sizes.get("layer_norm_epsilon", 1e-5))
+    ladder = sorted({-(-model["seq_len"] // k) for k in PAD_LADDER})
 
     @jax.jit
     def gaps(pflat, tokens, lo, hi):
-        ref = gpt2.logits(pflat, tokens[None], **kw)[0]
+        picked = jnp.roll(tokens, -1)
         if lowp:
-            picked = jnp.argmax(gpt2.logits(pflat, tokens[None], lowp=True, **kw)[0], -1)
-        else:
-            picked = jnp.roll(tokens, -1)
-        gap = ref.max(-1) - jnp.take_along_axis(ref, picked[:, None], -1)[:, 0]
-        pos = jnp.arange(t_max)
-        return jnp.where((pos >= lo) & (pos < hi), gap, 0.0).max()
+            low = ref.features(pflat, tokens[None], model, lowp=True)[0]
+            picked = _head_columns(ref, pflat, low, picked, model, True)[1]
+        feats = ref.features(pflat, tokens[None], model)[0]
+        best, _, of_picked = _head_columns(ref, pflat, feats, picked, model, False)
+        pos = jnp.arange(tokens.shape[0])
+        return jnp.where((pos >= lo) & (pos < hi), best - of_picked, 0.0).max()
 
     worst, n_tokens = 0.0, 0
     with jax.default_matmul_precision("highest"):
         for tokens, prompt_len in sample:
-            padded = np.zeros(t_max, np.int32)
+            # Causal attention: the compared positions do not see the padding,
+            # so the shortest rung that holds the request reads what seq_len would.
+            padded = np.zeros(min(t for t in ladder if t >= len(tokens)), np.int32)
             padded[: len(tokens)] = tokens
             # Position i's logits choose token i + 1: the served tokens are
             # those at prompt_len .. len - 1.
@@ -255,6 +304,7 @@ def run(cell: dict, args, dev: dict, t_start: float, peaks: dict | None) -> dict
     import jax
 
     cfg_file, mix = cell["config_file"], cell["traffic_file"]
+    ref = reference_of(cfg_file)  # a file that names none ends the run here, not after the window
     seed = args.seed
     traced = bool(args.trace) and not args.rehearse
     engine, tracer, params, sizes, eng_kw = build_engine(cell, seed, args.rehearse, traced)
@@ -313,7 +363,9 @@ def run(cell: dict, args, dev: dict, t_start: float, peaks: dict | None) -> dict
     sample = pick_sample(res, args.seconds, cfg_file["correct"]["sample_requests"], seed)
     engine.close()
     del engine, tracer
-    gap, n_tokens = served_gap(sizes, params, sample)
+    t_ref = time.perf_counter()
+    gap, n_tokens = served_gap(ref, sizes, params, sample)
+    log(f"reference and comparison: {time.perf_counter() - t_ref:.2f} s after the window")
     log(f"compared {n_tokens} served tokens of {len(sample)} requests")
     ok, compared = correct.decide(
         {"logit_gap": gap}, limits(cfg_file, args.rehearse),

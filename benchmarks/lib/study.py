@@ -77,6 +77,7 @@ def study_serve(cell, args, emit):
     from lib.weights import make_params
 
     cfg_file, mix = cell["config_file"], cell["traffic_file"]
+    ref = common.reference_of(cfg_file)
     engine, tracer, params, sizes, eng_kw = serve.build_engine(
         cell, args.first_seed, args.rehearse, False)
     if args.rehearse:
@@ -96,7 +97,7 @@ def study_serve(cell, args, emit):
         res = serve.drive(engine, schedule, seconds, float(mix.get("drain_s", 60.0)))
         w = serve.window_metrics(res, seconds)
         sample = serve.pick_sample(res, seconds, cfg_file["correct"]["sample_requests"], seed)
-        gap, n = serve.served_gap(sizes, params, sample)
+        gap, n = serve.served_gap(ref, sizes, params, sample)
         row = {"seed": seed, "program": {"logit_gap": gap}, "tokens": n,
                "attempted": w["attempted"], "failed": w["failed"],
                "why_failed": w["why_failed"], "drained_by_s": res["t_end"]}
@@ -104,7 +105,8 @@ def study_serve(cell, args, emit):
         row["program_correct"] = correct.decide(row["program"], limits,
                                                 extra_ok=w["failed"] == 0 and n > 0)[0]
         if i < args.control_seeds:
-            row["control_fp8"] = {"logit_gap": serve.served_gap(sizes, params, sample, lowp=True)[0]}
+            row["control_fp8"] = {
+                "logit_gap": serve.served_gap(ref, sizes, params, sample, lowp=True)[0]}
             row["control_fp8_correct"] = correct.decide(row["control_fp8"], limits)[0]
         emit(row)
     engine.close()

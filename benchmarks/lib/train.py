@@ -14,7 +14,7 @@ import os
 import time
 
 from . import correct, trace as tracelib
-from .common import ROOT, limits, log, memory_peak_bytes, phase, sized
+from .common import ROOT, limits, log, memory_peak_bytes, phase, reference_of, sized
 from .traffic import synthetic_lm_batch
 from .weights import flat, leaf_paths, make_params
 
@@ -108,48 +108,23 @@ def program_readings(trainer, state, seed: int, steps: int, b1: float):
 
 def reference_readings(cell: dict, cfg, seed: int, steps: int, rehearse: bool,
                        lowp: bool = False, fault=None, rows_per_block: int = 1):
-    """The plain reference over the same first steps, from weights and rows
-    the benchmark makes itself."""
+    """The configuration's plain reference over the same first steps, from
+    weights and rows the benchmark makes itself."""
     import jax
-    from reference import gpt2
 
     cfg_file = cell["config_file"]
+    ref = reference_of(cfg_file)
     model = sized(cfg_file, "model", rehearse)
     opt = sized(cfg_file, "optimizer", rehearse)
-    params = flat(make_params(_param_shapes(model), seed))
+    params = flat(make_params(ref.param_shapes(model), seed))
     batches = [
         synthetic_lm_batch(seed, s, cfg.data.global_batch_size, model["seq_len"],
                            model["vocab_size"])
         for s in range(steps)
     ]
     with jax.default_matmul_precision("highest"):
-        return gpt2.train_steps(
-            params, batches, opt, heads=model["num_heads"],
-            eps=model.get("layer_norm_epsilon", 1e-5), lowp=lowp, fault=fault,
-            rows_per_block=rows_per_block,
-        )
-
-
-def _param_shapes(model: dict):
-    """The checkpoint layout of reference/gpt2.py, from the sizes alone."""
-    import jax
-    import jax.numpy as jnp
-
-    l, d, v, t = (model[k] for k in ("num_layers", "hidden_dim", "vocab_size", "seq_len"))
-    f = d * model.get("mlp_ratio", 4)
-    s = lambda *shape: jax.ShapeDtypeStruct(shape, jnp.float32)
-    dense = lambda i, o: {"bias": s(l, o), "kernel": s(l, i, o)}
-    ln = lambda *lead: {"bias": s(*lead, d), "scale": s(*lead, d)}
-    return {
-        "blocks": {
-            "attn": {n: dense(d, d) for n in ("key", "out", "query", "value")},
-            "ln1": ln(l), "ln2": ln(l),
-            "mlp": {"fc_in": dense(d, f), "fc_out": dense(f, d)},
-        },
-        "ln_f": ln(),
-        "wpe": s(t, d),
-        "wte": {"embedding": s(v, d)},
-    }
+        return ref.train_steps(params, batches, opt, model, lowp=lowp, fault=fault,
+                               rows_per_block=rows_per_block)
 
 
 def _window_records(run_dir: str, first: int, last: int, every: int):
@@ -173,6 +148,7 @@ def run(cell: dict, args, dev: dict, t_start: float, peaks: dict | None) -> dict
     from frl_distributed_ml_scaffold_tpu.trainer.loop import Trainer
 
     cfg_file = cell["config_file"]
+    ref_shapes = reference_of(cfg_file).param_shapes  # a file that names none ends the run here
     seed, rehearse = args.seed, args.rehearse
     cfg = build_config(cell, seed, rehearse)
     batch = cfg.data.global_batch_size
@@ -186,7 +162,7 @@ def run(cell: dict, args, dev: dict, t_start: float, peaks: dict | None) -> dict
     trainer = Trainer(cfg)
     phase("trainer built")
     if leaf_paths(trainer.state_shapes.params) != leaf_paths(
-            _param_shapes(sized(cfg_file, "model", rehearse))):
+            ref_shapes(sized(cfg_file, "model", rehearse))):
         raise SystemExit("the program's parameter tree is not the reference's layout")
     state = fresh_state(trainer, seed)
     jax.block_until_ready(state.params)
@@ -258,7 +234,9 @@ def run(cell: dict, args, dev: dict, t_start: float, peaks: dict | None) -> dict
 
     # Free the program's state before the reference runs.
     del state, trainer
+    t_ref = time.perf_counter()
     ref = reference_readings(cell, cfg, seed, steps_checked, rehearse)
+    log(f"reference: {time.perf_counter() - t_ref:.2f} s after the window")
     numbers = correct.train_numbers(prog, ref)
     numbers["input_mismatch"] = input_mismatch
     ok, compared = correct.decide(numbers, dict(limits(cfg_file, rehearse), input_mismatch=0),

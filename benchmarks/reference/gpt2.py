@@ -5,7 +5,7 @@ precision; no kernels, no cache, no batching tricks. It follows Radford et
 al. 2019 as the published config.json states it (pre-LayerNorm blocks, learned
 positions, tanh-GELU, tied output head). It imports nothing of the program and
 is handed the weights the benchmark made (lib/weights.py), as a flat
-{name: array} dict whose names are the checkpoint layout:
+{name: array} dict whose names are the checkpoint layout (`param_shapes`):
 
   wte/embedding [V,D]  wpe [T,D]  ln_f/{scale,bias} [D]
   blocks/{ln1,ln2}/{scale,bias} [L,D]
@@ -19,6 +19,12 @@ under a per-tensor scale (e4m3 forward, e5m2 for the cotangents that the
 backward products take), the nearest precision below the bfloat16 the
 configurations state. Memory: layers are scanned under jax.checkpoint
 and rows are taken in blocks, so the full width fits beside nothing else.
+
+The drivers call `param_shapes`, `train_steps`, `features` and `head`, and
+hand each the configuration file's `model` group whole (README.md, "The
+reference's interface"); of it this module reads `num_layers`, `hidden_dim`,
+`num_heads`, `seq_len`, `vocab_size`, `mlp_ratio` (4 where absent) and
+`layer_norm_epsilon` (1e-5 where absent).
 """
 
 from __future__ import annotations
@@ -30,9 +36,11 @@ F8_MAX = 448.0  # largest finite float8_e4m3fn
 F8_GRAD_MAX = 57344.0  # largest finite float8_e5m2
 
 
-def _q8(x):
-    """Round to float8_e4m3 under a per-tensor scale; gradient passes through."""
-    scale = jax.lax.stop_gradient(jnp.max(jnp.abs(x)) / F8_MAX + 1e-30)
+def _q8(x, whole=None):
+    """Round to float8_e4m3 under a per-tensor scale; gradient passes through.
+    `whole` is the tensor the scale is taken from where `x` is a part of it."""
+    scale = jax.lax.stop_gradient(
+        jnp.max(jnp.abs(x if whole is None else whole)) / F8_MAX + 1e-30)
     y = (x / scale).astype(jnp.float8_e4m3fn).astype(jnp.float32) * scale
     return x + jax.lax.stop_gradient(y - x)
 
@@ -56,10 +64,29 @@ def _q_cotangent_bwd(_, g):
 _q_cotangent.defvjp(_q_cotangent_fwd, _q_cotangent_bwd)
 
 
-def _mm(a, b, lowp):
+def _mm(a, b, lowp, b_whole=None):
     if lowp:
-        return _q_cotangent(jnp.matmul(_q8(a), _q8(b), precision="highest"))
+        return _q_cotangent(jnp.matmul(_q8(a), _q8(b, b_whole), precision="highest"))
     return jnp.matmul(a, b, precision="highest")
+
+
+def param_shapes(model):
+    """The checkpoint layout as a tree of shapes, from the sizes alone."""
+    l, d, v, t = (model[k] for k in ("num_layers", "hidden_dim", "vocab_size", "seq_len"))
+    f = d * model.get("mlp_ratio", 4)
+    s = lambda *shape: jax.ShapeDtypeStruct(shape, jnp.float32)
+    dense = lambda i, o: {"bias": s(l, o), "kernel": s(l, i, o)}
+    ln = lambda *lead: {"bias": s(*lead, d), "scale": s(*lead, d)}
+    return {
+        "blocks": {
+            "attn": {n: dense(d, d) for n in ("key", "out", "query", "value")},
+            "ln1": ln(l), "ln2": ln(l),
+            "mlp": {"fc_in": dense(d, f), "fc_out": dense(f, d)},
+        },
+        "ln_f": ln(),
+        "wpe": s(t, d),
+        "wte": {"embedding": s(v, d)},
+    }
 
 
 def _layer_norm(x, scale, bias, eps):
@@ -92,8 +119,9 @@ def _block(x, p, heads, eps, lowp):
     return x + _mm(y, p["mlp/fc_out/kernel"], lowp) + p["mlp/fc_out/bias"]
 
 
-def features(params, tokens, *, heads, eps=1e-5, lowp=False):
+def features(params, tokens, model, lowp=False):
     """Final-LayerNorm features [B,T,D] for token ids [B,T]."""
+    heads, eps = model["num_heads"], model.get("layer_norm_epsilon", 1e-5)
     params = {k: v.astype(jnp.float32) for k, v in params.items()}
     t = tokens.shape[1]
     x = params["wte/embedding"][tokens] + params["wpe"][:t]
@@ -108,25 +136,34 @@ def features(params, tokens, *, heads, eps=1e-5, lowp=False):
     return _layer_norm(x, params["ln_f/scale"], params["ln_f/bias"], eps)
 
 
-def logits(params, tokens, **kw):
-    f = features(params, tokens, **kw)
-    return _mm(f, params["wte/embedding"].astype(jnp.float32).T,
-               kw.get("lowp", False))
+def head(params, feats, lo, n, model, lowp=False):
+    """Logits [..., n] of the vocabulary's rows lo .. lo + n - 1 (the tied
+    output head) for features [..., D]: the serving comparison takes the head
+    in such blocks, so that no [T, V] array is made. `n` is static, `lo` may
+    be traced; under `lowp` the scale is the whole matrix's."""
+    w = params["wte/embedding"].astype(jnp.float32)
+    rows = jax.lax.dynamic_slice_in_dim(w, lo, n, axis=0)
+    return _mm(feats, rows.T, lowp, b_whole=w)
 
 
-def loss_sum(params, tokens, **kw):
+def logits(params, tokens, model, lowp=False):
+    f = features(params, tokens, model, lowp)
+    return _mm(f, params["wte/embedding"].astype(jnp.float32).T, lowp)
+
+
+def loss_sum(params, tokens, model, lowp=False):
     """Summed next-token cross-entropy over tokens [B,T+1]."""
-    lg = logits(params, tokens[:, :-1], **kw)
+    lg = logits(params, tokens[:, :-1], model, lowp)
     logp = jax.nn.log_softmax(lg, axis=-1)
     picked = jnp.take_along_axis(logp, tokens[:, 1:, None], axis=-1)
     return -picked.sum()
 
 
-def loss_and_grad(params, tokens, *, rows_per_block=1, **kw):
+def loss_and_grad(params, tokens, model, lowp=False, rows_per_block=1):
     """Mean loss and its gradient over the whole batch, rows in blocks."""
     b, t1 = tokens.shape
     blocks = tokens.reshape(b // rows_per_block, rows_per_block, t1)
-    grad_fn = jax.value_and_grad(lambda p, tk: loss_sum(p, tk, **kw))
+    grad_fn = jax.value_and_grad(lambda p, tk: loss_sum(p, tk, model, lowp))
     zero = jax.tree.map(lambda v: jnp.zeros(v.shape, jnp.float32), params)
 
     def body(acc, tk):
@@ -170,8 +207,8 @@ def adamw_step(params, grads, mu, nu, count, opt):
     return new_p, new_mu, new_nu, grads
 
 
-def train_steps(params, batches, opt, *, heads, eps=1e-5, lowp=False,
-                rows_per_block=1, fault=None):
+def train_steps(params, batches, opt, model, lowp=False, fault=None,
+                rows_per_block=1):
     """Follow the first len(batches) optimizer steps from `params`.
 
     Returns per-step losses, the first gradient as the moments got it (on the
@@ -183,8 +220,7 @@ def train_steps(params, batches, opt, *, heads, eps=1e-5, lowp=False,
 
     @jax.jit
     def step(p, mu, nu, count, tokens):
-        loss, g = loss_and_grad(p, tokens, rows_per_block=rows_per_block,
-                                heads=heads, eps=eps, lowp=lowp)
+        loss, g = loss_and_grad(p, tokens, model, lowp, rows_per_block)
         new_p, mu, nu, seen = adamw_step(p, g, mu, nu, count, opt)
         return new_p, mu, nu, loss, seen, {k: jnp.sqrt(jnp.sum(v * v)) for k, v in seen.items()}
 

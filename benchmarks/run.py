@@ -56,11 +56,13 @@ def metric_specs(cell: dict) -> list[dict]:
 
 
 def reader_for(spec: dict):
-    """metrics/<name>.py with a `read(ctx, spec)` of its own, else the
-    function in lib/readers.py that the file names."""
-    own = os.path.join(BENCH_DIR, "metrics", spec["name"] + ".py")
-    if os.path.exists(own):
-        mod_spec = importlib.util.spec_from_file_location("metric_" + spec["reader"], own)
+    """The `read(ctx, spec)` of metrics/<reader_file>.py where the metric's
+    file names one (several metrics share a reader so), else that of
+    metrics/<name>.py where there is one, else the function in lib/readers.py
+    that the file names as `reader`."""
+    own = os.path.join(BENCH_DIR, "metrics", spec.get("reader_file", spec["name"]) + ".py")
+    if "reader_file" in spec or os.path.exists(own):
+        mod_spec = importlib.util.spec_from_file_location("metric_" + spec["name"], own)
         mod = importlib.util.module_from_spec(mod_spec)
         mod_spec.loader.exec_module(mod)
         return mod.read

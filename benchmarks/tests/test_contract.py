@@ -1,6 +1,10 @@
 """BENCHMARK.json against the contract's letter, and the data files against it."""
 
+import glob
+import io
+import os
 import re
+import tokenize
 
 import pytest
 
@@ -56,6 +60,46 @@ def test_per_layer_entries_agree_with_the_metric_files():
         for cell_name in m["workloads"]:
             cell = common.load_cell(cell_name)
             assert m["name"] in [s["name"] for s in run.metric_specs(cell)]
+
+
+def test_every_metric_file_finds_its_reader():
+    import run
+
+    for path in sorted(glob.glob(os.path.join(common.BENCH_DIR, "metrics", "*.json"))):
+        spec = common.load_json("metrics", os.path.basename(path))
+        assert callable(run.reader_for(spec)), spec["name"]
+    shared = [common.load_json("metrics", n + ".json").get("reader_file") for n in (
+        "graft_program_device_ms", "flash_fwd_ms.train", "flash_bwd_ms.train")]
+    assert shared == ["decode_program_device_ms", "kernel_ms", "kernel_ms"]
+    with pytest.raises(FileNotFoundError):
+        run.reader_for({"name": "x", "reader_file": "no_such_reader"})
+
+
+MODEL_NAME = re.compile(r"gpt2|GPTConfig|\bGPT\b")
+
+
+def test_the_harness_holds_no_models_name():
+    """run.py, lib/ and metrics/ take a configuration's model and reference
+    from its files: outside comments and docstrings no line of them names a
+    model (a reference module, a model class or its config class)."""
+    paths = [os.path.join(common.BENCH_DIR, "run.py")]
+    for sub in ("lib", "metrics"):
+        paths += sorted(glob.glob(os.path.join(common.BENCH_DIR, sub, "*.py")))
+    assert len(paths) > 10
+    named = []
+    for path in paths:
+        with open(path) as fh:
+            source = fh.read()
+        prev = None
+        for tok in tokenize.generate_tokens(io.StringIO(source).readline):
+            prose = tok.type == tokenize.COMMENT or (
+                tok.type == tokenize.STRING and (prev is None or prev in (
+                    tokenize.NEWLINE, tokenize.INDENT, tokenize.DEDENT)))  # a docstring
+            if tok.type not in (tokenize.NL, tokenize.COMMENT):
+                prev = tok.type
+            if not prose and MODEL_NAME.search(tok.string):
+                named.append((os.path.relpath(path, common.BENCH_DIR), tok.start[0], tok.string))
+    assert not named, named
 
 
 def test_config_flops_equal_their_stated_arithmetic():
