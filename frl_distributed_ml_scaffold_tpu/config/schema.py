@@ -467,10 +467,22 @@ class ViTConfig:
 @dataclass(frozen=True)
 class MoEConfig:
     """Mixture-of-experts block config (SURVEY C9). ``num_experts = 0``
-    disables MoE."""
+    disables MoE.
+
+    Two ROUTINGS, which are two models and not two implementations of one
+    (``routing``): ``capacity`` is GShard top-k with a per-group capacity
+    and DROPS, softmax scores and the auxiliary losses — what every
+    training recipe of this repo uses (``gpt2_moe*``); ``dropless`` is
+    top-k with no capacity, so no token is ever dropped, sigmoid or softmax
+    scores, an optional shared expert and a scaling of the routed sum —
+    what the published sparse decoders state and what serving runs
+    (models/moe.py computes it grouped by expert). ``dispatch`` below is
+    NOT that choice: it picks between two formulations of the ``capacity``
+    routing's token exchange with identical semantics (ROADMAP C6)."""
 
     num_experts: int = 0
     top_k: int = 2
+    routing: str = "capacity"  # capacity | dropless
     capacity_factor: float = 1.25
     router_aux_loss: float = 0.01
     # ST-MoE router z-loss coefficient (mean log²-sum-exp of router
@@ -480,8 +492,9 @@ class MoEConfig:
     # with 1/G and capacity is enforced per group. 0 = auto (the mesh's
     # batch-shard count, so each data shard routes its own tokens).
     num_groups: int = 0
-    # Token->expert exchange formulation, identical routing/drop semantics
-    # (seating comes from the same slot-major cumsum either way):
+    # Token->expert exchange formulation of the ``capacity`` routing,
+    # identical routing/drop semantics (seating comes from the same
+    # slot-major cumsum either way):
     #   einsum — one-hot [G,S,E,C] dispatch/combine einsums (GShard); the
     #            exchange is MACs against mostly-zero one-hots, costing
     #            O(S*E*C*D) — comparable to the expert FFN itself at
@@ -490,6 +503,40 @@ class MoEConfig:
     #            scattered into the [E*C] slot table and tokens gathered
     #            by index; ~zero exchange MACs.
     dispatch: str = "einsum"  # einsum | sort
+    # What a published sparse decoder's config.json states (``dropless``
+    # routing reads these; ``capacity`` keeps GPT-2's 4x GELU experts):
+    # the width of a routed expert's gated feed-forward
+    # (``moe_intermediate_size``), the shared experts every token passes
+    # through and their width, the router's score function, whether the
+    # chosen scores are normalised to sum to one (``norm_topk_prob``), and
+    # the factor on the routed sum (``moe_routed_scaling_factor``).
+    expert_dim: int = 0
+    num_shared_experts: int = 0
+    shared_expert_dim: int = 0
+    score_func: str = "softmax"  # softmax | sigmoid
+    norm_topk_prob: bool = True
+    routed_scaling_factor: float = 1.0
+
+
+@dataclass(frozen=True)
+class RopeConfig:
+    """Rotary position parameters of ONE layer type, as a published
+    ``rope_parameters`` entry states them. ``rope_type`` ``default`` is
+    plain rotary; ``yarn`` (Peng et al. 2023, arXiv:2309.00071) blends
+    interpolated and extrapolated frequencies between the ``beta_fast``
+    and ``beta_slow`` rotation counts over ``original_max_position_embeddings``
+    and scales cos and sin by ``attention_factor`` (0 = yarn's own
+    ``0.1 ln(factor) + 1``). ``partial_rotary_factor``: the leading share
+    of a head's dimensions that rotates."""
+
+    rope_type: str = "default"  # default | yarn
+    rope_theta: float = 10000.0
+    partial_rotary_factor: float = 1.0
+    factor: float = 1.0
+    original_max_position_embeddings: int = 0
+    beta_fast: float = 32.0
+    beta_slow: float = 1.0
+    attention_factor: float = 0.0
 
 
 @dataclass(frozen=True)
@@ -536,6 +583,38 @@ class GPTConfig:
     # correctness switch).
     lm_loss_chunk: int = 0
     moe: MoEConfig = field(default_factory=MoEConfig)
+    # ---- The architecture, as a published config.json describes it. The
+    # defaults are GPT-2's (LayerNorm, learned positions, full multi-head
+    # attention with biases, 4x GELU, tied head): its parameter tree and
+    # programs are what they were before these fields existed.
+    norm: str = "layernorm"  # layernorm | rmsnorm (eps: layer_norm_epsilon)
+    position: str = "learned"  # learned (wpe) | rope
+    # Rotary parameters by layer type: ``rope`` for full-attention layers,
+    # ``rope_sliding`` for sliding-window ones.
+    rope: RopeConfig = field(default_factory=RopeConfig)
+    rope_sliding: RopeConfig = field(default_factory=RopeConfig)
+    bias: bool = True  # biases on the attention and feed-forward maps
+    # Grouped KV heads and a head size apart from hidden / heads (0 = as
+    # GPT-2: num_heads KV heads of hidden_dim // num_heads).
+    num_kv_heads: int = 0
+    head_dim: int = 0
+    # One entry a layer, ``full_attention`` or ``sliding_attention``
+    # (empty: every layer full). A sliding layer's position p attends to
+    # positions p - sliding_window < j <= p and has ``num_heads_sliding``
+    # query heads (0 = num_heads). A model that states ``layer_types`` has
+    # layers of unlike shape: its stack is a loop over layers kept apart
+    # (``layer_<i>`` subtrees), not the one scanned ``blocks`` stack.
+    layer_types: tuple[str, ...] = ()
+    num_heads_sliding: int = 0
+    sliding_window: int = 0
+    # Per-head sigmoid gate on the attention output, a linear map of the
+    # layer's normed input, applied before the out projection.
+    attention_gate: bool = False
+    mlp: str = "gelu"  # gelu (fc_in, fc_out) | swiglu (gated, silu)
+    mlp_dim: int = 0  # dense feed-forward width (0 = hidden_dim * mlp_ratio)
+    # With experts: the layers that keep the dense feed-forward.
+    dense_layers: tuple[int, ...] = ()
+    tie_embeddings: bool = True  # False: an output head of its own (lm_head)
     # Pipeline parallelism (SURVEY C7): >1 stages the block stack over the
     # ``pipe`` mesh axis. ``pipeline_microbatches`` = 0 means "same as
     # stages" (the minimum that keeps every stage busy outside the bubble).

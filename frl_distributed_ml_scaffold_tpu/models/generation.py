@@ -488,6 +488,41 @@ def splice_pool_blocks(cache, slot_cache, blk_ids, m0, slot, *,
     return unflatten_dict(out)
 
 
+def splice_kind_pools(cache, slot_cache, ids_full, ids_window, slot, *,
+                      cfg: Any, block_size: int):
+    """``splice_pool_blocks`` for a model with ``layer_types`` (pools by
+    layer kind: models/gpt.py ``kind_pool_leaves``): write one prefilled
+    contiguous slot cache (``layer_<i>/attn/cached_key`` ``[1, S, Hkv,
+    hd]``) into the pools and set the slot's cursor. The FULL kind takes the
+    prompt's ``n_g = len(ids_full)`` blocks, logical block j to physical
+    ``ids_full[j]``. The SLIDING kind takes the prompt's last ``len(
+    ids_window)`` blocks only — a window's worth, ``min(ring places, n_g)``
+    — logical block ``n_g - n_w + j`` to ``ids_window[j]``; a block already
+    wholly behind the window has no home and goes to the trash block 0.
+    In place on the donated pools, like the uniform stack's splice."""
+    from frl_distributed_ml_scaffold_tpu.models.gpt import kind_layers
+
+    bs, n_g = block_size, ids_full.shape[0]
+    out = dict(cache)
+    for kind, layers in kind_layers(cfg).items():
+        ids = ids_full if kind == "full" else ids_window
+        n, first = ids.shape[0], (0 if kind == "full" else n_g - ids_window.shape[0])
+        for slot_name, pool_name in POOL_LEAF_OF.items():
+            pool_name = f"{pool_name}_{kind}"
+            if pool_name not in cache:
+                continue  # the scale leaves: these pools are not quantized
+            rows = jnp.stack([
+                slot_cache[f"layer_{i}"]["attn"][slot_name][0] for i in layers
+            ])  # [Lk, S, Hkv, hd]
+            blocks = rows[:, first * bs:(first + n) * bs].reshape(
+                len(layers), n, bs, -1)
+            out[pool_name] = out[pool_name].at[:, ids].set(
+                blocks.astype(out[pool_name].dtype))
+    out["pos_index"] = cache["pos_index"].at[slot].set(
+        slot_cache["pos_index"][0])
+    return out
+
+
 def pool_block_bytes(cache) -> int:
     """HBM bytes of ONE pool block across all layers — K/V payloads AND
     quantization-scale blocks, from the ACTUAL pool leaves (the paged

@@ -330,6 +330,198 @@ def _masked_dense_attention(q, k, v, mask):
     return out.astype(q.dtype)
 
 
+# ------------------------------------------------- layers of unlike shape
+#
+# A config that states ``layer_types`` describes a stack whose layers differ:
+# full and sliding-window attention with their own head counts and rotary
+# parameters, a dense feed-forward before sparse ones. The helpers below are
+# the one place that reads those fields; the attention of such a stack is
+# ``GroupedAttention`` and its layers are kept apart (``GPT.__call__``).
+
+#: Short names of the two layer kinds: the suffix of a kind's pool leaves
+#: and block table in the paged cache (``key_pool_full`` ...).
+KINDS = {"full_attention": "full", "sliding_attention": "sliding"}
+
+
+def layer_kind(cfg: GPTConfig, i: int) -> str:
+    """``full`` or ``sliding``: the kind of layer ``i``."""
+    if not cfg.layer_types:
+        return "full"
+    if len(cfg.layer_types) != cfg.num_layers:
+        raise ValueError(
+            f"layer_types has {len(cfg.layer_types)} entries for "
+            f"num_layers={cfg.num_layers}"
+        )
+    return KINDS[cfg.layer_types[i]]
+
+
+def kind_layers(cfg: GPTConfig) -> dict[str, list[int]]:
+    """kind -> its layers' indices in order (kinds with no layer left out):
+    a layer's place in this list is its row in the kind's pool."""
+    out: dict[str, list[int]] = {}
+    for i in range(cfg.num_layers):
+        out.setdefault(layer_kind(cfg, i), []).append(i)
+    return out
+
+
+def attn_geometry(cfg: GPTConfig, kind: str):
+    """(query heads, KV heads, head size, window, rotary parameters) of a
+    layer of ``kind``; window 0 = unbounded."""
+    sliding = kind == "sliding"
+    h = (cfg.num_heads_sliding or cfg.num_heads) if sliding else cfg.num_heads
+    h_kv = cfg.num_kv_heads or h
+    hd = cfg.head_dim or cfg.hidden_dim // cfg.num_heads
+    if h % h_kv:
+        raise ValueError(f"{h} query heads do not group over {h_kv} KV heads")
+    if sliding and cfg.sliding_window < 1:
+        raise ValueError("sliding_attention layers need sliding_window >= 1")
+    return (h, h_kv, hd, cfg.sliding_window if sliding else 0,
+            cfg.rope_sliding if sliding else cfg.rope)
+
+
+def window_table_blocks(cfg: GPTConfig, block_size: int) -> int:
+    """Places in a sliding layer's block table: the blocks a window can
+    touch, ``ceil(W / bs) + 1`` (a window that does not start on a block
+    boundary reaches into one more). The table is a RING: logical block j
+    sits at place ``j % places``, and a block the window has left gives its
+    place to the block that many further on."""
+    return -(-cfg.sliding_window // block_size) + 1
+
+
+def window_pool_blocks(cfg: GPTConfig, block_size: int, batch: int) -> int:
+    """Blocks of the sliding layers' pool: a ring's worth for each of
+    ``batch`` slot rows, what they can hold at once, and the trash block 0.
+    Worked out, not configured: no slot can hold more, so a smaller pool is
+    the only other size that means anything, and nothing sizes one yet."""
+    return batch * window_table_blocks(cfg, block_size) + 1
+
+
+def rope_inv_freq(rope, head_dim: int):
+    """(inverse frequencies ``[rot / 2]``, factor on cos and sin, rotating
+    dimensions) of one layer type's rotary parameters. ``yarn``: Peng et
+    al. 2023 as the published configs state it — dimensions that turn more
+    than ``beta_fast`` times over the original context keep their frequency,
+    those under ``beta_slow`` turns are interpolated by ``factor``, a linear
+    ramp between; cos and sin carry ``attention_factor``."""
+    import math
+
+    import numpy as np
+
+    rot = int(head_dim * rope.partial_rotary_factor)
+    rot -= rot % 2
+    inv = 1.0 / (rope.rope_theta ** (np.arange(0, rot, 2) / rot))
+    if rope.rope_type == "default":
+        return inv, 1.0, rot
+    if rope.rope_type != "yarn":
+        raise ValueError(f"unknown rope_type {rope.rope_type!r} (default | yarn)")
+    orig = rope.original_max_position_embeddings
+
+    def turns_at(n):  # the dimension that turns n times over `orig` positions
+        return rot * math.log(orig / (n * 2 * math.pi)) / (
+            2 * math.log(rope.rope_theta))
+
+    low = max(math.floor(turns_at(rope.beta_fast)), 0)
+    high = min(math.ceil(turns_at(rope.beta_slow)), rot - 1)
+    ramp = np.clip(
+        (np.arange(rot // 2) - low) / max(high - low, 1e-3), 0.0, 1.0
+    )
+    inv = inv / rope.factor * ramp + inv * (1.0 - ramp)
+    mscale = rope.attention_factor or 0.1 * math.log(rope.factor) + 1.0
+    return inv, mscale, rot
+
+
+def apply_rope(x, positions, rope, head_dim: int):
+    """Rotate the leading ``rot`` dimensions of every head of ``x``
+    ``[B, T, H, hd]`` by its position ``[B, T]`` (halves paired: dimension i
+    with i + rot/2); fp32 inside."""
+    inv, mscale, rot = rope_inv_freq(rope, head_dim)
+    if rot == 0:
+        return x
+    ang = positions.astype(jnp.float32)[..., None] * jnp.asarray(
+        inv, jnp.float32
+    )  # [B, T, rot/2]
+    cos = (jnp.cos(ang) * mscale)[:, :, None, :]
+    sin = (jnp.sin(ang) * mscale)[:, :, None, :]
+    x32 = x.astype(jnp.float32)
+    a, b_ = x32[..., : rot // 2], x32[..., rot // 2 : rot]
+    out = jnp.concatenate(
+        [a * cos - b_ * sin, b_ * cos + a * sin, x32[..., rot:]], axis=-1
+    )
+    return out.astype(x.dtype)
+
+
+#: Query rows a block of ``grouped_attention``: the score strip of one block
+#: is ``[B, H, rows, keys]`` in fp32 (64 heads x 256 x 4096: 268 MB), never
+#: ``[B, H, T, T]``.
+_QUERY_BLOCK = 256
+
+
+def grouped_attention(q, k, v, qpos, *, window: int = 0):
+    """Causal attention of ``q [B, T, Hq, hd]`` over keys ``k, v
+    [B, S, Hkv, hd]`` that sit at positions ``0 .. S-1``: query head i reads
+    KV head ``i // (Hq / Hkv)``, the query at position ``qpos [B, T]``
+    attends positions ``qpos - window < j <= qpos`` (``window`` 0:
+    unbounded). fp32 softmax. Queries go in blocks of ``_QUERY_BLOCK`` rows,
+    and a windowed block reads only the keys its rows can reach, so the
+    scores are never held whole. ``qpos`` must not decrease along a row
+    (left-pad columns clip to 0)."""
+    b, t, hq, hd = q.shape
+    s, hkv = k.shape[1], k.shape[2]
+    g = hq // hkv
+    scale = 1.0 / (hd ** 0.5)
+
+    def attend(qc, qp, kc, vc, kp):
+        c = qc.shape[1]
+        sc = jnp.einsum(
+            "bckgd,bjkd->bkgcj", qc.reshape(b, c, hkv, g, hd), kc,
+            preferred_element_type=jnp.float32,
+        ) * scale
+        mask = kp[:, None, :] <= qp[:, :, None]  # [B, C, K]
+        if window:
+            mask &= kp[:, None, :] > qp[:, :, None] - window
+        sc = jnp.where(mask[:, None, None], sc, jnp.finfo(jnp.float32).min)
+        p = jax.nn.softmax(sc, axis=-1)
+        out = jnp.einsum(
+            "bkgcj,bjkd->bckgd", p.astype(q.dtype), vc,
+            preferred_element_type=jnp.float32,
+        )
+        return out.reshape(b, c, hq, hd).astype(q.dtype)
+
+    all_pos = jnp.broadcast_to(jnp.arange(s)[None], (b, s))
+    if t <= _QUERY_BLOCK or t % _QUERY_BLOCK:
+        return attend(q, qpos, k, v, all_pos)
+    c = _QUERY_BLOCK
+    kw = c + window  # keys a windowed block can reach
+
+    def block(i):
+        qc = jax.lax.dynamic_slice_in_dim(q, i * c, c, axis=1)
+        qp = jax.lax.dynamic_slice_in_dim(qpos, i * c, c, axis=1)
+        if not window or kw >= s:
+            return attend(qc, qp, k, v, all_pos)
+        start = jnp.clip(qp[:, 0] - window + 1, 0, s - kw)  # [B]
+        cut = jax.vmap(
+            lambda x, at: jax.lax.dynamic_slice_in_dim(x, at, kw, axis=0)
+        )
+        return attend(qc, qp, cut(k, start), cut(v, start),
+                      start[:, None] + jnp.arange(kw)[None])
+
+    out = jax.lax.map(block, jnp.arange(t // c))  # [n, B, C, Hq, hd]
+    return jnp.swapaxes(out, 0, 1).reshape(b, t, hq, hd)
+
+
+def make_norm(cfg: GPTConfig, name: str):
+    """The config's normalisation, fp32: LayerNorm (GPT-2) or RMSNorm."""
+    if cfg.norm == "rmsnorm":
+        return nn.RMSNorm(
+            dtype=jnp.float32, epsilon=cfg.layer_norm_epsilon, name=name
+        )
+    if cfg.norm != "layernorm":
+        raise ValueError(f"unknown norm {cfg.norm!r} (layernorm | rmsnorm)")
+    return nn.LayerNorm(
+        dtype=jnp.float32, epsilon=cfg.layer_norm_epsilon, name=name
+    )
+
+
 def _constrain_kv_pool(x: jnp.ndarray, heads: int) -> jnp.ndarray:
     """Pin a PAGED cache leaf — the stacked lane-dense K/V pools
     ``[L, N, bs, H*hd]`` or their scale pools ``[L, N, H*bs]`` —
@@ -374,6 +566,11 @@ def paged_cache_leaves(
     there and back: PERF.md §6, PR 28.) A quantized pool's scales are
     ``[L, N, H*bs]`` for the same reason: a block's per-(position, head)
     scales as one row, heads major."""
+    if cfg.layer_types:
+        raise ValueError(
+            "a model with layer_types keeps a pool for each layer kind: "
+            "kind_pool_leaves"
+        )
     h = cfg.num_heads
     hd = cfg.hidden_dim // h
     layers = cfg.num_layers
@@ -391,6 +588,40 @@ def paged_cache_leaves(
     return leaves
 
 
+def kind_pool_leaves(
+    cfg: GPTConfig, dtype: Any, *, block_size: int, pool_blocks: int,
+    batch: int,
+) -> dict[str, tuple[tuple[int, ...], Any]]:
+    """Name -> (shape, dtype) of the PAGED cache of a model with
+    ``layer_types``: for each layer kind a K and a V pool, lane-dense
+    ``[layers of the kind, blocks, bs, Hkv*hd]`` (``paged_cache_leaves``
+    says why), and its block table. A full layer keeps every position of a
+    request, so its table has ``ceil(seq_len / bs)`` places; a sliding
+    layer keeps a window's worth, so its table is a ring of
+    ``window_table_blocks`` places whatever the context, and its pool
+    holds a ring for each row (``window_pool_blocks``). One write cursor
+    for all layers (``pos_index``), and the expert layers' counts of the
+    last step (``moe_stats``: experts touched, pairs)."""
+    _, h_kv, hd, _, _ = attn_geometry(cfg, "full")
+    row = h_kv * hd
+    counts = {k: len(v) for k, v in kind_layers(cfg).items()}
+    blocks = {"full": pool_blocks,
+              "sliding": window_pool_blocks(cfg, block_size, batch)
+              if "sliding" in counts else 0}
+    places = {"full": -(-cfg.seq_len // block_size),
+              "sliding": window_table_blocks(cfg, block_size)
+              if "sliding" in counts else 0}
+    leaves = {}
+    for kind, n in counts.items():
+        pool = ((n, blocks[kind], block_size, row), dtype)
+        leaves[f"key_pool_{kind}"] = leaves[f"value_pool_{kind}"] = pool
+        leaves["block_tables" + ("" if kind == "full" else f"_{kind}")] = (
+            (batch, places[kind]), jnp.int32)
+    leaves["pos_index"] = ((batch,), jnp.int32)
+    leaves["moe_stats"] = ((2,), jnp.int32)
+    return leaves
+
+
 def init_paged_cache(model: "GPT", batch: int) -> Any:
     """The EMPTY paged decode cache of ``model`` (a ``GPT`` cloned with
     ``kv_block_size`` / ``kv_pool_blocks``) for ``batch`` slot rows:
@@ -402,6 +633,14 @@ def init_paged_cache(model: "GPT", batch: int) -> Any:
     iteration has yet to create. Traceable: wrap in ``jax.jit`` or
     ``jax.eval_shape`` as needed."""
     cfg = model.config
+    if cfg.layer_types:
+        return {
+            n: jnp.zeros(shape, dt) for n, (shape, dt) in kind_pool_leaves(
+                cfg, model.policy.compute_dtype,
+                block_size=model.kv_block_size,
+                pool_blocks=model.kv_pool_blocks, batch=batch,
+            ).items()
+        }
     attn = paged_cache_leaves(
         cfg, model.policy.compute_dtype, block_size=model.kv_block_size,
         pool_blocks=model.kv_pool_blocks, batch=batch,
@@ -798,6 +1037,104 @@ class CausalSelfAttention(nn.Module):
         return y
 
 
+class GroupedAttention(nn.Module):
+    """The attention of a stack with ``layer_types``: query heads grouped
+    over fewer KV heads, a head size of its own, rotary positions by layer
+    kind, an optional sliding window and per-head output gate. Three ways
+    in, one set of weights: the plain causal forward; the CONTIGUOUS cache
+    (``generate`` and the engine's prefill: every position kept, the window
+    a mask); and the PAGED decode step over the pools of the layer's kind,
+    which are handed in and handed back (the layer loop of ``GPT.__call__``
+    carries them; one layer's row of the pool is updated in place)."""
+
+    config: GPTConfig
+    dtype: Any
+    kind: str  # full | sliding
+    row: int  # this layer's row in its kind's pool
+    cache_len: int = 0
+    kv_block_size: int = 0
+
+    @nn.compact
+    def __call__(self, x, *, train: bool, decode: bool, ctx: dict):
+        cfg = self.config
+        h, h_kv, hd, window, rope = attn_geometry(cfg, self.kind)
+        b, t, _ = x.shape
+        dense = lambda n, name: nn.Dense(  # noqa: E731
+            n, use_bias=cfg.bias, dtype=self.dtype, name=name
+        )
+        q = dense(h * hd, "query")(x).reshape(b, t, h, hd)
+        k = dense(h_kv * hd, "key")(x).reshape(b, t, h_kv, hd)
+        v = dense(h_kv * hd, "value")(x).reshape(b, t, h_kv, hd)
+        positions = ctx["positions"]  # [B, T] absolute
+        if cfg.position == "rope":
+            q = apply_rope(q, positions, rope, hd)
+            k = apply_rope(k, positions, rope, hd)
+        pools = ctx.get("pools")
+        if not decode:
+            y = grouped_attention(q, k, v, positions, window=window)
+        elif pools is None:
+            # Contiguous cache [B, S, Hkv, hd] by absolute position, as
+            # CausalSelfAttention keeps it: left-padded prompts are rolled
+            # so that real tokens land at [0, len); what wraps lands past
+            # the row's length, masked now and overwritten later.
+            s = self.cache_len or cfg.seq_len
+            ck = self.variable(
+                "cache", "cached_key", jnp.zeros, (b, s, h_kv, hd), self.dtype)
+            cv = self.variable(
+                "cache", "cached_value", jnp.zeros, (b, s, h_kv, hd),
+                self.dtype)
+            idx, pad = ctx["idx"], t - ctx["lengths"]
+            k_w, v_w = k.astype(self.dtype), v.astype(self.dtype)
+            if t > 1:
+                roll = (jnp.arange(t)[None, :] + pad[:, None]) % t
+                k_w = jnp.take_along_axis(k_w, roll[:, :, None, None], axis=1)
+                v_w = jnp.take_along_axis(v_w, roll[:, :, None, None], axis=1)
+            rows = jnp.arange(b)[:, None]
+            cols = idx[:, None] + jnp.arange(t)[None, :]
+            ck.value = ck.value.at[rows, cols].set(k_w, mode="drop")
+            cv.value = cv.value.at[rows, cols].set(v_w, mode="drop")
+            y = grouped_attention(q, ck.value, cv.value, positions,
+                                  window=window)
+        else:
+            from frl_distributed_ml_scaffold_tpu.ops.decode_attention import (
+                paged_grouped_decode_attention,
+            )
+
+            if t != 1:
+                raise NotImplementedError(
+                    "the pools of a model with layer_types take single-token "
+                    "decode steps only (no verify tile: speculation is "
+                    "refused at the engine's construction)"
+                )
+            k_pool, v_pool = pools[self.kind]
+            tbl = ctx["tables"][self.kind]
+            bs, places = self.kv_block_size, tbl.shape[1]
+            idx = ctx["idx"]  # [B]: this step's write position
+            blk = idx // bs
+            # A sliding layer's table is a ring; a full layer's is clamped
+            # like the uniform stack's (a retired row's cursor runs on).
+            place = blk % places if window else jnp.minimum(blk, places - 1)
+            phys = jnp.take_along_axis(tbl, place[:, None], axis=1)[:, 0]
+            at = (self.row, phys, idx % bs)
+            k_pool = k_pool.at[at].set(
+                k[:, 0].reshape(b, h_kv * hd).astype(k_pool.dtype))
+            v_pool = v_pool.at[at].set(
+                v[:, 0].reshape(b, h_kv * hd).astype(v_pool.dtype))
+            pools = {**pools, self.kind: (k_pool, v_pool)}
+            y = paged_grouped_decode_attention(
+                q[:, 0], k_pool, v_pool, idx + 1, tbl, self.row,
+                window=window, impl=cfg.decode_attention,
+                name=f"attn_mixed_decode_{self.kind}",
+            )[:, None]
+        if cfg.attention_gate:
+            gate = nn.Dense(h, use_bias=False, dtype=self.dtype, name="gate")(x)
+            y = y * jax.nn.sigmoid(gate.astype(jnp.float32)).astype(
+                y.dtype)[..., None]
+        y = dense(cfg.hidden_dim, "out")(y.reshape(b, t, h * hd))
+        y = nn.Dropout(cfg.dropout, deterministic=not train)(y)
+        return y, pools
+
+
 class GptMlp(nn.Module):
     config: GPTConfig
     dtype: Any
@@ -809,15 +1146,25 @@ class GptMlp(nn.Module):
         tp = self.tp
         ag_dg = tp.ag_dot_general if tp is not None else None
         mrs_dg = tp.mrs_dot_general if tp is not None else None
+        width = cfg.mlp_dim or cfg.hidden_dim * cfg.mlp_ratio
+        if cfg.mlp == "swiglu":
+            from frl_distributed_ml_scaffold_tpu.models.moe import gated_ffn
+
+            y = gated_ffn(x, width, cfg.hidden_dim, cfg.bias, self.dtype)
+            return nn.Dropout(cfg.dropout, deterministic=not train)(y)
+        if cfg.mlp != "gelu":
+            raise ValueError(f"unknown mlp {cfg.mlp!r} (gelu | swiglu)")
         y = nn.Dense(
-            cfg.hidden_dim * cfg.mlp_ratio,
+            width,
+            use_bias=cfg.bias,
             dtype=self.dtype,
             name="fc_in",
             dot_general=ag_dg,
         )(x)
         y = nn.gelu(y)
         y = nn.Dense(
-            cfg.hidden_dim, dtype=self.dtype, name="fc_out", dot_general=mrs_dg
+            cfg.hidden_dim, use_bias=cfg.bias, dtype=self.dtype,
+            name="fc_out", dot_general=mrs_dg,
         )(y)
         y = nn.Dropout(cfg.dropout, deterministic=not train)(y)
         return y
@@ -832,9 +1179,45 @@ class Block(nn.Module):
     cache_len: int = 0  # decode cache bucket (0 = config.seq_len)
     kv_block_size: int = 0  # paged decode pool (0 = contiguous cache)
     kv_pool_blocks: int = 0
+    # A layer kept APART (a stack with ``layer_types``): its index in the
+    # model, which says its kind, its row in the kind's pool and whether
+    # its feed-forward is dense. -1: a layer of the scanned uniform stack.
+    index: int = -1
+
+    def _apart(self, carry):
+        """One layer of a stack with ``layer_types``. The carry is ``(x,
+        aux loss, ctx)``; ``ctx`` holds what every layer reads (positions,
+        write cursor, prompt lengths, block tables, the mask of tokens that
+        are real) and what the layers hand on: the pools by kind and the
+        expert layers' counts."""
+        x, aux_loss, ctx = carry
+        cfg, train, i = self.config, self.train, self.index
+        kind = layer_kind(cfg, i)
+        y = make_norm(cfg, "ln1")(x)
+        attn_out, pools = GroupedAttention(
+            cfg, self.dtype, kind, kind_layers(cfg)[kind].index(i),
+            cache_len=self.cache_len, kv_block_size=self.kv_block_size,
+            name="attn",
+        )(y, train=train, decode=self.decode, ctx=ctx)
+        x = x + attn_out
+        y = make_norm(cfg, "ln2")(x)
+        if cfg.moe.num_experts > 0 and i not in cfg.dense_layers:
+            from frl_distributed_ml_scaffold_tpu.models.moe import MoEMlp
+
+            mlp_out, extra = MoEMlp(cfg, self.dtype, name="moe")(
+                y, train=train, token_mask=ctx.get("token_mask"))
+            if cfg.moe.routing == "dropless":
+                ctx = {**ctx, "moe_stats": ctx["moe_stats"] + extra}
+            else:
+                aux_loss = aux_loss + extra
+        else:
+            mlp_out = GptMlp(cfg, self.dtype, name="mlp")(y, train=train)
+        return (x + mlp_out, aux_loss, {**ctx, "pools": pools}), None
 
     @nn.compact
     def __call__(self, carry, layer):
+        if self.index >= 0:
+            return self._apart(carry)
         # Decode mode threads the per-row prompt lengths through the scan
         # carry (a traced array cannot be a module attribute); they are
         # loop-invariant. Paged decode additionally threads the per-row
@@ -850,7 +1233,7 @@ class Block(nn.Module):
         else:
             (x, aux_loss), lengths = carry, None
         cfg, train, tp = self.config, self.train, self.tp
-        y = nn.LayerNorm(dtype=jnp.float32, epsilon=cfg.layer_norm_epsilon, name="ln1")(x)
+        y = make_norm(cfg, "ln1")(x)
         attn_out = CausalSelfAttention(
             cfg, self.dtype, tp=tp, cache_len=self.cache_len,
             kv_block_size=self.kv_block_size,
@@ -869,7 +1252,7 @@ class Block(nn.Module):
             # LayerNorms are per-token, so anchoring here keeps the whole
             # inter-matmul segment local.
             x = tp.constrain_stream(x)
-        y = nn.LayerNorm(dtype=jnp.float32, epsilon=cfg.layer_norm_epsilon, name="ln2")(x)
+        y = make_norm(cfg, "ln2")(x)
         if cfg.moe.num_experts > 0:
             from frl_distributed_ml_scaffold_tpu.models.moe import MoEMlp
 
@@ -922,6 +1305,57 @@ class GPT(nn.Module):
     # cache is built by ``init_paged_cache`` before the first step.
     kv_block_size: int = 0
     kv_pool_blocks: int = 0
+    # (A model with ``layer_types`` keeps a pool for each layer kind:
+    # ``kv_pool_blocks`` is the full kind's, the sliding kind's follows
+    # from the slot rows, ``window_pool_blocks``.)
+
+    def _layers_apart(self, x, *, train, decode, paged, positions, idx, lens):
+        """The stack of a model with ``layer_types``: layers of unlike shape
+        (head counts by kind, a dense feed-forward before sparse ones) cannot
+        share one scanned body, so each is a module of its own,
+        ``layer_<i>``, called in turn. In a paged decode step the loop
+        carries the pools of both kinds whole — each layer updates its row
+        of its kind's pool in place and hands the pools on — so nothing
+        pool-sized is cut out or copied (tests/test_chip_compile.py)."""
+        cfg = self.config
+        if cfg.pipeline_stages > 1 or self.param_hooks or self.tp_overlap:
+            raise NotImplementedError(
+                "a model with layer_types runs on the plain layer loop: no "
+                "pipeline stages, no overlap hooks"
+            )
+        t = x.shape[1]
+        ctx = {"positions": positions, "idx": idx, "lengths": lens,
+               "moe_stats": jnp.zeros((2,), jnp.int32)}
+        kinds = list(kind_layers(cfg))
+        if paged:
+            table_of = lambda k: "block_tables" + (  # noqa: E731
+                "" if k == "full" else f"_{k}")
+            ctx["tables"] = {
+                k: self.get_variable("cache", table_of(k)) for k in kinds}
+            ctx["pools"] = {
+                k: (self.get_variable("cache", f"key_pool_{k}"),
+                    self.get_variable("cache", f"value_pool_{k}"))
+                for k in kinds}
+            # A slot row with no request points at the trash block 0: its
+            # token goes to no expert (it would cost an expert's read).
+            ctx["token_mask"] = (ctx["tables"]["full"][:, :1] != 0)
+        elif decode:
+            ctx["token_mask"] = (
+                jnp.arange(t)[None, :] >= (t - lens)[:, None])  # not padding
+        aux = jnp.zeros((), jnp.float32)
+        for i in range(cfg.num_layers):
+            (x, aux, ctx), _ = Block(
+                cfg, self.policy.compute_dtype, train, decode, None,
+                self.cache_len if decode else 0,
+                self.kv_block_size if paged else 0, 0, index=i,
+                name=f"layer_{i}",
+            )((x, aux, ctx), None)
+        if paged:
+            for k, (k_pool, v_pool) in ctx["pools"].items():
+                self.put_variable("cache", f"key_pool_{k}", k_pool)
+                self.put_variable("cache", f"value_pool_{k}", v_pool)
+            self.put_variable("cache", "moe_stats", ctx["moe_stats"])
+        return x, aux
 
     @nn.compact
     def __call__(
@@ -936,6 +1370,7 @@ class GPT(nn.Module):
         cfg = self.config
         dtype = self.policy.compute_dtype
         b, t = tokens.shape
+        apart = bool(cfg.layer_types)
         if lengths is not None and not decode:
             raise ValueError(
                 "lengths (ragged left-padded prompts) is a decode-mode "
@@ -965,9 +1400,16 @@ class GPT(nn.Module):
             embedding_init=nn.initializers.normal(stddev=0.02),
             name="wte",
         )
-        wpe = self.param(
-            "wpe", nn.initializers.normal(stddev=0.02), (cfg.seq_len, cfg.hidden_dim)
-        )
+        if cfg.position not in ("learned", "rope"):
+            raise ValueError(
+                f"unknown position {cfg.position!r} (learned | rope)")
+        learned = cfg.position == "learned"
+        if learned:
+            wpe = self.param(
+                "wpe", nn.initializers.normal(stddev=0.02),
+                (cfg.seq_len, cfg.hidden_dim),
+            )
+        idx0 = lens = None
         if decode:
             # Positions are absolute and PER ROW: offset by how much of
             # each row's cache this call's tokens come after (tracked here
@@ -993,11 +1435,14 @@ class GPT(nn.Module):
                 0,
                 cfg.seq_len - 1,
             )  # [B, t]
-            pe = jnp.take(wpe, pos_ids, axis=0)  # [B, t, D]
+            idx0 = pos.value  # the write cursor before this call
             pos.value = pos.value + lens
         else:
-            pe = wpe[:t]
-        x = wte(tokens) + pe.astype(dtype)
+            pos_ids = jnp.broadcast_to(jnp.arange(t)[None], (b, t))
+        x = wte(tokens)
+        if learned:
+            pe = jnp.take(wpe, pos_ids, axis=0) if decode else wpe[:t]
+            x = x + pe.astype(dtype)  # [B, t, D] / [t, D]
         x = nn.Dropout(cfg.dropout, deterministic=not train)(x)
 
         if decode and cfg.pipeline_stages > 1:
@@ -1008,7 +1453,12 @@ class GPT(nn.Module):
                 "params automatically (unstack_pipeline_params); only a "
                 "direct apply(decode=True) needs pipeline_stages=1"
             )
-        if cfg.pipeline_stages > 1:
+        if apart:
+            x, aux_loss = self._layers_apart(
+                x, train=train, decode=decode, paged=paged,
+                positions=pos_ids, idx=idx0, lens=lens,
+            )
+        elif cfg.pipeline_stages > 1:
             # flash/ring/ulysses open their own shard_map regions; the
             # pipeline's stage vmap names its axis (spmd_axis_name="pipe"),
             # so those regions batch over the stage dim and compose — no
@@ -1090,7 +1540,7 @@ class GPT(nn.Module):
                     (x, jnp.zeros((), jnp.float32)), None
                 )
 
-        x = nn.LayerNorm(dtype=jnp.float32, epsilon=cfg.layer_norm_epsilon, name="ln_f")(x)
+        x = make_norm(cfg, "ln_f")(x)
         if return_features:
             # Pre-head features for the chunked-vocab LM loss (the weight-
             # tied head lives at params['wte']['embedding']; the loss
@@ -1100,7 +1550,12 @@ class GPT(nn.Module):
             if cfg.moe.num_experts > 0:
                 return feats, aux_loss
             return feats
-        logits = wte.attend(x.astype(dtype))  # weight-tied LM head
+        if cfg.tie_embeddings:
+            logits = wte.attend(x.astype(dtype))  # weight-tied LM head
+        else:
+            logits = nn.Dense(
+                cfg.vocab_size, use_bias=False, dtype=dtype, name="lm_head"
+            )(x.astype(dtype))
         if cfg.moe.num_experts > 0:
             return logits, aux_loss
         return logits

@@ -22,6 +22,21 @@ any capacity drop).
 Router math in fp32. Load-balance aux loss per GShard/Switch over ALL k
 assignment slots, plus the ST-MoE router z-loss (mean log²-sum-exp of the
 router logits) that keeps logits from drifting into bf16-hostile ranges.
+
+**Which routing a recipe uses** (``moe.routing``; two models, not two
+implementations): everything above is the ``capacity`` routing — GShard
+top-k with a per-group capacity and DROPS — which every training recipe of
+this repo uses (``gpt2_moe*``); ``moe.dispatch`` (einsum | sort) picks
+between two formulations of ITS token exchange and nothing else. The
+``dropless`` routing (``MoEMlp._dropless``) is what the published sparse
+decoders state and what serving runs: the top-k of sigmoid (or softmax)
+scores with no capacity, so no token is ever dropped; the chosen scores
+normalised and scaled; a shared expert every token passes through; every
+expert a gated feed-forward. Its products are computed grouped by expert
+(ops/grouped_experts.py: pairs sorted by expert, one pass over row tiles,
+only the experts that got a pair are read), and it reports how many experts
+it touched and how many pairs it computed, for the serving engine's
+``decode`` span.
 """
 
 from __future__ import annotations
@@ -84,9 +99,22 @@ class MoEMlp(nn.Module):
     dtype: Any
 
     @nn.compact
-    def __call__(self, x: jnp.ndarray, *, train: bool) -> tuple[jnp.ndarray, jnp.ndarray]:
+    def __call__(
+        self, x: jnp.ndarray, *, train: bool, token_mask=None
+    ) -> tuple[jnp.ndarray, jnp.ndarray]:
+        """``capacity`` routing: ``(y, aux loss)``. ``dropless`` routing:
+        ``(y, stats)`` with ``stats`` int32 ``[2]`` — distinct experts that
+        got a pair, and pairs — over the tokens ``token_mask [B, T]`` keeps
+        (padding columns and dead slot rows route nowhere)."""
         cfg = self.config
         moe = cfg.moe
+        if moe.routing == "dropless":
+            return self._dropless(x, token_mask)
+        if moe.routing != "capacity":
+            raise ValueError(
+                f"moe.routing={moe.routing!r}: expected 'capacity' or "
+                "'dropless'"
+            )
         d = cfg.hidden_dim
         hidden = d * cfg.mlp_ratio
         e, k = moe.num_experts, moe.top_k
@@ -234,3 +262,101 @@ class MoEMlp(nn.Module):
             aux = aux + moe.router_z_loss * jnp.mean(z * z)
 
         return y.reshape(b, t, d), aux
+
+    def _dropless(self, x, token_mask):
+        """Top-k without drops, grouped by expert (module docstring)."""
+        from frl_distributed_ml_scaffold_tpu.ops.grouped_experts import (
+            expert_ffn_grouped,
+            grouped_layout,
+            tile_rows,
+        )
+
+        cfg, moe = self.config, self.config.moe
+        d, e, k, f = cfg.hidden_dim, moe.num_experts, moe.top_k, moe.expert_dim
+        b, t, _ = x.shape
+        n = b * t
+        xf = x.reshape(n, d).astype(self.dtype)
+        # Router in fp32, from the norm's fp32 output and at full matmul
+        # precision (the TPU's default would round both operands to bf16):
+        # the scores decide which experts a token gets, and the eighth and
+        # ninth of 256 lie closer than bf16 resolves.
+        logits = nn.Dense(
+            e, use_bias=False, dtype=jnp.float32, precision="highest",
+            name="router",
+        )(x.reshape(n, d).astype(jnp.float32))
+        if moe.score_func == "sigmoid":
+            scores = jax.nn.sigmoid(logits)
+        elif moe.score_func == "softmax":
+            scores = jax.nn.softmax(logits, axis=-1)
+        else:
+            raise ValueError(
+                f"moe.score_func={moe.score_func!r}: expected 'sigmoid' or "
+                "'softmax'"
+            )
+        top, idx = jax.lax.top_k(scores, k)  # [N, k]
+        if moe.norm_topk_prob:
+            top = top / jnp.maximum(top.sum(-1, keepdims=True), 1e-20)
+        top = top * moe.routed_scaling_factor
+
+        init = nn.initializers.normal(stddev=0.02)
+        w1 = self.param("w1", init, (e, d, f)).astype(self.dtype)
+        w3 = self.param("w3", init, (e, d, f)).astype(self.dtype)
+        # The down-projections lie flat, expert after expert: the sparse
+        # layer is a feed-forward of width E x F of which a token uses k x F
+        # rows (the layout of block-sparse expert libraries). The kernel's
+        # DMA addresses expert e's rows e*F .. (e+1)*F - 1 where they lie.
+        w2 = self.param("w2", init, (e * f, d)).astype(self.dtype)
+
+        live = (
+            jnp.ones((n,), bool) if token_mask is None
+            else token_mask.reshape(n)
+        )
+        pair_expert = jnp.where(live[:, None], idx, e).reshape(n * k)
+        tm = tile_rows(n * k)
+        dest, src, tile_expert, n_used, counts = grouped_layout(
+            pair_expert.astype(jnp.int32), e, tm
+        )
+        # Row r of the layout is pair src[r]'s token (none: a row of zeros).
+        x_rows = jnp.concatenate([xf, jnp.zeros((1, d), self.dtype)])[
+            jnp.minimum(src // k, n)
+        ]
+        y_rows = expert_ffn_grouped(
+            x_rows, w1, w3, w2, tile_expert, n_used, tm=tm
+        )
+        pair_live = jnp.repeat(live, k)
+        y_pairs = jnp.where(
+            pair_live[:, None], y_rows[dest].astype(jnp.float32), 0.0
+        ).reshape(n, k, d)
+        y = jnp.einsum("nk,nkd->nd", top.astype(jnp.float32), y_pairs)
+        if moe.num_shared_experts:
+            y = y + _GatedMlp(
+                moe.shared_expert_dim * moe.num_shared_experts, d, False,
+                self.dtype, name="shared",
+            )(xf).astype(jnp.float32)
+        stats = jnp.stack([(counts > 0).sum(), counts.sum()]).astype(jnp.int32)
+        return y.astype(self.dtype).reshape(b, t, d), stats
+
+
+class _GatedMlp(nn.Module):
+    """``(silu(x W1) * (x W3)) W2``: the gated feed-forward of the dense
+    layers and of the shared expert."""
+
+    width: int
+    out: int
+    bias: bool
+    dtype: Any
+
+    @nn.compact
+    def __call__(self, x):
+        return gated_ffn(x, self.width, self.out, self.bias, self.dtype)
+
+
+def gated_ffn(x, width: int, out: int, bias: bool, dtype):
+    """``(silu(x W1) * (x W3)) W2`` as three ``nn.Dense`` of the calling
+    module (``w1``, ``w3``, ``w2``): the gated feed-forward of a dense layer
+    (``GptMlp``) and of the shared expert."""
+    dense = lambda n, name: nn.Dense(  # noqa: E731
+        n, use_bias=bias, dtype=dtype, name=name
+    )
+    h = jax.nn.silu(dense(width, "w1")(x)) * dense(width, "w3")(x)
+    return dense(out, "w2")(h)

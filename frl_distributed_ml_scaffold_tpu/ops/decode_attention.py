@@ -1000,3 +1000,240 @@ def paged_decode_attention(
         q[:, None], k_pool, v_pool, kv_len, block_tables, layer,
         name="attn_paged_decode", **kw,
     )[:, 0]
+
+
+# ------------------------------------------- pools by layer kind (grouped)
+#
+# A model with ``layer_types`` (models/gpt.py) keeps a pool for each layer
+# kind. Its decode step differs from the uniform stack's in three ways: the
+# query heads of a layer are GROUPED over fewer KV heads (a pool row is
+# ``Hkv*D`` lanes, a query tile ``[Hq, D]``); a sliding layer attends the
+# last ``window`` positions only; and a sliding layer's block table is a
+# RING of ``ceil(window / bs) + 1`` places (logical block j at place
+# ``j % places``), so the grid covers the window's blocks whatever the
+# context. Single-token steps only.
+
+
+def _ring_block(jj, last, places):
+    """The logical block that sits at ring place ``jj`` when the newest
+    block is ``last``: the one in ``(last - places, last]`` congruent to
+    ``jj`` (negative: the place is empty)."""
+    return last - lax.rem(last - jj + places, places)
+
+
+def dense_paged_grouped_decode_attention(
+    q, k_pool, v_pool, kv_len, block_tables, row, *, window: int = 0
+):
+    """Reference for ``paged_grouped_decode_attention``: q ``[B, Hq, D]``
+    against row ``row`` of a kind's pools ``[Lk, N, bs, Hkv*D]`` through
+    that kind's tables ``[B, M]``, keys at positions ``kv_len - window <=
+    j < kv_len`` (``window`` 0: every ``j < kv_len``; a windowed table is a
+    ring). Streams one bounded block a table place through an online
+    softmax, like ``dense_paged_verify_attention``."""
+    bs, places = k_pool.shape[2], block_tables.shape[1]
+    b, hq, d = q.shape
+    hkv = k_pool.shape[3] // d
+    g = hq // hkv
+    qg = q.astype(jnp.float32).reshape(b, hkv, g, d)
+    inv = 1.0 / np.sqrt(d)
+    length = kv_len.astype(jnp.int32)
+    last = jnp.maximum(length - 1, 0) // bs
+    first_pos = jnp.maximum(length - window, 0) if window else jnp.zeros_like(length)
+
+    def step(carry, xs):
+        m, l, acc = carry
+        jj, phys = xs
+        j = _ring_block(jj, last, places) if window else jnp.full_like(last, jj)
+        k_c = k_pool[row, phys].reshape(b, bs, hkv, d).astype(jnp.float32)
+        v_c = v_pool[row, phys].reshape(b, bs, hkv, d).astype(jnp.float32)
+        sc = jnp.einsum("bkgd,bckd->bkgc", qg, k_c) * inv  # [B, Hkv, G, bs]
+        kpos = j[:, None] * bs + jnp.arange(bs)[None, :]  # [B, bs]
+        mask = (kpos < length[:, None]) & (kpos >= first_pos[:, None]) & (
+            j[:, None] >= 0)
+        mask = mask[:, None, None, :]
+        sc = jnp.where(mask, sc, _NEG_INF)
+        m_new = jnp.maximum(m, sc.max(axis=-1, keepdims=True))
+        p = jnp.where(mask, jnp.exp(sc - m_new), 0.0)
+        alpha = jnp.exp(m - m_new)
+        l = l * alpha + p.sum(axis=-1, keepdims=True)
+        acc = acc * alpha + jnp.einsum("bkgc,bckd->bkgd", p, v_c)
+        return (m_new, l, acc), None
+
+    carry0 = (
+        jnp.full((b, hkv, g, 1), _NEG_INF, jnp.float32),
+        jnp.zeros((b, hkv, g, 1), jnp.float32),
+        jnp.zeros((b, hkv, g, d), jnp.float32),
+    )
+    cols = block_tables.astype(jnp.int32).T  # [M, B]
+    (m, l, acc), _ = lax.scan(
+        step, carry0, (jnp.arange(places, dtype=jnp.int32), cols))
+    return (acc / jnp.maximum(l, 1e-30)).astype(q.dtype).reshape(b, hq, d)
+
+
+def _paged_grouped_kernel(len_ref, tbl_ref, row_ref, q_ref, k_ref, v_ref,
+                          o_ref, qh_ref, m_ref, l_ref, acc_ref, *, block_k,
+                          heads, kv_heads, head_dim, scale, window):
+    """One (slot row, table place) program. Lane-dense like
+    ``_paged_verify_kernel``: a pool block arrives as stored, ``(bs,
+    Hkv*D)``, and the query heads are spread over the lanes of their KV
+    heads by a 0/1 mask — row i of ``qh`` holds query head i in the lanes of
+    KV head ``i // (Hq / Hkv)`` and zeros elsewhere — so ONE ``[Hq, Hkv*D]
+    x [bs, Hkv*D]^T`` product gives every head's scores, and ``p @ v_blk``
+    gives ``[Hq, Hkv*D]`` of which row i is wanted in its own KV head's
+    lanes. With a window, place ``jj`` holds the ring's logical block
+    ``_ring_block`` and positions behind ``len - window`` are masked."""
+    b_, jj = pl.program_id(0), pl.program_id(1)
+    places = pl.num_programs(1)
+    length = len_ref[b_]
+    last = jnp.maximum(length - 1, 0) // block_k
+    j = _ring_block(jj, last, places) if window else jj
+    first_pos = jnp.maximum(length - window, 0) if window else 0
+    group = heads // kv_heads
+
+    def own(g):  # [Hq, 1]: the query head reads KV head g
+        at = lax.broadcasted_iota(jnp.int32, (heads, 1), 0)
+        return (at >= g * group) & (at < (g + 1) * group)
+
+    @pl.when(jj == 0)
+    def _init():
+        m_ref[:] = jnp.full_like(m_ref, _NEG_INF)
+        l_ref[:] = jnp.zeros_like(l_ref)
+        acc_ref[:] = jnp.zeros_like(acc_ref)
+        q = q_ref[0]  # (Hq, D)
+        qh_ref[:] = jnp.concatenate(
+            [jnp.where(own(g), q, jnp.zeros_like(q)) for g in range(kv_heads)],
+            axis=1,
+        )
+
+    @pl.when((j >= 0) & (j * block_k < length)
+             & ((j + 1) * block_k > first_pos))
+    def _step():
+        k_blk, v_blk = k_ref[0], v_ref[0]  # (Bk, Hkv*D), as stored
+        s = lax.dot_general(
+            qh_ref[:], k_blk,
+            dimension_numbers=(((1,), (1,)), ((), ())),
+            preferred_element_type=jnp.float32,
+        ) * scale  # (Hq, Bk)
+        kpos = j * block_k + lax.broadcasted_iota(jnp.int32, s.shape, 1)
+        s = jnp.where((kpos < length) & (kpos >= first_pos), s, _NEG_INF)
+        m = m_ref[:]
+        m_new = jnp.maximum(m, s.max(axis=-1, keepdims=True))
+        p = jnp.exp(s - m_new)
+        alpha = jnp.exp(m - m_new)
+        m_ref[:] = m_new
+        l_ref[:] = l_ref[:] * alpha + p.sum(axis=-1, keepdims=True)
+        acc_ref[:] = acc_ref[:] * alpha + jnp.dot(
+            p.astype(v_blk.dtype), v_blk, preferred_element_type=jnp.float32)
+
+    @pl.when(jj == places - 1)
+    def _finish():
+        out = acc_ref[:] / jnp.maximum(l_ref[:], 1e-30)  # (Hq, Hkv*D)
+        mine = jnp.zeros((heads, head_dim), jnp.float32)
+        for g in range(kv_heads):
+            mine = mine + jnp.where(
+                own(g), out[:, g * head_dim:(g + 1) * head_dim], 0.0)
+        o_ref[0] = mine.astype(o_ref.dtype)
+
+
+def _flash_paged_grouped(q, k_pool, v_pool, kv_len, tables, row, *, window,
+                         interpret, name):
+    b, hq, d = q.shape
+    bs, f = k_pool.shape[2], k_pool.shape[3]
+    places = tables.shape[1]
+
+    def block_at(b_, jj, len_ref, tbl_ref, row_ref):
+        last = jnp.maximum(len_ref[b_] - 1, 0) // bs
+        if window:
+            # An empty place (or one wholly behind the window) re-references
+            # the newest block: its body is skipped.
+            j = _ring_block(jj, last, places)
+            live = (j >= 0) & ((j + 1) * bs > len_ref[b_] - window)
+            place = jnp.where(live, jj, lax.rem(last, places))
+        else:
+            place = jnp.minimum(jj, last)
+        return (row_ref[0], tbl_ref[b_, place], 0, 0)
+
+    q_spec = pl.BlockSpec((1, hq, d), lambda b_, jj, *_refs: (b_, 0, 0))
+    kv_spec = pl.BlockSpec((None, 1, bs, f), block_at)
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=3,
+        grid=(b, places),
+        in_specs=[q_spec, kv_spec, kv_spec],
+        out_specs=q_spec,
+        scratch_shapes=[
+            pltpu.VMEM((hq, f), q.dtype),  # the query heads spread over lanes
+            pltpu.VMEM((hq, 1), jnp.float32),  # running max
+            pltpu.VMEM((hq, 1), jnp.float32),  # running denom
+            pltpu.VMEM((hq, f), jnp.float32),  # output accumulator
+        ],
+    )
+    return pl.pallas_call(
+        functools.partial(
+            _paged_grouped_kernel, block_k=bs, heads=hq, kv_heads=f // d,
+            head_dim=d, scale=1.0 / np.sqrt(d), window=window,
+        ),
+        grid_spec=grid_spec,
+        out_shape=jax.ShapeDtypeStruct((b, hq, d), q.dtype),
+        interpret=interpret,
+        name=name,
+    )(kv_len, tables, row, q, k_pool, v_pool)
+
+
+def paged_grouped_decode_attention(
+    q: jax.Array,
+    k_pool: jax.Array,
+    v_pool: jax.Array,
+    kv_len: jax.Array,
+    block_tables: jax.Array,
+    row: int,
+    *,
+    window: int = 0,
+    impl: str = "flash",
+    interpret: bool | None = None,
+    name: str = "attn_mixed_decode",
+) -> jax.Array:
+    """Single-token decode attention over the pools of ONE LAYER KIND: q
+    ``[B, Hq, D]`` (the step's K/V already written at position ``kv_len -
+    1``) against row ``row`` of the kind's lane-dense pools ``[Lk, N, bs,
+    Hkv*D]`` through the kind's block tables ``[B, M]``; query head i reads
+    KV head ``i // (Hq / Hkv)``. ``window`` 0: a full layer, every position
+    ``< kv_len``, table place j holds logical block j. ``window`` W: a
+    sliding layer, positions ``kv_len - W <= j < kv_len``, the table a ring
+    of ``M = ceil(W / bs) + 1`` places — the kernel's grid is those places,
+    so a step costs a window's blocks whatever the context. Same impl
+    routing and fallback contract as ``paged_verify_attention`` (one chip:
+    a mesh with a live ``model`` axis is refused by the engine for such a
+    model)."""
+    def dense():
+        return dense_paged_grouped_decode_attention(
+            q, k_pool, v_pool, kv_len, block_tables, row, window=window)
+
+    if impl == "dense":
+        return dense()
+    if impl != "flash":
+        raise KeyError(
+            f"unknown decode_attention impl {impl!r} (dense | flash)"
+        )
+    if interpret is None:
+        interpret = FORCE_INTERPRET
+    bs, f, d = k_pool.shape[2], k_pool.shape[3], q.shape[-1]
+    # The lane slices that pick a KV head's output are whole lane tiles
+    # only when a head is one or more tiles wide.
+    tileable = bs >= 8 and (bs & (bs - 1)) == 0 and d % 128 == 0
+    if not tileable and not interpret:
+        if jax.default_backend() == "tpu":
+            _warn_fallback(
+                "grouped paged decode falling back to dense: geometry "
+                f"(bs={bs}, head_dim={d}) is not tileable (need a "
+                "power-of-two block size >= 8 and head_dim % 128 == 0)"
+            )
+        return dense()
+    if interpret is None:
+        if jax.default_backend() != "tpu":
+            return dense()
+        interpret = False
+    return _flash_paged_grouped(
+        q, k_pool, v_pool, jnp.maximum(kv_len.astype(jnp.int32), 1),
+        block_tables.astype(jnp.int32), jnp.asarray([row], jnp.int32),
+        window=window, interpret=interpret, name=name,
+    )

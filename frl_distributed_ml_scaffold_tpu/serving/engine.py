@@ -81,9 +81,15 @@ from frl_distributed_ml_scaffold_tpu.models.generation import (
     pool_block_bytes,
     pool_to_slot_blocks,
     rewind_cache_indices,
+    splice_kind_pools,
     splice_pool_blocks,
 )
-from frl_distributed_ml_scaffold_tpu.models.gpt import init_paged_cache
+from frl_distributed_ml_scaffold_tpu.models.gpt import (
+    init_paged_cache,
+    kind_layers,
+    window_pool_blocks,
+    window_table_blocks,
+)
 from frl_distributed_ml_scaffold_tpu.telemetry import (
     Histogram,
     MetricsRegistry,
@@ -406,7 +412,8 @@ class ServingEngine:
         # without a config. Passing both is a caller bug, refused.
         if serving is not None:
             if (max_queue_depth or default_deadline_s or kv_block_size
-                    or kv_pool_blocks or prefix_cache is not None
+                    or kv_pool_blocks
+                    or prefix_cache is not None
                     or speculate is not None or speculate_k):
                 raise ValueError(
                     "pass either serving=ServingConfig(...) or the "
@@ -425,6 +432,22 @@ class ServingEngine:
             raise ValueError(f"max_queue_depth={max_queue_depth} < 0")
         self.max_queue_depth = int(max_queue_depth)
         self.default_deadline_s = float(default_deadline_s)
+        # A model with ``layer_types`` (full and sliding-window layers
+        # mixed) keeps a pool for each layer kind. What the engine cannot
+        # do over two kinds yet is refused HERE, by name: nothing falls
+        # back to keeping every position in a sliding layer.
+        self.mixed = bool(getattr(model.config, "layer_types", ()))
+        if self.mixed:
+            refused = {
+                "the bucketed cache (kv_block_size=0)": kv_block_size <= 0,
+                "the prefix cache (prefix_cache=True)": bool(prefix_cache),
+                "speculation (speculate)": speculate not in (None, "off"),
+                "the quantised pools (model.kv_cache_quant)":
+                    model.config.kv_cache_quant != "none",
+            }
+            self._refuse_for_layer_kinds(
+                *[what for what, asked in refused.items() if asked])
+            prefix_cache = False
         # Paged-cache knobs (ISSUE 10). Block sizes are powers of two so
         # every prompt bucket is a whole number of blocks (the graft's
         # reshape-to-blocks relies on it) and the paged kernel's chunk is
@@ -479,6 +502,23 @@ class ServingEngine:
             self._prefix_cache: collections.OrderedDict[
                 bytes, tuple[int, ...]
             ] = collections.OrderedDict()
+            # The SLIDING kind's half of the allocator: a free list of its
+            # own pool, and per slot a ring table of ``window_places``
+            # places and the blocks it holds by logical index. The pool
+            # holds a ring for every slot (``window_pool_blocks``), so a
+            # slot that is free finds its window's worth free with it: the
+            # sliding kind never bounds admission and keeps no count of
+            # blocks reserved.
+            self.window = (
+                model.config.sliding_window
+                if self.mixed and "sliding" in kind_layers(model.config)
+                else 0
+            )
+            if self.window:
+                self.window_places = window_table_blocks(model.config, bs)
+                self.window_pool_blocks = window_pool_blocks(
+                    model.config, bs, self.num_slots)
+                self._reset_window_pool()
 
         # Speculative decoding (ISSUE 11): draft-propose k tokens per
         # slot, verify all k+1 positions in ONE batched forward, accept
@@ -556,6 +596,8 @@ class ServingEngine:
         from frl_distributed_ml_scaffold_tpu.dist.mesh import current_mesh_env
 
         self._env = current_mesh_env()
+        if self._env is not None and self._env.axis_size("model") > 1:
+            self._refuse_for_layer_kinds("a mesh with a live model axis")
 
         self._queue: collections.deque[ServeRequest] = collections.deque()
         # Typed completions produced OUTSIDE a slot (shed at submit,
@@ -712,6 +754,23 @@ class ServingEngine:
             help="mid-decode KV blocks appended to slot tables "
             "(the paged engine's 'grow': one block, never a cache clone)",
         )
+        # Two kinds of layer in the pool: blocks in use of each kind
+        # (beside serve_pool_utilization, which is the full kind's share)
+        # and the sliding blocks given back while their requests lived.
+        self._m_blocks_full = t.gauge(
+            "serve_pool_blocks_in_use_full",
+            help="allocated blocks of the full-attention layers' pool",
+        )
+        self._m_blocks_window = t.gauge(
+            "serve_pool_blocks_in_use_sliding",
+            help="allocated blocks of the sliding-window layers' pool "
+            "(0 on a model without such layers)",
+        )
+        self._m_window_released = t.counter(
+            "serve_window_blocks_released_total",
+            help="sliding-layer blocks given back because the window of a "
+            "live request slid off them",
+        )
         self._m_prefix_hits = t.counter(
             "serve_prefix_hits_total",
             help="admissions that reused cached prefix blocks",
@@ -773,6 +832,30 @@ class ServingEngine:
             dump_path=stall_dump_path,
             first_beat_scale=stall_first_beat_scale,
         )
+
+    def _refuse_for_layer_kinds(self, *what: str) -> None:
+        """What the engine cannot do over pools of two layer kinds."""
+        if self.mixed and what:
+            raise NotImplementedError(
+                "not on a model with sliding-window layers (layer_types) "
+                "yet: " + "; ".join(what) + " — its pools are kept by layer "
+                "kind (a sliding layer holds its last window only) and this "
+                "path knows one kind"
+            )
+
+    def _reset_window_pool(self) -> None:
+        self._wfree: list[int] = list(
+            range(self.window_pool_blocks - 1, 0, -1))
+        self._wslot_blocks: list[dict[int, int]] = [
+            {} for _ in range(self.num_slots)]
+        self._wtables = np.zeros(
+            (self.num_slots, self.window_places), np.int32)
+
+    def _window_first_block(self, write_pos: int) -> int:
+        """The oldest logical block a step that writes ``write_pos`` still
+        reads in a sliding layer: it attends positions ``> write_pos -
+        window``."""
+        return max(write_pos - self.window + 1, 0) // self.block_size
 
     def _phase(self, name, *, t0, dur_s, trace=None, parent=None, **attrs):
         """Span plus guaranteed phase record: the engine-built tracer tees
@@ -984,6 +1067,8 @@ class ServingEngine:
             self._tables[:] = 0
             self._tables_dirty = True
             self._prefix_cache.clear()
+            if self.window:
+                self._reset_window_pool()
         self._slot_spec_degraded[:] = False
         self._slot_spec_proposed[:] = 0
         self._slot_spec_accepted[:] = 0
@@ -1180,7 +1265,13 @@ class ServingEngine:
 
             def serve_paged_decode(params, cache, tok, rng):
                 logits, cache = _decode_step(m, params, cache, tok)
-                return _sample(logits, rng, **kw), cache
+                nxt = _sample(logits, rng, **kw)
+                if self.mixed:
+                    # The expert layers' counts (experts touched, pairs)
+                    # come back behind the tokens, in the same array: one
+                    # fetch a step, not two.
+                    nxt = jnp.concatenate([nxt, cache["moe_stats"]])
+                return nxt, cache
 
             # Donate the cache (pool included) — the same two-caches-live
             # audit fix as _decode_fn, now sized at the POOL.
@@ -1263,8 +1354,14 @@ class ServingEngine:
         rebinds it; appends and growth never clone it."""
         if (s_c, n_priv) not in self._paged_graft_jit:
             bs = self.block_size
+            cfg = self.model.config
 
             def serve_paged_graft(cache, slot_cache, blk_ids, m0, slot):
+                if self.mixed:  # m0: the sliding kind's block ids
+                    return splice_kind_pools(
+                        cache, slot_cache, blk_ids, m0, slot, cfg=cfg,
+                        block_size=bs,
+                    )
                 return splice_pool_blocks(
                     cache, slot_cache, blk_ids, m0, slot, block_size=bs
                 )
@@ -1676,6 +1773,18 @@ class ServingEngine:
                 and self._evict_one()
             ):
                 pass
+        window = {}
+        if self.window:
+            # The sliding kind: a window's worth, whatever the length —
+            # the blocks that hold positions (l - W, l] now; the most the
+            # slot will ever hold at once goes on the `admit` span.
+            window = {
+                "wblocks": {
+                    j: self._wfree.pop()
+                    for j in range(self._window_first_block(l), n_now)
+                },
+                "wbudget": min(self.window_places, n_total),
+            }
         priv = [self._free.pop() for _ in range(n_now - m)]
         for bid in priv:
             self._ref[bid] += 1
@@ -1685,6 +1794,7 @@ class ServingEngine:
             "shared": list(shared),
             "priv": priv,
             "future": n_total - n_now,
+            **window,
         }
 
     def _pool_release(self, res: dict) -> None:
@@ -1694,6 +1804,8 @@ class ServingEngine:
         for bid in res["priv"] + res["shared"]:
             self._deref(bid)
         self._reserved_future -= res["future"]
+        if "wblocks" in res:
+            self._wfree.extend(res["wblocks"].values())
 
     def _note_pool_peak(self) -> None:
         """High-watermark of pool DEMAND — blocks held by slots (and by
@@ -1874,23 +1986,37 @@ class ServingEngine:
         l = int(req.prompt.size)
         if self.paged:
             n_g = blocks_for_tokens(l, self.block_size)
-            # ``m0`` is the private blocks' logical offset WITHIN the
-            # slot cache: ``m`` for a full bucketed cache, 0 when the
-            # scheduler pre-sliced the cross-partition transfer down to
-            # the private window.
+            if self.mixed:
+                # The sliding kind takes the prompt's last window only:
+                # logical blocks [n_g - n_w, n_g), those already behind
+                # the window to the trash block.
+                held = res.get("wblocks", {})
+                n_w = min(self.window_places, n_g) if self.window else 0
+                fourth = jnp.asarray(
+                    [held.get(j, 0) for j in range(n_g - n_w, n_g)], jnp.int32)
+            else:
+                # ``m0`` is the private blocks' logical offset WITHIN the
+                # slot cache: ``m`` for a full bucketed cache, 0 when the
+                # scheduler pre-sliced the cross-partition transfer down
+                # to the private window.
+                fourth = jnp.int32(m if m0 is None else m0)
             self.cache = self._call(
                 "paged_graft", (s_c, n_g - m),
                 self._paged_graft_fn(s_c, n_g - m),
                 self.cache,
                 slot_cache,
                 jnp.asarray(res["priv"][: n_g - m], jnp.int32),
-                jnp.int32(m if m0 is None else m0),
+                fourth,
                 jnp.int32(slot),
             )
             # The re-own: ownership moves as one table-row write.
             blocks = res["shared"] + res["priv"]
             self._tables[slot, :] = 0
             self._tables[slot, : len(blocks)] = blocks
+            if self.window:
+                self._wtables[slot, :] = 0
+                for j, bid in res["wblocks"].items():
+                    self._wtables[slot, j % self.window_places] = bid
             self._tables_dirty = True
         else:
             if self.cache is None:
@@ -2011,6 +2137,11 @@ class ServingEngine:
         if self.paged:
             self._slot_blocks[slot] = res["shared"] + res["priv"]
             self._slot_future[slot] = res["future"]
+            if self.window:
+                self._wslot_blocks[slot] = dict(res["wblocks"])
+                self.stats["reserved_blocks_sliding"] += res["wbudget"]
+            self.stats["reserved_blocks_full"] += (
+                len(res["priv"]) + res["future"])
             self._note_pool_peak()
             self._slot_prefix_hit[slot] = m > 0
             self._slot_tokens_saved[slot] = m * bs
@@ -2059,6 +2190,7 @@ class ServingEngine:
         retried on a healthy worker), and the engine state is untouched
         because the pool is only rebound to a successful program's
         output and the table/slot bookkeeping runs after it."""
+        self._refuse_for_layer_kinds("the disaggregated engine (admit_handoff)")
         assert self.paged, "handoff admission is a paged-engine contract"
         assert not self._active[slot], f"slot {slot} is occupied"
         l = int(req.prompt.size)
@@ -2103,6 +2235,7 @@ class ServingEngine:
         parked request, and the worst-case reservation stays accounted so
         the resumed request's appends still can never fail). Returns the
         opaque parked state ``resume_parked`` restores."""
+        self._refuse_for_layer_kinds("park / resume (park_slot)")
         assert self.paged, "parking is a paged-engine contract"
         assert self._active[slot], f"slot {slot} has nothing to park"
         parked = {
@@ -2150,6 +2283,7 @@ class ServingEngine:
         engine invariant, so the move only touches the resumed row). The
         request then continues decoding from its parked ``last_tok``,
         token-identically — nothing about its K/V ever moved."""
+        self._refuse_for_layer_kinds("park / resume (resume_parked)")
         assert self.paged and not self._active[slot]
         req = parked["req"]
         self._req[slot] = req
@@ -2257,6 +2391,7 @@ class ServingEngine:
         two trees): the engine takes ownership of the param buffers it
         was constructed with, so callers sharing that exact tree with
         another consumer must re-place their copy first."""
+        self._refuse_for_layer_kinds("the live re-spread (respread_pool)")
         if not self.paged:
             raise ValueError(
                 "respread_pool is a paged-engine contract "
@@ -2437,8 +2572,12 @@ class ServingEngine:
             self._slot_blocks[slot] = []
             self._slot_future[slot] = 0
             self._tables[slot, :] = 0
+            if self.window:
+                self._wfree.extend(self._wslot_blocks[slot].values())
+                self._wslot_blocks[slot] = {}
+                self._wtables[slot, :] = 0
             self._tables_dirty = True
-            self._m_pool_util.set(self.pool_utilization())
+            self._set_pool_gauges()
         self.stats["completed"] += 1
         self.stats[f"finish_{reason}"] += 1
         self._m_completed.inc()
@@ -2481,9 +2620,15 @@ class ServingEngine:
         depth = len(self._queue)
         self._m_queue.set(depth)
         with self._span("admit", queue=depth) as span:
-            before = self.stats["admitted"]
+            before = {k: self.stats[k] for k in (
+                "admitted", "reserved_blocks_full", "reserved_blocks_sliding")}
             self._admit()
-            span.set(admitted=self.stats["admitted"] - before)
+            span.set(
+                admitted=self.stats["admitted"] - before["admitted"],
+                **({f"reserved_{kind}": self.stats[f"reserved_blocks_{kind}"]
+                    - before[f"reserved_blocks_{kind}"]
+                    for kind in ("full", "sliding")} if self.paged else {}),
+            )
         # Typed completions resolved since the last step (shed at
         # submit) and during this admission round (expired/quarantined).
         self._completed.extend(self._early)
@@ -2547,7 +2692,10 @@ class ServingEngine:
         t0 = time.perf_counter()
         # One engine-lane span per slot-array decode program, from before
         # its dispatch until its tokens are on the host...
-        with self._span("decode", bucket=self.bucket, active=n_active):
+        with self._span(
+            "decode", bucket=self.bucket, active=n_active,
+            **self._kind_attrs(),
+        ) as decode_span:
             # `dispatch` returns when the program is enqueued; `fetch` is
             # where the host waits for the device.
             with self._span("dispatch"), self._trace_ctx():
@@ -2569,6 +2717,10 @@ class ServingEngine:
                 )
             with self._span("fetch"):
                 nxt = np.asarray(jax.device_get(nxt))
+            if self.mixed:
+                nxt, (touched, pairs) = nxt[:-2], nxt[-2:]
+                decode_span.set(
+                    experts_touched=int(touched), expert_pairs=int(pairs))
         dt = time.perf_counter() - t0
         with self._span("emit_tokens"):
             self._emit_decoded(nxt, n_active, t0, dt)
@@ -2629,14 +2781,74 @@ class ServingEngine:
                     "block_append", t0=time.perf_counter(), dur_s=0.0,
                     trace=self._engine_trace, slot=int(slot), block=bid,
                 )
-        self._m_pool_util.set(self.pool_utilization())
+        if self.window:
+            appended += self._slide_windows()
+        self._set_pool_gauges()
         if self._tables_dirty and self._active.any():
             self.cache = {
                 **self.cache,
                 "block_tables": jnp.asarray(self._tables),
+                **({"block_tables_sliding": jnp.asarray(self._wtables)}
+                   if self.window else {}),
             }
             self._tables_dirty = False
         return appended
+
+    def _slide_windows(self) -> int:
+        """The sliding kind's turn of ``append_blocks``: before the step
+        that writes position p, a block the window (p - W, p] has wholly
+        left goes back to the free list AT ONCE and its place in the ring
+        table is free for the block that many further on; the block that
+        holds p comes off the free list, which the pool's size keeps from
+        running dry. So a slot never holds more than ``ceil(W / block) +
+        1`` sliding blocks, whatever its context. Returns the blocks
+        appended."""
+        appended = 0
+        for slot in np.flatnonzero(self._active):
+            p = int(self._len[slot]) - 1  # this step's write position
+            held = self._wslot_blocks[slot]
+            first = self._window_first_block(p)
+            for j in [j for j in held if j < first]:
+                self._wfree.append(held.pop(j))
+                self._wtables[slot, j % self.window_places] = 0
+                self._tables_dirty = True
+                self.stats["window_blocks_released"] += 1
+                self._m_window_released.inc()
+            j = p // self.block_size
+            if j not in held:
+                held[j] = self._wfree.pop()
+                self._wtables[slot, j % self.window_places] = held[j]
+                self._tables_dirty = True
+                appended += 1
+                self.stats["block_append_sliding"] += 1
+        return appended
+
+    def _set_pool_gauges(self) -> None:
+        self._m_pool_util.set(self.pool_utilization())
+        self._m_blocks_full.set(self.pool_blocks - 1 - len(self._free))
+        if self.window:
+            self._m_blocks_window.set(self._window_blocks_in_use())
+
+    def _window_blocks_in_use(self) -> int:
+        return self.window_pool_blocks - 1 - len(self._wfree)
+
+    def _kind_attrs(self) -> dict:
+        """What a `decode` span says of the two layer kinds: the positions
+        ONE full layer and ONE sliding layer attend over in this step,
+        summed over the live rows (a row that writes position p attends
+        p + 1 positions, a window's worth at most in a sliding layer), and
+        the sliding pool's fill."""
+        if not self.mixed:
+            return {}
+        attended = self._len[self._active]  # p + 1 with p = len - 1
+        out = {"kv_tokens_full": int(attended.sum())}
+        if self.window:
+            out.update(
+                kv_tokens_window=int(np.minimum(attended, self.window).sum()),
+                window_blocks_in_use=self._window_blocks_in_use(),
+                window_pool_blocks=self.window_pool_blocks - 1,
+            )
+        return out
 
     def _emit_decoded(self, nxt, n_active: int, t0: float, dt: float) -> None:
         """The host half of a decode step (``step``'s `emit_tokens`

@@ -356,6 +356,13 @@ class DisaggServingEngine:
         stall_dump_path: str | None = None,
         stall_first_beat_scale: float = 5.0,
     ):
+        if getattr(model.config, "layer_types", ()):
+            raise NotImplementedError(
+                "not on a model with sliding-window layers (layer_types) "
+                "yet: the disaggregated engine — its handoff splices one "
+                "kind of pool, and such a model keeps a pool for each "
+                "layer kind"
+            )
         if serving is not None:
             if (max_queue_depth or default_deadline_s or kv_block_size
                     or kv_pool_blocks or prefix_cache is not None

@@ -231,9 +231,11 @@ _UPDATE_ROOT = re.compile(
 )
 
 
-def _pool_sized_ops(text: str) -> list[str]:
+def _pool_sized_ops(
+    text: str, pool_slice: int = POOL_SLICE, pool_blocks: int = POOL_BLOCKS
+) -> list[str]:
     """Instructions of the optimised HLO, outside fused computations, whose
-    output holds an array of POOL_SLICE elements or more and that are
+    output holds an array of ``pool_slice`` elements or more and that are
     neither free nor the in-place update itself (a scatter /
     dynamic-update-slice, or the fusion whose root is one)."""
     bodies = dict(re.findall(
@@ -255,7 +257,7 @@ def _pool_sized_ops(text: str) -> list[str]:
                 int(np.prod([int(d) for d in dims.split(",") if d] or [1]))
                 for dims in re.findall(r"\w+\[([\d,]*)\]", out)
             ]
-            if max(sizes, default=0) < POOL_SLICE or op in _MOVES_NOTHING:
+            if max(sizes, default=0) < pool_slice or op in _MOVES_NOTHING:
                 continue
             if op in _UPDATES:
                 continue
@@ -265,7 +267,7 @@ def _pool_sized_ops(text: str) -> list[str]:
             ):
                 continue
             if op in ("copy-start", "copy-done") and (
-                f",{POOL_BLOCKS}," not in out
+                f",{pool_blocks}," not in out
             ):
                 continue  # the prefetch of a weight: no array of pool blocks
             found.append(f"{op} -> {out[:60]}")
@@ -383,6 +385,154 @@ def test_pool_program_leaves_the_pool_in_place(pool_programs, program, quant):
         assert re.search(
             rf"%{kernel}[\w.]* = .*custom-call\(.*tpu_custom_call", text
         ), f"{kernel} is not in {program}"
+
+
+# Laguna-XS.2 (huggingface.co/poolside/Laguna-XS.2 config.json) at its
+# published widths, cut in depth alone to layers 0-4 (the dense full layer,
+# then one whole period: sliding, sliding, sliding, full): 3.87 B parameters,
+# 7.74 GB in bf16. What PERF.md section 4 says of it was measured at these
+# sizes.
+LAGUNA_XS2_LAYERS_0_4 = {
+    "family": "gpt", "vocab_size": 100352, "num_layers": 5, "hidden_dim": 2048,
+    "seq_len": 5120, "num_heads": 48, "num_heads_sliding": 64,
+    "num_kv_heads": 8, "head_dim": 128,
+    "layer_types": ["full_attention", "sliding_attention", "sliding_attention",
+                    "sliding_attention", "full_attention"],
+    "sliding_window": 512, "norm": "rmsnorm", "layer_norm_epsilon": 1e-06,
+    "position": "rope",
+    "rope": {"rope_type": "yarn", "rope_theta": 500000.0,
+             "partial_rotary_factor": 0.5, "factor": 64.0,
+             "original_max_position_embeddings": 4096, "beta_fast": 64.0,
+             "beta_slow": 1.0, "attention_factor": 1.4158883083359672},
+    "rope_sliding": {"rope_type": "default", "rope_theta": 10000.0,
+                     "partial_rotary_factor": 1.0},
+    "bias": False, "attention_gate": True, "mlp": "swiglu", "mlp_dim": 8192,
+    "dense_layers": [0], "tie_embeddings": False,
+    "moe": {"num_experts": 256, "top_k": 8, "routing": "dropless",
+            "expert_dim": 512, "num_shared_experts": 1,
+            "shared_expert_dim": 512, "score_func": "sigmoid",
+            "norm_topk_prob": True, "routed_scaling_factor": 2.5},
+    "dropout": 0.0, "decode_attention": "flash",
+}
+# 64 slots over blocks of 128: 576 usable blocks of the full kind (1 MiB
+# each), and a ring of 5 for each slot in the sliding kind.
+LAGUNA_XS2_ENGINE = {"num_slots": 64, "kv_block_size": 128,
+                     "kv_pool_blocks": 577, "prefix_cache": False}
+
+
+@pytest.fixture(scope="module")
+def kind_pool_programs(one_chip):
+    """{program: (compiled, engine)}: the paged decode program and the
+    admission graft of a model with two kinds of layer in the pool and
+    expert layers, at published widths (``LAGUNA_XS2_LAYERS_0_4``: window
+    and full attention mixed, 48 / 64 query heads over 8 KV heads of 128,
+    256 routed experts), from the engine's own builders over abstract
+    weights."""
+    from frl_distributed_ml_scaffold_tpu.config import (
+        ExperimentConfig,
+        config_from_dict,
+    )
+    from frl_distributed_ml_scaffold_tpu.models import create_model
+    from frl_distributed_ml_scaffold_tpu.models.gpt import init_paged_cache
+    from frl_distributed_ml_scaffold_tpu.precision import get_policy
+    from frl_distributed_ml_scaffold_tpu.serving import ServingEngine
+
+    ge = importlib.import_module(
+        "frl_distributed_ml_scaffold_tpu.ops.grouped_experts"
+    )
+    policy = get_policy("bf16")
+    model = create_model(
+        config_from_dict(
+            ExperimentConfig, {"model": LAGUNA_XS2_LAYERS_0_4}
+        ).model,
+        policy,
+    )
+    params = jax.tree.map(
+        lambda s: jax.ShapeDtypeStruct(s.shape, policy.param_dtype),
+        jax.eval_shape(
+            lambda: model.init(
+                {"params": jax.random.key(0)}, jnp.zeros((1, 8), I32),
+                train=False,
+            )["params"]
+        ),
+    )
+    eng = ServingEngine(model, params, **LAGUNA_XS2_ENGINE)
+    cache = jax.eval_shape(
+        lambda: init_paged_cache(eng._paged_model(), eng.num_slots)
+    )
+    # A 1000-token prompt: a slot cache of 1024 positions, eight blocks of
+    # 128, of which the sliding layers' pool takes the last five.
+    s_c, n_g = 1024, 8
+    slot_model = eng._model_at(s_c)
+    slot_cache = jax.eval_shape(
+        lambda p, t: slot_model.apply(
+            {"params": p}, t, decode=True, mutable=["cache"]
+        )[1]["cache"],
+        params, jax.ShapeDtypeStruct((1, 8), I32),
+    )
+    i32 = lambda *shape: jax.ShapeDtypeStruct(shape, I32)  # noqa: E731
+    programs = {
+        "serve_paged_decode": (
+            eng._paged_decode_fn(),
+            (params, cache, i32(eng.num_slots),
+             jax.eval_shape(lambda: jax.random.key(0))),
+        ),
+        "serve_paged_graft": (
+            eng._paged_graft_fn(s_c, n_g),
+            (cache, slot_cache, i32(n_g), i32(eng.window_places), i32()),
+        ),
+    }
+    on_chip = lambda tree: jax.tree.map(  # noqa: E731
+        lambda s: jax.ShapeDtypeStruct(s.shape, s.dtype, sharding=one_chip),
+        tree,
+    )
+    was = da.FORCE_INTERPRET, ge.FORCE_INTERPRET
+    da.FORCE_INTERPRET = ge.FORCE_INTERPRET = False
+    try:
+        yield {
+            name: (fn.lower(*on_chip(args)).compile(), eng)
+            for name, (fn, args) in programs.items()
+        }
+    finally:
+        da.FORCE_INTERPRET, ge.FORCE_INTERPRET = was
+
+
+@pytest.mark.parametrize("program", ["serve_paged_decode", "serve_paged_graft"])
+def test_pool_program_leaves_the_pools_of_both_kinds_in_place(
+    kind_pool_programs, program
+):
+    """``test_pool_program_leaves_the_pool_in_place`` for a model that keeps
+    a pool for each layer kind: the layer loop (layers apart, not a scan)
+    hands both pools on whole, so no op writes a layer's slice of either
+    pool other than the in-place update, both arrive row-major, the
+    program's temporaries stay small, and the decode program holds the
+    grouped attention kernel of each kind and the expert kernel under their
+    names."""
+    compiled, eng = kind_pool_programs[program]
+    text = compiled.as_text()
+    row = 8 * 128  # 8 KV heads of 128: a token's K row
+    for blocks in (eng.pool_blocks, eng.window_pool_blocks):
+        assert _pool_sized_ops(
+            text, blocks * eng.block_size * row, blocks) == []
+    for layers, blocks in ((2, eng.pool_blocks), (3, eng.window_pool_blocks)):
+        shape = f"bf16[{layers},{blocks},{eng.block_size},{row}]"
+        layouts = re.findall(
+            re.escape(shape) + r"\{([\d,]+)", text.split("\n", 1)[0])
+        assert layouts and set(layouts) == {"3,2,1,0"}, (shape, layouts)
+    mem = compiled.memory_analysis()
+    assert mem.temp_size_in_bytes < 64e6
+    assert mem.alias_size_in_bytes >= sum(
+        2 * layers * blocks * eng.block_size * row * 2
+        for layers, blocks in (
+            (2, eng.pool_blocks), (3, eng.window_pool_blocks))
+    )
+    if program == "serve_paged_decode":
+        calls = set(re.findall(
+            r"%([a-z_]+)[\w.]* = .*custom-call\(.*tpu_custom_call", text))
+        assert calls == {
+            "attn_mixed_decode_full", "attn_mixed_decode_sliding",
+            "moe_expert_ffn",
+        }, calls
 
 
 @pytest.mark.parametrize(

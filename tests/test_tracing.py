@@ -318,9 +318,18 @@ def test_step_span_is_covered_by_its_children(gpt, eng_kw):
     slack, they do not overlap, and `dispatch` and `fetch` tile the
     `decode` / `verify` between them. (`program_build`, the one child of
     `step` that lies INSIDE its siblings, is left out of the tiling.)"""
+    import gc
+
     model, params = gpt
     work = _workload(n=6, seed=21)
     eng, _ = _serve(model, params, work, **eng_kw)
+    # A phase the spans leave out shows in EVERY step; a pause of the host
+    # shows in one. The collector is held off for the timed run (late in a
+    # long worker process one collection outlasts the slack), and one step
+    # of the run may still be over it (six workers share the CI host).
+    gc.collect()
+    gc.disable()
+    over = []
     try:
         eng.tracing.drain()
         for prompt, n_new in work:
@@ -352,7 +361,8 @@ def test_step_span_is_covered_by_its_children(gpt, eng_kw):
                 assert _end(a) <= b["t0_s"] + 1e-9, (a, b)
             assert _end(kids[-1]) <= _end(step) + 1e-9
             covered = sum(k["dur_s"] for k in kids)
-            assert step["dur_s"] - covered < STEP_SLACK_S, (step, names)
+            if step["dur_s"] - covered >= STEP_SLACK_S:
+                over.append((step, names))
             # dispatch + fetch tile the program's span.
             parts = _children(spans, program[0])
             assert [p["name"] for p in parts] == ["dispatch", "fetch"]
@@ -361,7 +371,9 @@ def test_step_span_is_covered_by_its_children(gpt, eng_kw):
             assert _end(parts[1]) <= _end(program[0]) + 1e-9
             assert program[0]["dur_s"] - parts[0]["dur_s"] - parts[1]["dur_s"] < 1e-3
         assert ran >= 3
+        assert len(over) <= 1, over
     finally:
+        gc.enable()
         eng.close()
 
 
