@@ -534,6 +534,9 @@ def phase_kernels(fields: dict, *, batch: int = 8, heads: int = 16,
     # a block's int8 scales one row [L, N, H*bs] — and read at layer 1 of
     # 2 (layer 0 holds zeros).
     layer = 1
+    # Row 1 is DEAD (a slot with no request): length 0, zeros out.
+    lens_p = kv_len.at[1].set(0)
+    live = (lens_p > 0)[:, None, None]
     for bs in block_sizes:
         m = seq // bs
         perm = jax.random.permutation(next(keys), batch * m) + 1  # 0=trash
@@ -551,13 +554,13 @@ def phase_kernels(fields: dict, *, batch: int = 8, heads: int = 16,
             f"paged_decode_bs{bs}",
             lambda q, k, v, l, t: da.paged_decode_attention(
                 q, k, v, l, t, layer, impl="flash", interpret=interpret),
-            qd, kp, vp, kv_len, tables,
+            qd, kp, vp, lens_p, tables,
         )
         check(f"paged_decode_bs{bs}", out, da.dense_paged_decode_attention(
-            qd, kp, vp, kv_len, tables, layer), 2e-2)
+            qd, kp, vp, lens_p, tables, layer), 2e-2)
         # ...which is also what the contiguous reference gives.
-        check(f"paged_vs_contiguous_bs{bs}", out,
-              da.dense_decode_attention(qd, k, v, kv_len), 2e-2)
+        check(f"paged_vs_contiguous_bs{bs}", out, jnp.where(
+            live, da.dense_decode_attention(qd, k, v, kv_len), 0), 2e-2)
         kp8, vp8 = pool("key_pool", k8), pool("value_pool", v8)
         ksp, vsp = pool("key_pool_scale", ks), pool("value_pool_scale", vs)
         out = jit_checked(
@@ -565,15 +568,15 @@ def phase_kernels(fields: dict, *, batch: int = 8, heads: int = 16,
             lambda q, k, v, l, t, a, b: da.paged_decode_attention(
                 q, k, v, l, t, layer, k_scale=a, v_scale=b, impl="flash",
                 interpret=interpret),
-            qd, kp8, vp8, kv_len, tables, ksp, vsp,
+            qd, kp8, vp8, lens_p, tables, ksp, vsp,
         )
         check(f"paged_decode_int8_bs{bs}", out,
               da.dense_paged_decode_attention(
-                  qd, kp8, vp8, kv_len, tables, layer, ksp, vsp), 2e-2)
+                  qd, kp8, vp8, lens_p, tables, layer, ksp, vsp), 2e-2)
         qv = jax.random.normal(
             next(keys), (batch, verify_len, heads, head_dim), bf16
         )
-        lens_v = jnp.maximum(kv_len, verify_len)
+        lens_v = jnp.where(lens_p > 0, jnp.maximum(kv_len, verify_len), 0)
         out = jit_checked(
             f"paged_verify_bs{bs}",
             lambda q, k, v, l, t: da.paged_verify_attention(

@@ -876,8 +876,16 @@ class CausalSelfAttention(nn.Module):
                     paged_verify_attention,
                 )
 
+                # A row whose table starts at the trash block is DEAD (the
+                # engine hands block 0 to no request, and a retired or
+                # never-used slot's row is all zeros): nothing resets its
+                # cursor, so the kernel is told length 0 — no block read,
+                # zeros out — where the cursor alone would have it walk
+                # the trash block for as long as the row once was.
+                live = block_tables[:, 0] != 0
                 y = paged_verify_attention(
-                    q, ck.value, cv.value, idx + t, block_tables, layer,
+                    q, ck.value, cv.value, jnp.where(live, idx + t, 0),
+                    block_tables, layer,
                     impl=cfg.decode_attention,
                     name="attn_paged_decode" if t == 1
                     else "attn_paged_verify",

@@ -55,11 +55,14 @@ shared-prefix reuse). The pool is ONE leaf for all layers and LANE-DENSE,
 ``[L, N, bs, H*D]``: a token's K row is H*D contiguous values, so the
 device keeps the leaf row-major and the kernel reads it where it lies
 (models/gpt.py ``paged_cache_leaves`` has the why). ``paged_decode_attention``
-extends the split-KV kernel through the SAME scalar-prefetch path: the
-block table and the layer index ride the prefetch channel next to the
-per-row lengths, so the K/V index maps gather block-by-block — chunk j of
-row b DMAs block ``table[b, j]`` of the layer, clamped to the row's last
-occupied block exactly like the contiguous kernel clamps its chunk index.
+takes the split-KV merge through the SAME scalar-prefetch channel: the
+block table and the layer index ride it next to the per-row lengths, and
+the kernel — one program a row, the pools left in HBM — copies block
+``table[b, j]`` of the layer for the ``ceil(kv_len[b] / bs)`` blocks
+under the row's length and for no other, a lane tile's worth a step,
+the next step's copies in flight under this step's products. A row of
+length 0 is dead: nothing is read and its output is zeros. So a step
+costs what is live, not the table's width (ISSUE 32).
 Nothing is ever gathered into a contiguous logical view and no layer's
 slice of the pool is cut out: the dense fallback streams bounded
 ``[B, bs, H*D]`` chunks (one gather per table column) through the same
@@ -227,7 +230,8 @@ def dense_paged_verify_attention(
     ``block_tables[b, j]``). CAUSAL inside the tile: query t attends
     logical positions ``< kv_len - T + 1 + t``, so position 0 scores
     exactly like a single-token decode step and each draft position
-    additionally sees the drafts before it. With ``k_scale``/``v_scale``
+    additionally sees the drafts before it; a row with ``kv_len`` 0 sees
+    nothing and reads zeros. With ``k_scale``/``v_scale``
     (``[L, N, H*bs]``: a block's scales are one row, heads major) the
     pool is quantized and the scales fold into the score strip /
     probability rows per chunk.
@@ -418,45 +422,65 @@ def _decode_kernel_quant(len_ref, q_ref, k_ref, ks_ref, v_ref, vs_ref,
 
 
 def _paged_verify_kernel(len_ref, tbl_ref, layer_ref, q_ref, *refs,
-                         block_k, q_len, heads, scale, quant):
-    """THE paged kernel — one (batch row, logical block) program over a
-    small query tile; single-token decode is the T=1 tile (a dedicated
-    q_len=1 kernel would be a batched mat-vec whose left operand has no
-    free dimension, which Mosaic refuses). The block table and the layer
-    index are consumed by the INDEX MAPS (they ride the scalar-prefetch
-    channel, so the DMA fetches block ``tbl_ref[b, j]`` of layer
-    ``layer_ref[0]`` straight out of the stacked pool).
+                         block_k, group, q_len, heads, scale, quant):
+    """THE paged kernel — one program a batch ROW, which walks the row's
+    LIVE blocks and nothing else; single-token decode is the T=1 tile (a
+    dedicated q_len=1 kernel would be a batched mat-vec whose left
+    operand has no free dimension, which Mosaic refuses).
+
+    The walk. The pools stay in HBM, whole (``memory_space=pl.ANY``):
+    the kernel reads ``ceil(len_ref[b] / bs)`` blocks of row b — never
+    more than the table holds — in steps of ``group`` blocks
+    (``_blocks_per_step``), each block copied from where it lies, block
+    ``tbl_ref[b, j]`` of layer ``layer_ref[0]``, into one of two VMEM
+    buffers: step i + 1 is in flight while step i is computed. A step's
+    ``group`` blocks are ONE ``[group * bs, H*D]`` tile, so the score
+    strip fills whole lanes where a single small block would fill
+    ``bs`` of them. A row of length 0 is DEAD: no copy starts, the loop
+    runs no step, and its output is zeros. Places of the table past the
+    row's length are never looked at, so the time follows what is live
+    and not the table's width.
 
     Everything is LANE-DENSE: a pool block arrives as it is stored,
     ``(bs, H*D)`` — a token's K row is H*D contiguous values — and Mosaic
     refuses to split that minor dimension into ``(H, D)`` inside a
     kernel. So the heads are separated by a 0/1 mask instead of a
     reshape: row ``t*H + h`` of ``qh`` holds query t with every lane
-    outside head h zeroed, and ONE ``[T*H, H*D] x [bs, H*D]^T`` product
-    gives all heads' scores ``[T*H, bs]`` (the zeros take the other
-    heads' lanes out of the contraction); ``p @ v_blk`` then gives
-    ``[T*H, H*D]``, of which row ``t*H + h`` is wanted in head h's lanes
-    only, and the same mask picks those at the end. The causal mask is
-    applied INSIDE the chunk loop — query t of a row at total occupancy
-    ``len_ref[b]`` admits keys at logical positions ``< len - (T-1) + t``
-    (for T=1: ``< len``). Running max / denominator / accumulator live
-    in VMEM scratch, fp32.
+    outside head h zeroed, and ONE ``[T*H, H*D] x [group*bs, H*D]^T``
+    product gives all heads' scores (the zeros take the other heads'
+    lanes out of the contraction); ``p @ v`` then gives ``[T*H, H*D]``,
+    of which row ``t*H + h`` is wanted in head h's lanes only, and the
+    same mask picks those at the end. The causal mask is applied INSIDE
+    the walk — query t of a row at total occupancy ``len_ref[b]`` admits
+    keys at logical positions ``< len - (T-1) + t`` (for T=1: ``< len``).
+    Running max / denominator / accumulator live in VMEM scratch, fp32.
 
-    ``quant``: the pool is 1-byte; blocks are upcast in VMEM and the
-    per-(position, head) scales — a block's are ONE lane-dense row
-    ``(1, H*bs)``, heads major, spread to ``[H, bs]`` by a mask and a
-    0/1 product for the same reason — fold into the score strip /
-    probability rows after the dots: the same per-chunk dequantize
-    contract as ``_decode_kernel_quant``."""
+    ``quant``: the pool is 1-byte; a step is one block, upcast in VMEM,
+    and the per-(position, head) scales — a block's are ONE lane-dense
+    row ``(1, H*bs)``, heads major, which arrives inside its aligned
+    group of ``_SCALE_ROWS`` pool rows and is spread to ``[H, bs]`` by a
+    mask and a 0/1 product for the same reason (a pool whose block count
+    is no multiple of the group hands its last rows over as a block of
+    their own, ``ks_tail`` / ``vs_tail``: a copy cannot take part of a
+    group) — fold into the score strip / probability rows after the
+    dots: the same per-chunk dequantize contract as
+    ``_decode_kernel_quant``."""
+    k_hbm, v_hbm, *refs = refs
     if quant:
-        k_ref, ks_ref, v_ref, vs_ref, o_ref, qh_ref, m_ref, l_ref, acc_ref = refs
-    else:
-        k_ref, v_ref, o_ref, qh_ref, m_ref, l_ref, acc_ref = refs
-    b_, j = pl.program_id(0), pl.program_id(1)
-    n_k = pl.num_programs(1)
-    length = len_ref[b_]
-    rows, f = qh_ref.shape  # T*H, H*D
+        ks_hbm, vs_hbm, ks_tail, vs_tail, *refs = refs
+    o_ref, k_buf, v_buf, *refs = refs
+    if quant:
+        ks_buf, vs_buf, *refs = refs
+    sems, qh_ref, m_ref, l_ref, acc_ref = refs
+    b_ = pl.program_id(0)
+    length, layer = len_ref[b_], layer_ref[0]
+    f = qh_ref.shape[1]  # H*D
     hd = f // heads
+    span = group * block_k  # positions a step covers
+    n_blocks = jnp.minimum(
+        (length + block_k - 1) // block_k, tbl_ref.shape[1]
+    )
+    n_steps = (n_blocks + group - 1) // group
 
     def tile(piece):  # [H, ...] per query position -> [T*H, ...]
         return jnp.concatenate([piece(t) for t in range(q_len)], axis=0)
@@ -470,15 +494,72 @@ def _paged_verify_kernel(len_ref, tbl_ref, layer_ref, q_ref, *refs,
         own = own_lanes(hd)
         return tile(lambda t: own)
 
-    def scales(ref):  # the block's scale row, heads major -> [T*H, Bk]
-        # The DMA brings the aligned group of _SCALE_ROWS pool rows that
-        # holds the block's (``_paged_index_map``); pick it out.
-        jj = jnp.minimum(j, jnp.maximum((length - 1) // block_k, 0))
-        mine = tbl_ref[b_, jj] % _SCALE_ROWS
-        group = ref[:].astype(jnp.float32)  # (_SCALE_ROWS, H*Bk)
-        at = lax.broadcasted_iota(jnp.int32, group.shape, 0)
-        row = jnp.where(at == mine, group, 0.0).sum(axis=0, keepdims=True)
-        row = jnp.where(own_lanes(block_k), row, 0.0)  # (H, H*Bk)
+    def scale_group(blk):
+        """First row of the WHOLE aligned group of scale rows that a copy
+        brings for block ``blk``: its own group, or the pool's last whole
+        one (the rows after that come in ``ks_tail`` / ``vs_tail``)."""
+        whole = (ks_hbm.shape[1] // _SCALE_ROWS - 1) * _SCALE_ROWS
+        own = blk // _SCALE_ROWS * _SCALE_ROWS
+        return pl.multiple_of(jnp.minimum(own, whole), _SCALE_ROWS)
+
+    def copies(j, slot, at):
+        """The copies that bring logical block j of the row into place
+        ``at`` of buffer ``slot``."""
+        blk = tbl_ref[b_, j]
+        dst = pl.ds(at * block_k, block_k)
+        out = [
+            pltpu.make_async_copy(
+                k_hbm.at[layer, blk], k_buf.at[slot, dst], sems.at[0, slot]
+            ),
+            pltpu.make_async_copy(
+                v_hbm.at[layer, blk], v_buf.at[slot, dst], sems.at[1, slot]
+            ),
+        ]
+        if quant:
+            src = pl.ds(scale_group(blk), _SCALE_ROWS)
+            out += [
+                pltpu.make_async_copy(
+                    ks_hbm.at[layer, src], ks_buf.at[slot], sems.at[2, slot]
+                ),
+                pltpu.make_async_copy(
+                    vs_hbm.at[layer, src], vs_buf.at[slot], sems.at[3, slot]
+                ),
+            ]
+        return out
+
+    def transfer(step, slot, arrive):
+        """Start (``arrive`` False) or await the copies of ``step``'s
+        live blocks into buffer ``slot``."""
+        for at in range(group):
+            j = step * group + at
+
+            @pl.when(j < n_blocks)
+            def _():
+                for copy in copies(j, slot, at):
+                    copy.wait() if arrive else copy.start()
+
+            if not arrive:
+                continue
+
+            # A place of the last step that no live block fills holds
+            # whatever the buffer held: its scores are masked, and zeros
+            # in V keep 0 x (stale bits) out of the accumulator.
+            @pl.when(j >= n_blocks)
+            def _():
+                v_buf[slot, pl.ds(at * block_k, block_k)] = jnp.zeros(
+                    (block_k, f), v_buf.dtype
+                )
+
+    def scales(buf, tail_ref, step, slot):  # the block's row -> [T*H, bs]
+        blk = tbl_ref[b_, step]  # quant: a step is one block
+        # The pool's last group of rows (whole or not) is ``tail_ref``.
+        tail = (ks_hbm.shape[1] - 1) // _SCALE_ROWS * _SCALE_ROWS
+        in_tail = blk >= tail
+        mine = blk - jnp.where(in_tail, tail, scale_group(blk))
+        got = jnp.where(in_tail, tail_ref[:], buf[slot]).astype(jnp.float32)
+        at = lax.broadcasted_iota(jnp.int32, got.shape, 0)
+        row = jnp.where(at == mine, got, 0.0).sum(axis=0, keepdims=True)
+        row = jnp.where(own_lanes(block_k), row, 0.0)  # (H, H*bs)
         x = lax.broadcasted_iota(jnp.int32, (heads * block_k, block_k), 0)
         c = lax.broadcasted_iota(jnp.int32, (heads * block_k, block_k), 1)
         # one nonzero term a sum: exact at any matmul precision (the
@@ -486,61 +567,67 @@ def _paged_verify_kernel(len_ref, tbl_ref, layer_ref, q_ref, *refs,
         per_head = jnp.dot(
             row, ((x & (block_k - 1)) == c).astype(jnp.float32),
             preferred_element_type=jnp.float32,
-        )  # [H, Bk]
+        )  # [H, bs]
         return tile(lambda t: per_head)
 
-    @pl.when(j == 0)
-    def _init():
-        m_ref[:] = jnp.full_like(m_ref, _NEG_INF)
-        l_ref[:] = jnp.zeros_like(l_ref)
-        acc_ref[:] = jnp.zeros_like(acc_ref)
-        q = q_ref[0].astype(jnp.float32)  # (T, H*D)
-        q_rows = tile(lambda t: jnp.broadcast_to(q[t:t + 1], (heads, f)))
-        qh_ref[:] = jnp.where(head_mask(), q_rows, 0.0).astype(qh_ref.dtype)
+    m_ref[:] = jnp.full_like(m_ref, _NEG_INF)
+    l_ref[:] = jnp.zeros_like(l_ref)
+    acc_ref[:] = jnp.zeros_like(acc_ref)
+    q = q_ref[0].astype(jnp.float32)  # (T, H*D)
+    q_rows = tile(lambda t: jnp.broadcast_to(q[t:t + 1], (heads, f)))
+    qh_ref[:] = jnp.where(head_mask(), q_rows, 0.0).astype(qh_ref.dtype)
+    transfer(0, 0, arrive=False)
 
-    @pl.when(j * block_k < length)
-    def _step():
-        k_blk, v_blk = k_ref[0], v_ref[0]  # (Bk, H*D), as stored
+    def step_body(i, carry):
+        slot = lax.rem(i, 2)
+
+        @pl.when(i + 1 < n_steps)
+        def _():
+            transfer(i + 1, 1 - slot, arrive=False)
+
+        transfer(i, slot, arrive=True)
+        k_blk, v_blk = k_buf[slot], v_buf[slot]  # (span, H*D), as stored
         if quant:
             k_blk = k_blk.astype(jnp.float32)  # VMEM upcast
             v_blk = v_blk.astype(jnp.float32)
-        # (T*H, H*D) x (Bk, H*D)^T -> (T*H, Bk)
+        # (T*H, H*D) x (span, H*D)^T -> (T*H, span)
         s = lax.dot_general(
             qh_ref[:], k_blk,
             dimension_numbers=(((1,), (1,)), ((), ())),
             preferred_element_type=jnp.float32,
         ) * scale
         if quant:
-            s = s * scales(ks_ref)
-        kpos = j * block_k + lax.broadcasted_iota(jnp.int32, s.shape, 1)
+            s = s * scales(ks_buf, ks_tail, i, slot)
+        kpos = i * span + lax.broadcasted_iota(jnp.int32, s.shape, 1)
         tpos = tile(lambda t: jnp.full((heads, 1), t, jnp.int32))
-        s = jnp.where(kpos < length - (q_len - 1) + tpos, s, _NEG_INF)
+        seen = kpos < length - (q_len - 1) + tpos
+        s = jnp.where(seen, s, _NEG_INF)
         m = m_ref[:]
         m_new = jnp.maximum(m, s.max(axis=-1, keepdims=True))
-        p = jnp.exp(s - m_new)
+        p = jnp.where(seen, jnp.exp(s - m_new), 0.0)
         alpha = jnp.exp(m - m_new)
         m_ref[:] = m_new
         l_ref[:] = l_ref[:] * alpha + p.sum(axis=-1, keepdims=True)
         if quant:
-            p = p * scales(vs_ref)
-        # (T*H, Bk) x (Bk, H*D) -> (T*H, H*D)
+            p = p * scales(vs_buf, vs_tail, i, slot)
+        # (T*H, span) x (span, H*D) -> (T*H, H*D)
         acc_ref[:] = acc_ref[:] * alpha + jnp.dot(
             p.astype(v_blk.dtype), v_blk,
             preferred_element_type=jnp.float32,
         )
+        return carry
 
-    @pl.when(j == n_k - 1)
-    def _finish():
-        own = jnp.where(
-            head_mask(), acc_ref[:] / jnp.maximum(l_ref[:], 1e-30), 0.0
-        )
-        o_ref[0] = jnp.concatenate(
-            [
-                own[t * heads:(t + 1) * heads].sum(axis=0, keepdims=True)
-                for t in range(q_len)
-            ],
-            axis=0,
-        ).astype(o_ref.dtype)
+    lax.fori_loop(0, n_steps, step_body, 0)
+    own = jnp.where(
+        head_mask(), acc_ref[:] / jnp.maximum(l_ref[:], 1e-30), 0.0
+    )
+    o_ref[0] = jnp.concatenate(
+        [
+            own[t * heads:(t + 1) * heads].sum(axis=0, keepdims=True)
+            for t in range(q_len)
+        ],
+        axis=0,
+    ).astype(o_ref.dtype)
 
 
 def _kv_index_map(block_k):
@@ -628,32 +715,21 @@ def _flash_decode_quant(q, k, k_scale, v, v_scale, kv_len, *, block_k,
 
 
 #: A block's scales are ONE row of the ``[L, N, H*bs]`` scale pool, and
-#: Mosaic moves rows in aligned groups of eight: the DMA fetches the group
+#: Mosaic moves rows in aligned groups of eight: the copy fetches the group
 #: and the kernel picks its row.
 _SCALE_ROWS = 8
 
+#: Lanes of a vector register: the paged kernel takes as many blocks a step
+#: as make a score strip one register wide.
+_LANES = 128
 
-def _paged_index_map(block_k, rows=1):
-    """The block-table gather: logical block j of row b DMAs block
-    ``tbl_ref[b, j]`` of layer ``layer_ref[0]`` out of the STACKED pool,
-    where it lies — a K/V pool ``[L, N, bs, H*D]`` (``rows`` 1: the
-    block itself) or a scale pool ``[L, N, H*bs]`` (``rows``
-    ``_SCALE_ROWS``: the aligned group of rows that holds the block's).
-    Blocks entirely past the row's occupancy re-reference the last
-    occupied block (their compute is skipped by ``pl.when``) — the same
-    clamp discipline as ``_kv_index_map``, with the table lookup
-    composed on top. Lengths, table and layer all ride the
-    scalar-prefetch channel, so the physical address is available to
-    the DMA before the kernel body runs."""
 
-    def index_map(b_, j, len_ref, tbl_ref, layer_ref):
-        last = jnp.maximum((len_ref[b_] - 1) // block_k, 0)
-        blk = tbl_ref[b_, jnp.minimum(j, last)]
-        if rows > 1:
-            return (layer_ref[0], blk // rows, 0)
-        return (layer_ref[0], blk, 0, 0)
-
-    return index_map
+def _blocks_per_step(block_k, quant):
+    """Blocks the paged kernel takes in one step of its walk: as many as
+    fill the lanes of a score strip (8 blocks of 16, 2 of 64, 1 of 128 or
+    more). A quantized block is spread by its own scale row, so it is a
+    step by itself."""
+    return 1 if quant else max(1, _LANES // block_k)
 
 
 # ------------------------------------------------------------------ router
@@ -804,8 +880,9 @@ def _flash_paged_verify(q, k_pool, v_pool, kv_len, tables, layer, *,
                         interpret, name, k_scale=None, v_scale=None):
     """q ``[B, T, H, D]``, stacked pools ``[L, N, bs, H*D]`` (+ optional
     ``[L, N, H*bs]`` scales), tables ``[B, M]`` int32, ``layer`` int32
-    ``[1]`` -> ``[B, T, H, D]``. Grid is (rows, logical blocks); block_k
-    == the pool's block size; the scratch accumulators carry the T dim.
+    ``[1]`` -> ``[B, T, H, D]``. Grid is the rows; the pools are handed
+    over where they lie and the kernel copies a row's live blocks itself
+    (``_paged_verify_kernel``); the scratch accumulators carry the T dim.
     The kernel serves a decode step (T=1) and a verify tile: its caller
     names it (``attn_paged_decode`` / ``attn_paged_verify``; the
     quantized pool's kernel adds ``_quant``), and that is what a device
@@ -813,19 +890,30 @@ def _flash_paged_verify(q, k_pool, v_pool, kv_len, tables, layer, *,
     b, t, h, d = q.shape
     bs, f = k_pool.shape[2], h * d
     quant = k_scale is not None
-    q_spec = pl.BlockSpec((1, t, f), lambda b_, j, *_refs: (b_, 0, 0))
-    kv_spec = pl.BlockSpec((None, 1, bs, f), _paged_index_map(bs))
-    sc_spec = pl.BlockSpec(
-        (None, _SCALE_ROWS, h * bs), _paged_index_map(bs, _SCALE_ROWS)
-    )
+    group = _blocks_per_step(bs, quant)
+    q_spec = pl.BlockSpec((1, t, f), lambda b_, *_refs: (b_, 0, 0))
+    hbm = pl.BlockSpec(memory_space=pl.ANY)
+    kv_buf = pltpu.VMEM((2, group * bs, f), k_pool.dtype)
+    pools, bufs = (k_pool, v_pool), [kv_buf, kv_buf]
+    in_specs = [q_spec, hbm, hbm]
+    if quant:
+        sc_buf = pltpu.VMEM((2, _SCALE_ROWS, h * bs), k_scale.dtype)
+        tail = pl.BlockSpec(
+            (None, _SCALE_ROWS, h * bs),
+            lambda b_, len_ref, tbl_ref, layer_ref: (
+                layer_ref[0], (k_scale.shape[1] - 1) // _SCALE_ROWS, 0
+            ),
+        )
+        pools += (k_scale, v_scale, k_scale, v_scale)
+        bufs += [sc_buf, sc_buf]
+        in_specs += [hbm, hbm, tail, tail]
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=3,
-        grid=(b, tables.shape[1]),
-        in_specs=[q_spec]
-        + ([kv_spec, sc_spec, kv_spec, sc_spec] if quant
-           else [kv_spec, kv_spec]),
+        grid=(b,),
+        in_specs=in_specs,
         out_specs=q_spec,
-        scratch_shapes=[
+        scratch_shapes=bufs + [
+            pltpu.SemaphoreType.DMA((4 if quant else 2, 2)),  # pool x buffer
             # the query tile spread over heads; fp32 against a 1-byte pool
             pltpu.VMEM((t * h, f), jnp.float32 if quant else q.dtype),
             pltpu.VMEM((t * h, 1), jnp.float32),  # running max
@@ -833,12 +921,9 @@ def _flash_paged_verify(q, k_pool, v_pool, kv_len, tables, layer, *,
             pltpu.VMEM((t * h, f), jnp.float32),  # output accumulator
         ],
     )
-    pools = (
-        (k_pool, k_scale, v_pool, v_scale) if quant else (k_pool, v_pool)
-    )
     out = pl.pallas_call(
         functools.partial(
-            _paged_verify_kernel, block_k=bs, q_len=t, heads=h,
+            _paged_verify_kernel, block_k=bs, group=group, q_len=t, heads=h,
             scale=1.0 / np.sqrt(d), quant=quant,
         ),
         grid_spec=grid_spec,
@@ -874,14 +959,17 @@ def _local_paged_verify(q, k_pool, v_pool, kv_len, tables, layer,
     # its own (the contiguous kernel gets to pick a divisor; a paged
     # kernel cannot re-chunk across physical blocks), and a token's row
     # must fill whole 128-lane tiles.
-    tileable = bs >= 8 and (bs & (bs - 1)) == 0 and f % 128 == 0
+    # (A quantized pool's scale rows are copied in groups of _SCALE_ROWS.)
+    tileable = bs >= 8 and (bs & (bs - 1)) == 0 and f % 128 == 0 and (
+        k_scale is None or k_pool.shape[1] >= _SCALE_ROWS
+    )
     if not tileable:
         if jax.default_backend() == "tpu":
             _warn_fallback(
                 "paged flash-decode falling back to dense: block geometry "
                 f"(bs={bs}, heads*head_dim={f}) is not tileable (need a "
-                "power-of-two block size >= 8 and heads*head_dim % 128 "
-                "== 0)"
+                "power-of-two block size >= 8, heads*head_dim % 128 == 0 "
+                f"and, quantized, {_SCALE_ROWS} pool blocks or more)"
             )
         return dense()
     if interpret is None:
@@ -891,7 +979,7 @@ def _local_paged_verify(q, k_pool, v_pool, kv_len, tables, layer,
             return dense()
         interpret = False
     return _flash_paged_verify(
-        q, k_pool, v_pool, jnp.maximum(kv_len.astype(jnp.int32), 1),
+        q, k_pool, v_pool, kv_len.astype(jnp.int32),
         tables.astype(jnp.int32), jnp.asarray(layer, jnp.int32).reshape(1),
         interpret=interpret, name=name, k_scale=k_scale, v_scale=v_scale,
     )
@@ -922,11 +1010,19 @@ def paged_verify_attention(
     logical positions ``kv_len - T .. kv_len - 1``; ``kv_len [B]`` is
     each row's TOTAL occupancy including the tile; ``block_tables
     [B, M]`` int32 maps logical block j of row b to a physical pool
-    block. The pools are the model's cache leaves AS THEY ARE STORED:
+    block. ``kv_len[b] == 0`` says the row is DEAD (a serving slot with
+    no request): no block of it is read — whatever its table says — and
+    its output is zeros, finite, in the kernel and in the plain twin
+    alike. Death is the length alone: the kernel never reads it from the
+    table (physical block 0 is a block like any other here; the MODEL
+    knows that the engine hands it to no request, models/gpt.py). A live
+    row costs its ``ceil(kv_len / bs)`` blocks and a dead one an empty
+    grid step, whatever the table's width.
+    The pools are the model's cache leaves AS THEY ARE STORED:
     all layers stacked, lane-dense, ``[L, N, bs, H*D]`` (a token's K row
     is H*D contiguous values, heads major), and ``layer`` (an int32
     scalar, traced inside the layer loop) says which layer's blocks to
-    read — the kernel's index maps address the stack directly, so no
+    read — the kernel's copies address the stack directly, so no
     layer's slice of the pool is ever cut out or copied. With
     ``k_scale``/``v_scale`` (``[L, N, H*bs]``: a block's per-(position,
     head) scales as one row, heads major; both or neither) the pool is

@@ -2837,10 +2837,16 @@ class ServingEngine:
         ONE full layer and ONE sliding layer attend over in this step,
         summed over the live rows (a row that writes position p attends
         p + 1 positions, a window's worth at most in a sliding layer), and
-        the sliding pool's fill."""
-        if not self.mixed:
+        the sliding pool's fill. The uniform paged engine says
+        ``kv_blocks_live``: the blocks ONE layer's paged kernel walks in
+        this step (table places, slots x width, are static: the share of
+        them that is walked is this over that)."""
+        if not self.paged:
             return {}
         attended = self._len[self._active]  # p + 1 with p = len - 1
+        if not self.mixed:
+            return {"kv_blocks_live": int(
+                (-(-attended // self.block_size)).sum())}
         out = {"kv_tokens_full": int(attended.sum())}
         if self.window:
             out.update(

@@ -18,6 +18,8 @@ import pytest as _pytest_mark
 # integration gates compile multi-second decode programs and stay tier-1.
 pytestmark = _pytest_mark.mark.serving
 
+import functools
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -583,6 +585,76 @@ def test_paged_kernel_reads_the_stacked_pool_where_it_lies(pool, t):
             interpret=True, **kw,
         )
         np.testing.assert_array_equal(np.asarray(one), np.asarray(kern[:, 0]))
+
+
+@pytest.mark.fast
+@pytest.mark.parametrize("t", [1, 4], ids=lambda t: f"T{t}")
+@pytest.mark.parametrize("pool", ["bf16", "int8"])
+def test_paged_kernel_walks_live_blocks_only(pool, t):
+    """ISSUE 32 op gate: the kernel's walk follows ``kv_len`` and nothing
+    else. Rows at the walk's edges — length 0 (DEAD: no block read, zeros
+    out), the shortest live row, one short of and exactly on a block
+    boundary, on and just past a multiple of the blocks a step takes, and
+    the whole table (whose width is no multiple of a step) — agree with
+    the plain twin, in layer ``LAYER`` of the stack; and a pool POISONED
+    with NaN everywhere a live block is not (every block of the dead row
+    among them, though its table names real blocks: death is the length,
+    never the table — a live row here owns block 0) reads the same, bit
+    for bit."""
+    b, h, d, bs, m_tbl = 8, 4, 64, 16, 20
+    s = m_tbl * bs
+    step = da._blocks_per_step(bs, pool == "int8") * bs  # positions a step
+    assert (m_tbl * bs) % (8 * bs) != 0
+    lens = jnp.asarray(
+        [0, t, bs - 1, bs, 8 * bs, 8 * bs + 1, 2 * step + 3, s], jnp.int32
+    )  # total incl. the tile
+    rng = np.random.default_rng(32 + t)
+    k = jnp.asarray(rng.normal(size=(b, s, h, d)), jnp.float32)
+    v = jnp.asarray(rng.normal(size=(b, s, h, d)), jnp.float32)
+    q = jnp.asarray(rng.normal(size=(b, t, h, d)), jnp.bfloat16)
+    sc = None
+    if pool == "int8":
+        from frl_distributed_ml_scaffold_tpu.ops.quantization import quantize
+
+        k, ks = quantize(k, "int8", channel_axes=(0, 1, 2))
+        v, vs = quantize(v, "int8", channel_axes=(0, 1, 2))
+        sc = (ks[..., 0].astype(jnp.bfloat16), vs[..., 0].astype(jnp.bfloat16))
+    else:
+        k, v = k.astype(jnp.bfloat16), v.astype(jnp.bfloat16)
+    pools = list(_paged_from_contiguous(k, v, bs, b * m_tbl + 6, seed=t,
+                                        scales=sc))
+    tables = pools[2]
+    leaves = [pools[0], pools[1]] + list(pools[3] or ())
+    # A LIVE row's first block is physical block 0.
+    moved = int(tables[3, 0])
+    leaves = [x.at[LAYER, 0].set(x[LAYER, moved]) for x in leaves]
+    tables = tables.at[3, 0].set(0)
+    live = {
+        int(tables[r, j])
+        for r in range(b) for j in range(-(-int(lens[r]) // bs))
+    }
+    dead = np.asarray([i for i in range(leaves[0].shape[1]) if i not in live])
+
+    def poisoned(x):
+        bad = 127 if jnp.issubdtype(x.dtype, jnp.integer) else jnp.nan
+        return x.at[:, dead].set(bad)
+
+    def run(fn, xs):
+        kw = {} if sc is None else dict(k_scale=xs[2], v_scale=xs[3])
+        return np.asarray(
+            fn(q, xs[0], xs[1], lens, tables, LAYER, **kw), np.float32
+        )
+
+    kernel = functools.partial(
+        da.paged_verify_attention, impl="flash", interpret=True
+    )
+    ref = run(functools.partial(da.paged_verify_attention, impl="dense"),
+              leaves)
+    clean = run(kernel, leaves)
+    np.testing.assert_allclose(clean, ref, atol=2e-2, rtol=2e-2)
+    assert not ref[0].any() and not clean[0].any(), "a dead row reads zeros"
+    dirty = run(kernel, [poisoned(x) for x in leaves])
+    np.testing.assert_array_equal(dirty, clean)
 
 
 @pytest.mark.fast

@@ -491,6 +491,84 @@ def test_paged_engine_matches_generate_with_block_append(gpt):
     eng.close()
 
 
+def test_paged_engine_tells_the_kernel_which_rows_are_dead(gpt, monkeypatch):
+    """ISSUE 32: slots retire and are taken again while others decode on,
+    and every request stays token-identical to generate(). The decode
+    program has no argument that says which rows are live — a retired
+    row's cursor runs on, a never-used row's too — so the model reads
+    death from the table it holds (a row whose table starts at the trash
+    block 0) and hands the paged kernel length 0 for it: what reaches
+    ``paged_verify_attention`` is the host's ``_len`` for an active slot
+    and 0 for every other, in every step. The `decode` span's
+    ``kv_blocks_live`` is the host's own count of the blocks under those
+    lengths."""
+    import importlib
+
+    from frl_distributed_ml_scaffold_tpu.telemetry import Tracer
+
+    da = importlib.import_module(
+        "frl_distributed_ml_scaffold_tpu.ops.decode_attention"
+    )
+    model, params, _ = gpt
+    bs, seen, told = 8, [], []
+    inner = da.paged_verify_attention
+
+    def watched(q, k_pool, v_pool, kv_len, tables, layer, **kw):
+        jax.debug.callback(
+            lambda n, l: seen.append(tuple(int(x) for x in n))
+            if int(l) == 0 else None,
+            kv_len, layer,
+        )
+        return inner(q, k_pool, v_pool, kv_len, tables, layer, **kw)
+
+    monkeypatch.setattr(da, "paged_verify_attention", watched)
+    tracer = Tracer(capacity=100_000)
+    eng = ServingEngine(
+        model, params, num_slots=3, temperature=0.0, kv_block_size=bs,
+        tracer=tracer,
+    )
+    call = eng._call
+
+    def recording(program, key, fn, *args):
+        if program == "paged_decode":
+            told.append(tuple(
+                int(n) if a else 0 for n, a in zip(eng._len, eng._active)
+            ))
+        return call(program, key, fn, *args)
+
+    eng._call = recording
+    rng = np.random.default_rng(32)
+    reqs = [  # two slots busy, the third never used until the end
+        (rng.integers(0, 64, size=5).astype(np.int32), 20),
+        (rng.integers(0, 64, size=3).astype(np.int32), 4),
+    ]
+    ids = {eng.submit(p, n): (p, n) for p, n in reqs}
+    done = {}
+    for _ in range(8):  # the short one retires; its slot stands dead
+        done.update((c.id, c) for c in eng.step())
+    assert int(eng._active.sum()) == 1 and len(done) == 1
+    for p, n in [(rng.integers(0, 64, size=9).astype(np.int32), 6),
+                 (rng.integers(0, 64, size=2).astype(np.int32), 11)]:
+        ids[eng.submit(p, n)] = (p, n)  # the dead slot is taken again
+    done.update((c.id, c) for c in eng.run())
+    jax.effects_barrier()
+    assert sorted(done) == sorted(ids)
+    for rid, (prompt, n_new) in ids.items():
+        ref = generate(
+            model, params, jnp.asarray(prompt)[None], max_new_tokens=n_new,
+            temperature=0.0,
+        )
+        np.testing.assert_array_equal(done[rid].tokens, np.asarray(ref)[0])
+    assert len(told) == eng.stats["decode_paged"] > 15
+    assert sorted(seen) == sorted(told)
+    assert any(0 in row for row in told) and any(all(row) for row in told)
+    decodes = [s for s in tracer.spans() if s["name"] == "decode"]
+    assert [s["kv_blocks_live"] for s in decodes] == [
+        sum(-(-n // bs) for n in row) for row in told
+    ]
+    eng.close()
+
+
 @pytest.mark.parametrize("fmt", ["int8", "fp8_e4m3"])
 def test_paged_engine_token_identical_across_block_sizes_and_formats(
     gpt, fmt
