@@ -52,6 +52,13 @@ TRAIN_POINT = (
     "model.attention=flash", "model.lm_loss_chunk=128",
     "trainer.remat=none", "model.block_remat=full",
 )
+#: The attention of Laguna-XS.2's two layer kinds (the configuration
+#: ``laguna-xs2-serve``): kind -> (query heads, window), over 8 KV heads of
+#: 128 in pool blocks of 128.
+LAGUNA_KINDS = dict(
+    kv_heads=8, head_dim=128, block=128,
+    layers={"full": (48, 0), "sliding": (64, 512)},
+)
 
 
 def emit(phase: str, **fields) -> None:
@@ -427,7 +434,8 @@ def phase_serve(fields: dict, *, model_kw: dict = GPT2_MEDIUM,
 def phase_kernels(fields: dict, *, batch: int = 8, heads: int = 16,
                   head_dim: int = 64, seq: int = 1024,
                   block_sizes: tuple[int, ...] = (16, 64),
-                  verify_len: int = 4, adamw_shape=(1024, 4096),
+                  verify_len: int = 4, kinds: dict = LAGUNA_KINDS,
+                  adamw_shape=(1024, 4096),
                   interpret: bool | None = None) -> None:
     """Every Pallas kernel of the train and serve paths, compiled for real
     at the width it has there and compared with its dense reference.
@@ -437,11 +445,13 @@ def phase_kernels(fields: dict, *, batch: int = 8, heads: int = 16,
 
     import jax
     import jax.numpy as jnp
+    import numpy as np
     import optax
 
     from frl_distributed_ml_scaffold_tpu.models.generation import (
         slot_blocks_to_pool,
     )
+    from frl_distributed_ml_scaffold_tpu.models.gpt import grouped_attention
     from frl_distributed_ml_scaffold_tpu.ops.flash_attention import (
         flash_attention,
     )
@@ -587,6 +597,47 @@ def phase_kernels(fields: dict, *, batch: int = 8, heads: int = 16,
             qv, kp, vp, lens_v, tables, layer), 2e-2)
         del kp, vp, kp8, vp8, ksp, vsp, out
 
+    # The same paged kernel over the pools of a layer KIND: query heads
+    # grouped over fewer KV heads and, under a window, the table a ring
+    # (block j at place j % places) that holds a row's newest blocks — the
+    # longer rows have wrapped it, the shorter stay under the window. Held
+    # to its plain twin and to plain grouped attention over the contiguous
+    # K/V.
+    h_kv, hd, bs = kinds["kv_heads"], kinds["head_dim"], kinds["block"]
+    kk, vk = (
+        jax.random.normal(next(keys), (batch, seq, h_kv, hd), bf16)
+        for _ in range(2)
+    )
+    for kind, (h_q, window) in kinds["layers"].items():
+        places = -(-window // bs) + 1 if window else seq // bs
+        tables = np.asarray(
+            jax.random.permutation(next(keys), batch * places) + 1, np.int32
+        ).reshape(batch, places)
+        pools = np.zeros((2, 2, batch * places + 1, bs, h_kv * hd), bf16)
+        for r, n in enumerate(np.asarray(lens_p)):
+            end = -(-int(n) // bs)
+            for j in range(max(end - places, 0), end):
+                for pool, x in zip(pools, (kk, vk)):
+                    pool[layer, tables[r, j % places]] = np.asarray(
+                        x[r, j * bs:(j + 1) * bs]).reshape(bs, h_kv * hd)
+        kp, vp = jnp.asarray(pools[0]), jnp.asarray(pools[1])
+        qk = jax.random.normal(next(keys), (batch, h_q, hd), bf16)
+        out = jit_checked(
+            f"mixed_decode_{kind}",
+            lambda q, k, v, l, t: da.paged_verify_attention(
+                q[:, None], k, v, l, t, layer, window=window, impl="flash",
+                interpret=interpret, name=f"attn_mixed_decode_{kind}")[:, 0],
+            qk, kp, vp, lens_p, jnp.asarray(tables),
+        )
+        check(f"mixed_decode_{kind}", out, da.dense_paged_decode_attention(
+            qk, kp, vp, lens_p, jnp.asarray(tables), layer, window=window),
+            2e-2)
+        check(f"mixed_vs_contiguous_{kind}", out, jnp.where(
+            live, grouped_attention(
+                qk[:, None], kk, vk, kv_len[:, None] - 1, window=window
+            )[:, 0], 0), 2e-2)
+        del kp, vp, pools, out
+
     # Fused AdamW vs optax.adamw at an MLP weight's shape.
     params = {"w": jax.random.normal(next(keys), adamw_shape)}
     grads = jax.tree.map(jnp.cos, params)
@@ -604,6 +655,7 @@ def phase_kernels(fields: dict, *, batch: int = 8, heads: int = 16,
     fields.update(
         widths=dict(batch=batch, heads=heads, head_dim=head_dim, seq=seq,
                     block_sizes=list(block_sizes), verify_len=verify_len,
+                    kinds=kinds,
                     adamw_shape=list(adamw_shape)),
         kernels_in_program=_on_tpu(),
         max_abs_err={k_: float(f"{e:.3g}") for k_, e in errs.items()},

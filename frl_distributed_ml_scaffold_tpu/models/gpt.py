@@ -656,6 +656,89 @@ def init_paged_cache(model: "GPT", batch: int) -> Any:
     }
 
 
+def live_rows(full_tables: jnp.ndarray) -> jnp.ndarray:
+    """``[B]`` bool: the slot rows that hold a request, read from the block
+    tables of the layers that keep every position. A row whose table
+    starts at the trash block is DEAD: the engine hands block 0 to no
+    request, and a retired or never-used slot's row is all zeros. Nothing
+    resets such a row's cursor — a dead row is not short — so its death is
+    read here and nowhere else."""
+    return full_tables[:, 0] != 0
+
+
+def paged_attend(
+    q, k, v, pools, tables, row, idx, full_tables, *, window: int = 0,
+    quant: str = "none", impl: str, name: str,
+):
+    """One layer's step over the PAGED cache: write the step's K/V into
+    the carried pools in place, then attend — the one place that decides
+    where a token's K/V row goes and which rows a step attends.
+
+    ``q [B, T, Hq, hd]`` and ``k, v [B, T, Hkv, hd]`` are the tokens at
+    logical positions ``idx[b] .. idx[b] + T - 1``. ``pools``: ``key_pool``
+    and ``value_pool`` ``[rows, N, bs, Hkv*hd]`` (quantized: 1-byte, with
+    ``key_pool_scale`` / ``value_pool_scale`` ``[rows, N, Hkv*bs]``), of
+    which this layer owns row ``row`` (traced in the scanned stack).
+    ``tables [B, places]``: position p of a row sits in pool block
+    ``tables[b, p // bs]`` at offset ``p % bs`` — the block's place CLAMPED
+    to the table for layers that keep every position (a dead row's cursor
+    runs on, and a verify tile's DRAFT positions beyond the blocks the
+    engine appended land in the trash block: padding whose scores are never
+    accepted), and taken ``% places`` under a ``window``, whose table is a
+    ring. ``full_tables``: the tables that say which rows are dead
+    (``live_rows``; ``tables`` itself but for a sliding layer). A dead row
+    is told length 0, so the kernel reads no block of it and returns zeros,
+    where its cursor alone would have it walk the trash block for as long
+    as the row once was.
+    Returns the attention output ``[B, T, Hq, hd]`` and the updated pools:
+    nothing pool-sized is cut out, copied or written back (the compiled HLO
+    is pinned in tests/test_chip_compile.py)."""
+    from frl_distributed_ml_scaffold_tpu.ops.decode_attention import (
+        paged_verify_attention,
+    )
+
+    b, t, h_kv, hd = k.shape
+    bs, places = pools["key_pool"].shape[2], tables.shape[1]
+    offs = idx[:, None] + jnp.arange(t)[None, :]  # [B, t]
+    blk = offs // bs
+    phys = jnp.take_along_axis(
+        tables.astype(jnp.int32),
+        blk % places if window else jnp.minimum(blk, places - 1),
+        axis=1,
+    )  # [B, t]
+    off = offs % bs
+    pools, rows = dict(pools), {"key_pool": k, "value_pool": v}
+    if quant != "none":
+        from frl_distributed_ml_scaffold_tpu.ops.quantization import quantize
+
+        # Quantize ONCE per written token over its own head vector:
+        # per-(row, pos, head) scales over hd, identical to the contiguous
+        # path's scale at the same position. A block's scales are one row,
+        # heads major: head i of offset o sits at lane i * bs + o.
+        lanes = jnp.arange(h_kv) * bs + off[..., None]
+        for n, x in rows.items():
+            rows[n], sc = quantize(x, quant, channel_axes=(0, 1, 2))
+            pools[n + "_scale"] = _constrain_kv_pool(
+                pools[n + "_scale"].at[row, phys[..., None], lanes].set(
+                    sc[..., 0].astype(jnp.bfloat16)),
+                h_kv,
+            )
+    for n, x in rows.items():
+        pools[n] = _constrain_kv_pool(
+            pools[n].at[row, phys, off].set(
+                x.reshape(b, t, h_kv * hd).astype(pools[n].dtype)),
+            h_kv,
+        )
+    y = paged_verify_attention(
+        q, pools["key_pool"], pools["value_pool"],
+        jnp.where(live_rows(full_tables), idx + t, 0), tables, row,
+        window=window,
+        k_scale=pools.get("key_pool_scale"),
+        v_scale=pools.get("value_pool_scale"), impl=impl, name=name,
+    )
+    return y, pools
+
+
 def _constrain_kv_cache(x: jnp.ndarray) -> jnp.ndarray:
     """Pin a cache leaf — [B, S, H, hd] K/V values or their [B, S, H]
     quantization scales — model-sharded over the mesh's ``model`` axis
@@ -789,108 +872,31 @@ class CausalSelfAttention(nn.Module):
                 # ``paged_cache_leaves``) and the layer loop CARRIES them
                 # whole (GPT.__call__): this layer writes its tokens' rows
                 # at ``[layer, block, offset]`` in place and the kernel
-                # reads the stack where it lies. Nothing pool-sized is cut
-                # out, copied or written back (the compiled HLO is pinned
-                # in tests/test_chip_compile.py).
-                bs_blk = self.kv_block_size
+                # reads the stack where it lies (``paged_attend``). ONE
+                # kernel for the decode step and the speculative VERIFY
+                # tile: all t positions score against the paged cache in
+                # one forward, causal inside the tile, so greedy acceptance
+                # against these logits is exact; the decode step is its
+                # t = 1 tile, under its own name in a device trace.
                 cache = {
                     name: self.variable("cache", name, jnp.zeros, shape, dt)
                     for name, (shape, dt) in paged_cache_leaves(
-                        cfg, self.dtype, block_size=bs_blk,
+                        cfg, self.dtype, block_size=self.kv_block_size,
                         pool_blocks=self.kv_pool_blocks, batch=b,
                     ).items()
                 }
-                ck, cv = cache["key_pool"], cache["value_pool"]
-                ci = cache["cache_index"]
+                ci = cache.pop("cache_index")
                 idx = ci.value[layer]  # [B]
-                # Physical write target for the j-th tile column: block
-                # tbl[(idx + j) // bs], offset (idx + j) % bs. Retired
-                # slots point at the reserved trash block 0 (and their
-                # index keeps advancing), so the lookup clamps to the
-                # table width instead of trusting idx to stay inside the
-                # logical capacity — for the verify tile (t > 1, ISSUE
-                # 11) the same clamp also routes DRAFT positions beyond
-                # the row's allocated blocks into the trash block: the
-                # engine only appends blocks through each row's real
-                # draft count, and positions past it are padding whose
-                # scores are never accepted.
-                m_tbl = block_tables.shape[1]
-                offs = idx[:, None] + jnp.arange(t)[None, :]  # [B, t]
-                phys = jnp.take_along_axis(
-                    block_tables.astype(jnp.int32),
-                    jnp.minimum(offs // bs_blk, m_tbl - 1),
-                    axis=1,
-                )  # [B, t]
-                off = offs % bs_blk
-                k_w = k.astype(self.dtype)  # [B, t, H, hd]
-                v_w = v.astype(self.dtype)
-                scales = {}
-                if quant:
-                    from frl_distributed_ml_scaffold_tpu.ops.quantization import (
-                        quantize,
-                    )
-
-                    # Quantize ONCE per written token over its own head
-                    # vector (the PR 6 contract): per-(row, pos, head)
-                    # scales over hd, identical to the contiguous path's
-                    # scale at the same position. A block's scales are one
-                    # row, heads major: head i of offset o sits at lane
-                    # i * bs + o.
-                    lanes = jnp.arange(h) * bs_blk + off[..., None]
-
-                    def quantized(x, scale_pool):
-                        x_q, sc = quantize(
-                            x, cfg.kv_cache_quant, channel_axes=(0, 1, 2)
-                        )
-                        scale_pool.value = _constrain_kv_pool(
-                            scale_pool.value.at[
-                                layer, phys[..., None], lanes
-                            ].set(sc[..., 0].astype(jnp.bfloat16)),
-                            h,
-                        )
-                        return x_q  # [B, t, H, hd] 1-byte payload
-
-                    k_w = quantized(k_w, cache["key_pool_scale"])
-                    v_w = quantized(v_w, cache["value_pool_scale"])
-                    scales = dict(
-                        k_scale=cache["key_pool_scale"].value,
-                        v_scale=cache["value_pool_scale"].value,
-                    )
-                ck.value = _constrain_kv_pool(
-                    ck.value.at[layer, phys, off].set(k_w.reshape(b, t, d)),
-                    h,
-                )
-                cv.value = _constrain_kv_pool(
-                    cv.value.at[layer, phys, off].set(v_w.reshape(b, t, d)),
-                    h,
-                )
-                # ONE kernel for the decode step and the speculative
-                # VERIFY tile (ISSUE 11): all t positions score against
-                # the paged cache in one forward — causal inside the tile
-                # (query j attends logical positions <= idx + j), so
-                # query 0 computes exactly the single-token decode step's
-                # output and greedy acceptance against these logits is
-                # exact. The decode step is its t = 1 tile, under its own
-                # name in a device trace.
-                from frl_distributed_ml_scaffold_tpu.ops.decode_attention import (
-                    paged_verify_attention,
-                )
-
-                # A row whose table starts at the trash block is DEAD (the
-                # engine hands block 0 to no request, and a retired or
-                # never-used slot's row is all zeros): nothing resets its
-                # cursor, so the kernel is told length 0 — no block read,
-                # zeros out — where the cursor alone would have it walk
-                # the trash block for as long as the row once was.
-                live = block_tables[:, 0] != 0
-                y = paged_verify_attention(
-                    q, ck.value, cv.value, jnp.where(live, idx + t, 0),
-                    block_tables, layer,
-                    impl=cfg.decode_attention,
+                y, pools = paged_attend(
+                    q, k.astype(self.dtype), v.astype(self.dtype),
+                    {n: c.value for n, c in cache.items()}, block_tables,
+                    layer, idx, block_tables,
+                    quant=cfg.kv_cache_quant, impl=cfg.decode_attention,
                     name="attn_paged_decode" if t == 1
                     else "attn_paged_verify",
-                    **scales,
                 )
+                for n, c in cache.items():
+                    c.value = pools[n]
                 ci.value = ci.value.at[layer].set(idx + t)
                 y = y.reshape(b, t, d)
                 y = nn.Dense(
@@ -1060,7 +1066,6 @@ class GroupedAttention(nn.Module):
     kind: str  # full | sliding
     row: int  # this layer's row in its kind's pool
     cache_len: int = 0
-    kv_block_size: int = 0
 
     @nn.compact
     def __call__(self, x, *, train: bool, decode: bool, ctx: dict):
@@ -1104,36 +1109,13 @@ class GroupedAttention(nn.Module):
             y = grouped_attention(q, ck.value, cv.value, positions,
                                   window=window)
         else:
-            from frl_distributed_ml_scaffold_tpu.ops.decode_attention import (
-                paged_grouped_decode_attention,
-            )
-
-            if t != 1:
-                raise NotImplementedError(
-                    "the pools of a model with layer_types take single-token "
-                    "decode steps only (no verify tile: speculation is "
-                    "refused at the engine's construction)"
-                )
-            k_pool, v_pool = pools[self.kind]
-            tbl = ctx["tables"][self.kind]
-            bs, places = self.kv_block_size, tbl.shape[1]
-            idx = ctx["idx"]  # [B]: this step's write position
-            blk = idx // bs
-            # A sliding layer's table is a ring; a full layer's is clamped
-            # like the uniform stack's (a retired row's cursor runs on).
-            place = blk % places if window else jnp.minimum(blk, places - 1)
-            phys = jnp.take_along_axis(tbl, place[:, None], axis=1)[:, 0]
-            at = (self.row, phys, idx % bs)
-            k_pool = k_pool.at[at].set(
-                k[:, 0].reshape(b, h_kv * hd).astype(k_pool.dtype))
-            v_pool = v_pool.at[at].set(
-                v[:, 0].reshape(b, h_kv * hd).astype(v_pool.dtype))
-            pools = {**pools, self.kind: (k_pool, v_pool)}
-            y = paged_grouped_decode_attention(
-                q[:, 0], k_pool, v_pool, idx + 1, tbl, self.row,
-                window=window, impl=cfg.decode_attention,
+            y, kind_pools = paged_attend(
+                q, k, v, pools[self.kind], ctx["tables"][self.kind],
+                self.row, ctx["idx"], ctx["tables"]["full"], window=window,
+                impl=cfg.decode_attention,
                 name=f"attn_mixed_decode_{self.kind}",
-            )[:, None]
+            )
+            pools = {**pools, self.kind: kind_pools}
         if cfg.attention_gate:
             gate = nn.Dense(h, use_bias=False, dtype=self.dtype, name="gate")(x)
             y = y * jax.nn.sigmoid(gate.astype(jnp.float32)).astype(
@@ -1204,8 +1186,7 @@ class Block(nn.Module):
         y = make_norm(cfg, "ln1")(x)
         attn_out, pools = GroupedAttention(
             cfg, self.dtype, kind, kind_layers(cfg)[kind].index(i),
-            cache_len=self.cache_len, kv_block_size=self.kv_block_size,
-            name="attn",
+            cache_len=self.cache_len, name="attn",
         )(y, train=train, decode=self.decode, ctx=ctx)
         x = x + attn_out
         y = make_norm(cfg, "ln2")(x)
@@ -1341,12 +1322,12 @@ class GPT(nn.Module):
             ctx["tables"] = {
                 k: self.get_variable("cache", table_of(k)) for k in kinds}
             ctx["pools"] = {
-                k: (self.get_variable("cache", f"key_pool_{k}"),
-                    self.get_variable("cache", f"value_pool_{k}"))
+                k: {n: self.get_variable("cache", f"{n}_{k}")
+                    for n in ("key_pool", "value_pool")}
                 for k in kinds}
-            # A slot row with no request points at the trash block 0: its
-            # token goes to no expert (it would cost an expert's read).
-            ctx["token_mask"] = (ctx["tables"]["full"][:, :1] != 0)
+            # A slot row with no request attends nothing, and its token
+            # goes to no expert (it would cost an expert's read).
+            ctx["token_mask"] = live_rows(ctx["tables"]["full"])[:, None]
         elif decode:
             ctx["token_mask"] = (
                 jnp.arange(t)[None, :] >= (t - lens)[:, None])  # not padding
@@ -1359,9 +1340,9 @@ class GPT(nn.Module):
                 name=f"layer_{i}",
             )((x, aux, ctx), None)
         if paged:
-            for k, (k_pool, v_pool) in ctx["pools"].items():
-                self.put_variable("cache", f"key_pool_{k}", k_pool)
-                self.put_variable("cache", f"value_pool_{k}", v_pool)
+            for k, kind_pools in ctx["pools"].items():
+                for n, pool in kind_pools.items():
+                    self.put_variable("cache", f"{n}_{k}", pool)
             self.put_variable("cache", "moe_stats", ctx["moe_stats"])
         return x, aux
 

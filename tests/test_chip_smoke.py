@@ -108,11 +108,14 @@ def test_kernel_phase_rehearses_on_cpu(smoke, capsys):
         cs.phase_kernels(
             f, batch=2, heads=2, head_dim=32, seq=128, block_sizes=(16,),
             verify_len=3, adamw_shape=(512, 256), interpret=True,
+            kinds=dict(kv_heads=2, head_dim=128, block=16,
+                       layers={"full": (6, 0), "sliding": (8, 40)}),
         )
     errs = _phase_line(capsys, "kernels")["max_abs_err"]
     assert {"flash_fwd", "flash_bwd_dq", "decode", "decode_int8",
             "paged_decode_bs16", "paged_decode_int8_bs16",
-            "paged_verify_bs16", "fused_adamw"} <= set(errs)
+            "paged_verify_bs16", "mixed_decode_full", "mixed_decode_sliding",
+            "mixed_vs_contiguous_sliding", "fused_adamw"} <= set(errs)
 
 
 def test_four_chip_phase_rehearses_on_virtual_devices(smoke, capsys):

@@ -587,30 +587,69 @@ def test_paged_kernel_reads_the_stacked_pool_where_it_lies(pool, t):
         np.testing.assert_array_equal(np.asarray(one), np.asarray(kern[:, 0]))
 
 
+#: (query heads, KV heads, head size, window) of the pools the one paged
+#: kernel serves: the uniform stack's (each head its own lanes, half a lane
+#: tile wide), a layer kind's with the query heads grouped over fewer KV
+#: heads, and a sliding kind's, whose table is a ring under a window that
+#: starts on no block boundary.
+GEOMETRY = {
+    "uniform": (4, 4, 64, 0),
+    "grouped": (8, 2, 128, 0),
+    "ring": (8, 2, 128, 40),
+}
+
+
+def _plain_paged_attention(q, k, v, lens, window):
+    """A softmax a (row, query, head), in numpy: query j of a row of total
+    length L (the tile included) sees positions ``< L - T + 1 + j``, the
+    last ``window`` of them under a window; query head i reads KV head
+    ``i // (Hq / Hkv)``; a row of length 0 reads zeros."""
+    q, k, v = (np.asarray(x, np.float32) for x in (q, k, v))
+    (b, t, h, d), h_kv = q.shape, k.shape[2]
+    out = np.zeros(q.shape, np.float32)
+    for r in range(b):
+        for j in range(t):
+            n = int(lens[r]) - (t - 1) + j
+            lo = max(n - window, 0) if window else 0
+            for i in range(h if n > lo else 0):
+                kv = i // (h // h_kv)
+                sc = k[r, lo:n, kv] @ q[r, j, i] / np.sqrt(d)
+                p = np.exp(sc - sc.max())
+                out[r, j, i] = (p / p.sum()) @ v[r, lo:n, kv]
+    return out
+
+
 @pytest.mark.fast
 @pytest.mark.parametrize("t", [1, 4], ids=lambda t: f"T{t}")
 @pytest.mark.parametrize("pool", ["bf16", "int8"])
-def test_paged_kernel_walks_live_blocks_only(pool, t):
-    """ISSUE 32 op gate: the kernel's walk follows ``kv_len`` and nothing
-    else. Rows at the walk's edges — length 0 (DEAD: no block read, zeros
-    out), the shortest live row, one short of and exactly on a block
-    boundary, on and just past a multiple of the blocks a step takes, and
-    the whole table (whose width is no multiple of a step) — agree with
-    the plain twin, in layer ``LAYER`` of the stack; and a pool POISONED
-    with NaN everywhere a live block is not (every block of the dead row
-    among them, though its table names real blocks: death is the length,
-    never the table — a live row here owns block 0) reads the same, bit
-    for bit."""
-    b, h, d, bs, m_tbl = 8, 4, 64, 16, 20
+@pytest.mark.parametrize("geometry", list(GEOMETRY))
+def test_paged_kernel_walks_live_blocks_only(geometry, pool, t):
+    """ISSUE 32 op gate, for every geometry the ONE paged kernel serves
+    (ISSUE 33): the kernel's walk follows ``kv_len`` — and the window —
+    and nothing else. Rows at the walk's edges — length 0 (DEAD: no block
+    read, zeros out), the shortest live row, one short of and exactly on a
+    block boundary, on and just past a multiple of the blocks a step takes,
+    and the whole table (whose width is no multiple of a step); under a
+    window, a context shorter than it, exactly it, one past it, and several
+    windows long (the ring has wrapped many times) — agree with the plain
+    twin, in layer ``LAYER`` of the stack, and the twin with a softmax a
+    position; and a pool POISONED with NaN everywhere a block of the walk
+    is not (every block of the dead row among them, though its table names
+    real blocks: death is the length, never the table — a live row here
+    owns block 0; and the blocks a ring still names that the window has
+    left) reads the same, bit for bit."""
+    h, h_kv, d, window = GEOMETRY[geometry]
+    b, bs, m_tbl = 8, 16, 20
     s = m_tbl * bs
     step = da._blocks_per_step(bs, pool == "int8") * bs  # positions a step
-    assert (m_tbl * bs) % (8 * bs) != 0
-    lens = jnp.asarray(
-        [0, t, bs - 1, bs, 8 * bs, 8 * bs + 1, 2 * step + 3, s], jnp.int32
+    assert (m_tbl * bs) % (8 * bs) != 0 and (window % bs or not window)
+    lens = (
+        [0, t, 23, window, window + 1, 5 * window + 7, 10 * bs, s] if window
+        else [0, t, bs - 1, bs, 8 * bs, 8 * bs + 1, 2 * step + 3, s]
     )  # total incl. the tile
     rng = np.random.default_rng(32 + t)
-    k = jnp.asarray(rng.normal(size=(b, s, h, d)), jnp.float32)
-    v = jnp.asarray(rng.normal(size=(b, s, h, d)), jnp.float32)
+    k = jnp.asarray(rng.normal(size=(b, s, h_kv, d)), jnp.float32)
+    v = jnp.asarray(rng.normal(size=(b, s, h_kv, d)), jnp.float32)
     q = jnp.asarray(rng.normal(size=(b, t, h, d)), jnp.bfloat16)
     sc = None
     if pool == "int8":
@@ -619,8 +658,11 @@ def test_paged_kernel_walks_live_blocks_only(pool, t):
         k, ks = quantize(k, "int8", channel_axes=(0, 1, 2))
         v, vs = quantize(v, "int8", channel_axes=(0, 1, 2))
         sc = (ks[..., 0].astype(jnp.bfloat16), vs[..., 0].astype(jnp.bfloat16))
+        plain_kv = [x.astype(jnp.float32) * y.astype(jnp.float32)[..., None]
+                    for x, y in ((k, sc[0]), (v, sc[1]))]
     else:
         k, v = k.astype(jnp.bfloat16), v.astype(jnp.bfloat16)
+        plain_kv = [k, v]
     pools = list(_paged_from_contiguous(k, v, bs, b * m_tbl + 6, seed=t,
                                         scales=sc))
     tables = pools[2]
@@ -628,33 +670,54 @@ def test_paged_kernel_walks_live_blocks_only(pool, t):
     # A LIVE row's first block is physical block 0.
     moved = int(tables[3, 0])
     leaves = [x.at[LAYER, 0].set(x[LAYER, moved]) for x in leaves]
-    tables = tables.at[3, 0].set(0)
+    tables = np.asarray(tables.at[3, 0].set(0))
+    # The blocks a row's walk reads: those under its length, from the first
+    # one the tile's window reaches.
+    end = [-(-n // bs) for n in lens]
+    first = [max(n - (t - 1) - window, 0) // bs if window else 0 for n in lens]
     live = {
-        int(tables[r, j])
-        for r in range(b) for j in range(-(-int(lens[r]) // bs))
+        int(tables[r, j]) for r in range(b) for j in range(first[r], end[r])
     }
+    if window:
+        # The ring keeps a row's newest blocks, block j at place j % places.
+        places = (window + t - 2) // bs + 2
+        ring = np.zeros((b, places), np.int32)
+        for r in range(b):
+            for j in range(max(end[r] - places, 0), end[r]):
+                ring[r, j % places] = tables[r, j]
+        tables = ring
+    tables, lens = jnp.asarray(tables), jnp.asarray(lens, jnp.int32)
     dead = np.asarray([i for i in range(leaves[0].shape[1]) if i not in live])
 
     def poisoned(x):
         bad = 127 if jnp.issubdtype(x.dtype, jnp.integer) else jnp.nan
         return x.at[:, dead].set(bad)
 
-    def run(fn, xs):
+    def run(fn, xs, tables=tables):
         kw = {} if sc is None else dict(k_scale=xs[2], v_scale=xs[3])
         return np.asarray(
-            fn(q, xs[0], xs[1], lens, tables, LAYER, **kw), np.float32
+            fn(q, xs[0], xs[1], lens, tables, LAYER, window=window, **kw),
+            np.float32,
         )
 
     kernel = functools.partial(
         da.paged_verify_attention, impl="flash", interpret=True
     )
-    ref = run(functools.partial(da.paged_verify_attention, impl="dense"),
-              leaves)
+    dense = functools.partial(da.paged_verify_attention, impl="dense")
+    ref = run(dense, leaves)
+    np.testing.assert_allclose(
+        ref, _plain_paged_attention(q, *plain_kv, lens, window),
+        atol=2e-2, rtol=2e-2,
+    )
     clean = run(kernel, leaves)
     np.testing.assert_allclose(clean, ref, atol=2e-2, rtol=2e-2)
     assert not ref[0].any() and not clean[0].any(), "a dead row reads zeros"
     dirty = run(kernel, [poisoned(x) for x in leaves])
     np.testing.assert_array_equal(dirty, clean)
+    if window:
+        for fn in (kernel, dense):
+            with pytest.raises(ValueError, match="a ring of 3 blocks"):
+                run(fn, leaves, tables[:, :3])
 
 
 @pytest.mark.fast

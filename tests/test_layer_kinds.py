@@ -153,18 +153,27 @@ def test_the_tolerance_fails_bf16():
 
 @pytest.mark.parametrize("route", ["dense", "kernel"])
 def test_prefill_then_decode_through_both_pools_matches_the_reference(
-    fp32, route
+    fp32, route, monkeypatch
 ):
     """Three rows ragged in one batch: a context that stays under the window
     (3 -> 13 of 16), one that crosses it during decode (10 -> 20) and one
     several windows long (50 -> 60). Each row's prompt is prefilled (the
     contiguous cache) and spliced into the pools — every block into the full
     kind's, the last window into the sliding kind's ring — and then every
-    decode step's logits, through the paged kernels' dense route and through
-    the Pallas kernels themselves (interpreted), are compared with the
-    reference's full forward at that position."""
-    model, params, sizes = fp32
+    decode step's logits, through the paged kernel's dense route and through
+    the Pallas kernels themselves (interpreted; heads of 128, because query
+    heads that share a KV head's lanes come out of the kernel in whole lane
+    slices, and any other head size takes the dense route), are compared
+    with the reference's full forward at that position."""
+    model, params, sizes = fp32 if route == "dense" else build(head_dim=128)
     cfg = model.config
+    kernel_calls, kernel = [], da._flash_paged_verify
+
+    def counted(*args, **kw):
+        kernel_calls.append(kw["name"])
+        return kernel(*args, **kw)
+
+    monkeypatch.setattr(da, "_flash_paged_verify", counted)
     prompts, steps = [3, 10, 50], 10
     rng = np.random.default_rng(5)
     rows = [rng.integers(0, VOCAB, size=n + steps) for n in prompts]
@@ -218,6 +227,9 @@ def test_prefill_then_decode_through_both_pools_matches_the_reference(
             assert int(cache["moe_stats"][1]) == 3 * 2 * 4
     finally:
         da.FORCE_INTERPRET, ge.FORCE_INTERPRET = was
+    assert sorted(kernel_calls) == (
+        ["attn_mixed_decode_full"] * 2 + ["attn_mixed_decode_sliding"] * 3
+        if route == "kernel" else [])
 
 
 def _served(eng, requests):
