@@ -44,6 +44,20 @@ of any length mix freely inside those shapes:
   exactly once, and prefill work scales with UNIQUE prefixes, not
   requests.
 
+- **One step of lookahead**: a plain decode step is enqueued BEFORE the
+  tokens of the step before it are fetched. The last sampled tokens are
+  a device value handed from one call of the decode program to the next
+  (an admission's first token is scattered into it on the device), so
+  nothing a step needs waits on the host, and the device computes step
+  n+1 while the host fetches, emits and books step n. The host books a
+  step in two halves: what is known at dispatch (rows live, lengths,
+  death by length, blocks, tables) then, what only the tokens say (the
+  token, eos, deadline) at the fetch. At most ONE step is ahead, and
+  whatever reads or rewrites slot state outside ``step`` first lands it
+  (``_drain_inflight``). A speculating engine proposes from host tokens
+  and does not look ahead; nor, YET, does one whose pools are kept by
+  layer kind (``_looks_ahead``).
+
 Everything here is host logic around jitted pure functions; under a live
 mesh (captured at construction) the same loop serves model-sharded caches
 — the jitted programs trace under ``mesh_context`` so the decode
@@ -319,6 +333,20 @@ def _hbm_gib() -> dict[str, float]:
     }
 
 
+@dataclasses.dataclass
+class _StepAhead:
+    """A decode step that is on the device while its tokens are not yet on
+    the host: what was known when it was enqueued, kept until the `decode`
+    span that fetches it. ``rows`` remembers slot -> request, so that a
+    token is handed only to the request it was computed for (a row that
+    ended by eos, deadline or cancel while this step was in flight has run
+    one step too many: its token here is dropped)."""
+
+    out: Any  # device: the tokens, the expert layers' counts behind them
+    rows: list[tuple[int, ServeRequest]]
+    attrs: dict[str, Any]  # what its `decode` span says, counted at dispatch
+
+
 class _Phase:
     """``ServingEngine._span`` under a tracer that does not tee into the
     engine's timeline: the tracer's scoped span, plus the bare timeline
@@ -531,6 +559,16 @@ class ServingEngine:
             raise ValueError(
                 f"speculate={self.spec_mode!r} unknown (off | ngram | draft)"
             )
+        # One step of lookahead is what a plain decode step does. A
+        # speculating engine proposes from the host's tokens, so it lands
+        # every step before the next. Over pools kept by layer kind the
+        # engine lands every step too, FOR NOW: the path is the same and
+        # was measured (PERF.md section 6, PR 34), but the benchmark's
+        # traced reduction grows with the square of the step rate and does
+        # not end inside its time limit at the rate lookahead gives the
+        # one cell that serves such a model (PERF.md section 7: the two
+        # files and the edits; then this condition goes).
+        self._looks_ahead = self.spec_mode == "off" and not self.mixed
         self.spec_k = int(speculate_k)
         self.spec_ngram_max = int(speculate_ngram_max)
         self.spec_window = int(speculate_window)
@@ -617,14 +655,26 @@ class ServingEngine:
         self._req: list[ServeRequest | None] = [None] * self.num_slots
         self._tokens: list[list[int]] = [[] for _ in range(self.num_slots)]
         self._len = np.zeros(self.num_slots, np.int64)  # prompt+generated
+        # A slot is `_active` from admission until its request's last token
+        # is on the host, and `_decoding` while the NEXT step to be enqueued
+        # holds its row: a row whose last step (by length) is in flight is
+        # still active and no longer decoding.
         self._active = np.zeros(self.num_slots, bool)
+        self._decoding = np.zeros(self.num_slots, bool)
         self._latency: list[list[float]] = [[] for _ in range(self.num_slots)]
         # Token ARRIVAL times per slot (submit-relative) — the gap record
         # behind Completion.token_times_s (ISSUE 12).
         self._tok_times: list[list[float]] = [
             [] for _ in range(self.num_slots)
         ]
+        # The last sampled token of each row: `_tok_dev` is the device
+        # value the decode program reads and hands on (None: the host
+        # mirror `_last_tok`, written as tokens are emitted, is the truth
+        # and is uploaded at the next dispatch), `_inflight` the one step
+        # enqueued whose tokens are not fetched yet.
         self._last_tok = np.zeros(self.num_slots, np.int32)
+        self._tok_dev: Any = None
+        self._inflight: _StepAhead | None = None
 
         self.cache: Any = None
         self.bucket = 0
@@ -638,6 +688,7 @@ class ServingEngine:
         # seeds keyed on (cache bucket, shared blocks), block grafts
         # keyed on (cache bucket, private blocks written).
         self._paged_decode_jit: Any = None
+        self._place_token_jit: Any = None
         self._prefill_seeded_jit: dict[tuple[int, int], Any] = {}
         self._seed_jit: dict[tuple[int, int], Any] = {}
         self._paged_graft_jit: dict[tuple[int, int], Any] = {}
@@ -708,6 +759,17 @@ class ServingEngine:
         self._m_prefills = t.counter("serve_prefill_total", help="prefills run")
         self._m_decodes = t.counter(
             "serve_decode_steps_total", help="slot-array decode iterations"
+        )
+        self._m_ahead = t.counter(
+            "serve_decode_ahead_total",
+            help="decode steps enqueued before the step before them was "
+            "fetched (one step of lookahead; the rest began at an engine "
+            "that stood empty or had just been drained)",
+        )
+        self._m_wasted_rows = t.counter(
+            "serve_decode_wasted_rows_total",
+            help="rows of a step in flight computed for a request that had "
+            "already ended (eos, deadline, cancel): the token is dropped",
         )
         self._m_grows = t.counter(
             "serve_bucket_grow_total", help="cache bucket growths"
@@ -1051,6 +1113,7 @@ class ServingEngine:
         (tools/serve_bench.py): the bucket trajectory replays instead of
         starting at the warm pass's terminal bucket. Refuses while
         requests are in flight."""
+        self._drain_inflight()
         if self._active.any():
             raise RuntimeError("reset_cache with active slots in flight")
         self.cache = None
@@ -1104,7 +1167,10 @@ class ServingEngine:
         return cache_bytes_per_slot(self.cache, self.num_slots)
 
     def close(self) -> None:
-        """Stop the watchdog thread (daemon — leak-safe either way)."""
+        """Land the step in flight (its completions come back from the
+        next ``step()`` / ``run()``), then stop the watchdog thread
+        (daemon — leak-safe either way)."""
+        self._drain_inflight()
         self.watchdog.stop()
 
     def export_trace(self, path: str) -> None:
@@ -1125,7 +1191,9 @@ class ServingEngine:
             if max_steps is not None and steps >= max_steps:
                 break
         # Requests resolved without ever entering a slot (e.g. every
-        # submit shed on a full queue) never pass through step().
+        # submit shed on a full queue) never pass through step(), nor do
+        # those a drain outside it resolved (close, park_slot).
+        out.extend(self._drain_completed())
         out.extend(self._early)
         self._early.clear()
         return out
@@ -1136,6 +1204,7 @@ class ServingEngine:
         """The decode program at the engine's current cache shape, lowered
         — for callers that inspect the program (chip_smoke.py checks that
         the decode kernel is in it)."""
+        self._drain_inflight()
         fn = (
             self._paged_decode_fn() if self.paged
             else self._decode_fn(self.bucket)
@@ -1167,8 +1236,15 @@ class ServingEngine:
             kw = dict(self._sample_kw)
 
             def serve_decode(params, cache, tok, rng):
+                # The key is split in here and handed back: a dispatch
+                # costs the host one call, and uploads nothing.
+                rng, sub = jax.random.split(rng)
                 logits, cache = _decode_step(m, params, cache, tok)
-                return _sample(logits, rng, **kw), cache
+                nxt = _sample(logits, sub, **kw)
+                # The tokens twice: what the next call reads on the
+                # device, and what the host fetches (the paged program
+                # puts its expert layers' counts behind the second).
+                return nxt, nxt, cache, rng
 
             # Donate the cache (the PR 5 graft-lint audit's find): the
             # engine immediately rebinds self.cache to the step's output,
@@ -1264,14 +1340,16 @@ class ServingEngine:
             kw = dict(self._sample_kw)
 
             def serve_paged_decode(params, cache, tok, rng):
+                rng, sub = jax.random.split(rng)  # as in serve_decode
                 logits, cache = _decode_step(m, params, cache, tok)
-                nxt = _sample(logits, rng, **kw)
+                nxt = out = _sample(logits, sub, **kw)
                 if self.mixed:
                     # The expert layers' counts (experts touched, pairs)
                     # come back behind the tokens, in the same array: one
-                    # fetch a step, not two.
-                    nxt = jnp.concatenate([nxt, cache["moe_stats"]])
-                return nxt, cache
+                    # fetch a step, not two. The tokens alone stay on the
+                    # device for the next call.
+                    out = jnp.concatenate([nxt, cache["moe_stats"]])
+                return nxt, out, cache, rng
 
             # Donate the cache (pool included) — the same two-caches-live
             # audit fix as _decode_fn, now sized at the POOL.
@@ -1279,6 +1357,18 @@ class ServingEngine:
                 serve_paged_decode, donate_argnums=(1,)
             )
         return self._paged_decode_jit
+
+    def _place_token_fn(self):
+        """An admitted request's first token into the device's last
+        tokens at its slot: the prefill's token is a device array before
+        the host fetches it, so the step ahead reads it without a host
+        upload that orders after a fetch."""
+        if self._place_token_jit is None:
+            def serve_place_token(toks, tok, slot):
+                return toks.at[slot].set(tok[0])
+
+            self._place_token_jit = jax.jit(serve_place_token)
+        return self._place_token_jit
 
     def _prefill_seeded_fn(self, s_p: int, s_c: int):
         """Suffix prefill for shared-prefix admissions: the prompt SUFFIX
@@ -2074,6 +2164,14 @@ class ServingEngine:
                             slot, req, res, slot_cache, s_p, s_c, m
                         )
                         graft.set(bucket=self.bucket)
+                    if self._looks_ahead:
+                        # The first token goes to the device's last tokens
+                        # before the host has it: enqueued behind the step
+                        # in flight, in front of the step that reads it.
+                        self._tok_dev = self._call(
+                            "place_token", None, self._place_token_fn(),
+                            self._last_tokens(), tok, jnp.int32(slot),
+                        )
                 tok = int(jax.device_get(tok)[0])
             dt = time.perf_counter() - t0
         except Exception as e:
@@ -2162,7 +2260,7 @@ class ServingEngine:
         self._req[slot] = req
         self._tokens[slot] = [tok]
         self._len[slot] = l + 1
-        self._active[slot] = True
+        self._active[slot] = self._decoding[slot] = True
         self._latency[slot] = [dt]
         self._tok_times[slot] = [time.perf_counter() - req.t_submit]
         self._last_tok[slot] = tok
@@ -2192,6 +2290,7 @@ class ServingEngine:
         output and the table/slot bookkeeping runs after it."""
         self._refuse_for_layer_kinds("the disaggregated engine (admit_handoff)")
         assert self.paged, "handoff admission is a paged-engine contract"
+        self._drain_inflight()
         assert not self._active[slot], f"slot {slot} is occupied"
         l = int(req.prompt.size)
         bs = self.block_size
@@ -2237,6 +2336,10 @@ class ServingEngine:
         opaque parked state ``resume_parked`` restores."""
         self._refuse_for_layer_kinds("park / resume (park_slot)")
         assert self.paged, "parking is a paged-engine contract"
+        # The step in flight lands first: the parked tokens are whole. (A
+        # caller that picks its victim from slot state drains before it
+        # looks, or the victim may have finished by now.)
+        self._drain_inflight()
         assert self._active[slot], f"slot {slot} has nothing to park"
         parked = {
             "req": self._req[slot],
@@ -2256,7 +2359,7 @@ class ServingEngine:
             ),
         }
         self._req[slot] = None
-        self._active[slot] = False
+        self._active[slot] = self._decoding[slot] = False
         self._tokens[slot] = []
         self._latency[slot] = []
         self._tok_times[slot] = []
@@ -2284,6 +2387,7 @@ class ServingEngine:
         request then continues decoding from its parked ``last_tok``,
         token-identically — nothing about its K/V ever moved."""
         self._refuse_for_layer_kinds("park / resume (resume_parked)")
+        self._drain_inflight()
         assert self.paged and not self._active[slot]
         req = parked["req"]
         self._req[slot] = req
@@ -2299,7 +2403,7 @@ class ServingEngine:
         (self._slot_spec_degraded[slot], self._slot_spec_proposed[slot],
          self._slot_spec_accepted[slot]) = parked["spec"]
         self._parked_held.pop(req.id, None)
-        self._active[slot] = True
+        self._active[slot] = self._decoding[slot] = True
         self._tables[slot, :] = 0
         self._tables[slot, : len(parked["blocks"])] = parked["blocks"]
         self._tables_dirty = True
@@ -2392,6 +2496,7 @@ class ServingEngine:
         was constructed with, so callers sharing that exact tree with
         another consumer must re-place their copy first."""
         self._refuse_for_layer_kinds("the live re-spread (respread_pool)")
+        self._drain_inflight()
         if not self.paged:
             raise ValueError(
                 "respread_pool is a paged-engine contract "
@@ -2487,13 +2592,20 @@ class ServingEngine:
             raise
         # Programs traced under the old mesh are unusable (and would
         # silently recompute on stale shardings): drop every jit cache;
-        # they rebuild lazily under the new mesh context.
+        # they rebuild lazily under the new mesh context. The key is an
+        # output of the decode program, so it sits on the old mesh's
+        # devices: it comes over the host, committed to none.
+        self._rng = jax.random.wrap_key_data(
+            np.asarray(jax.random.key_data(self._rng)),
+            impl=jax.random.key_impl(self._rng),
+        )
         self._env = new_env
         self._prefill_jit.clear()
         self._decode_jit.clear()
         self._graft_jit.clear()
         self._grow_jit.clear()
         self._paged_decode_jit = None
+        self._place_token_jit = None
         self._prefill_seeded_jit.clear()
         self._seed_jit.clear()
         self._paged_graft_jit.clear()
@@ -2558,7 +2670,7 @@ class ServingEngine:
         )
         self._completed.append(comp)
         self._req[slot] = None
-        self._active[slot] = False
+        self._active[slot] = self._decoding[slot] = False
         if self.paged:
             # Release the slot's block references (prefix-cache entries
             # keep shared chains alive past retirement — that is the
@@ -2603,11 +2715,19 @@ class ServingEngine:
         this step (possibly at admission, for 1-token budgets; typed
         shed/deadline/error resolutions ride along).
 
+        One iteration fetches one step's tokens and, in front of that,
+        enqueues the step AHEAD: the engine keeps one plain decode step
+        in flight on the device while the host fetches, emits and books
+        the step before it (the module docstring's lookahead).
+
         On the record: one engine-lane `step` span per call, whose
         children (`admit`, `append_blocks` / `propose`, `decode` or
         `verify` with `dispatch` and `fetch` inside, `emit_tokens`) leave
         only the few lines between them uncovered — `step`'s self time
-        is host time that no phase owns."""
+        is host time that no phase owns. A `decode` span's attributes
+        describe the step whose tokens it FETCHES; its `dispatch` child
+        is the enqueueing of the step ahead (and, after an engine that
+        stood empty, of the fetched step itself: `ahead` 0)."""
         with self._span("step", trace=self._engine_trace) as span:
             self._step_span = span
             try:
@@ -2615,6 +2735,20 @@ class ServingEngine:
             finally:
                 self._step_span = None
             return self._drain_completed()
+
+    def _drain_inflight(self) -> None:
+        """THE rule for whatever reads or rewrites slot state outside
+        ``_step``'s own loop (a verify step's proposals, park / resume,
+        the live re-spread, a handoff admission, reset, close, lowering
+        the decode step): the step in flight lands first — fetched,
+        emitted, booked — so that the host's tokens, lengths and tables
+        are whole, and the host's last tokens rule again (the next
+        dispatch uploads them). Completions it resolves come back from
+        the next ``step()`` or ``run()``."""
+        if self._inflight is not None:
+            cur, self._inflight = self._inflight, None
+            self._decode(cur, look_ahead=False)
+        self._tok_dev = None
 
     def _step(self) -> None:
         depth = len(self._queue)
@@ -2635,110 +2769,193 @@ class ServingEngine:
         self._early.clear()
         self._m_occupancy.set(float(self._active.sum()) / self.num_slots)
         if not self._active.any():
+            # (A step in flight whose rows all ended is never fetched.)
+            self._drop_wasted()
             return
 
         # Speculative proposal round (ISSUE 11): drafts per slot for
         # this step's verify tile — BEFORE the block-append loop, which
-        # must cover each row's draft write positions too.
+        # must cover each row's draft write positions too. An engine
+        # that does not look ahead (``_looks_ahead``) starts every step
+        # from the host's tokens.
+        look_ahead = self._looks_ahead
         drafts: dict[int, np.ndarray] = {}
-        if self.paged and self.spec_mode != "off":
+        if not look_ahead:
+            self._drain_inflight()
+        if self.spec_mode != "off":
             with self._span("propose") as span:
                 drafts = self._propose()
                 span.set(slots=len(drafts))
 
+        # The dispatch half of the booking, for the step this call
+        # enqueues: the step ahead of the one in flight, or the next one
+        # where nothing is.
         if self.paged:
             with self._span("append_blocks") as span:
                 span.set(appended=self._append_blocks(drafts))
-            if not self._active.any():
-                return
-            if drafts:
-                # At least one slot speculates: the whole batch rides
-                # the ONE verify program (slots without drafts
-                # single-step inside it — the mixed-batch contract).
-                self._spec_verify(drafts)
-                return
         else:
-            # Bucket must hold every active row's next write position: an
-            # active row holds cache_index == _len - 1 (prefill sets idx=l
-            # with _len=l+1; both advance together), so this step writes
-            # position _len - 1 and needs capacity exactly _len.
-            try:
-                self._ensure_bucket(int(self._len[self._active].max()))
-            except CacheGrowError as e:
-                # Degrade, don't die: rows that NEED the larger bucket are
-                # retired typed ("error", carrying their tokens so far);
-                # rows still inside the current bucket keep decoding — a
-                # capacity failure at high occupancy costs the big
-                # requests, never the whole batch.
-                from frl_distributed_ml_scaffold_tpu.utils.logging import (
-                    get_logger,
-                )
+            self._fit_bucket()
+        if drafts:
+            # At least one slot speculates: the whole batch rides the ONE
+            # verify program (slots without drafts single-step inside it
+            # — the mixed-batch contract).
+            if self._active.any():
+                self._spec_verify(drafts)
+            return
+        self._drop_wasted()
+        if self._inflight is None and not self._decoding.any():
+            return
+        cur, self._inflight = self._inflight, None
+        self._decode(cur, look_ahead=look_ahead)
 
-                victims = [
-                    s for s in np.flatnonzero(self._active)
-                    if self._len[s] > self.bucket
-                ]
-                get_logger().warning(
-                    "serving: cache grow failed (%s); retiring %d slot(s) "
-                    "needing the larger bucket, %d keep decoding",
-                    e, len(victims), int(self._active.sum()) - len(victims),
-                )
-                for s in victims:
-                    self._retire(int(s), "error")
-                if not self._active.any():
-                    return
+    def _last_tokens(self):
+        """The last sampled token of each row, on the device: the value
+        the decode program handed on, else the host's mirror. (A COPY of
+        the mirror, like every upload of an array the host writes again:
+        with a step in flight the host no longer waits for the program
+        that reads the upload, and on the CPU an upload may alias.)"""
+        if self._tok_dev is not None:
+            return self._tok_dev
+        return jnp.asarray(self._last_tok.copy())
 
-        n_active = int(self._active.sum())
-        t0 = time.perf_counter()
-        # One engine-lane span per slot-array decode program, from before
-        # its dispatch until its tokens are on the host...
-        with self._span(
-            "decode", bucket=self.bucket, active=n_active,
-            **self._kind_attrs(),
-        ) as decode_span:
-            # `dispatch` returns when the program is enqueued; `fetch` is
-            # where the host waits for the device.
-            with self._span("dispatch"), self._trace_ctx():
-                self._rng, sub = jax.random.split(self._rng)
+    def _owed(self, step: _StepAhead) -> list[tuple[int, ServeRequest]]:
+        """The rows of ``step`` whose request still holds the slot: those
+        its tokens go to."""
+        return [(s, r) for s, r in step.rows if self._req[s] is r]
+
+    def _drop_wasted(self) -> None:
+        """A step in flight none of whose rows is still owed its token
+        (each ended by eos, deadline or cancel at the step before) is not
+        fetched: nothing waits for it, and the device's order keeps what
+        is enqueued behind it whole."""
+        cur = self._inflight
+        if cur is not None and not self._owed(cur):
+            self._inflight = None
+            self._count_wasted(len(cur.rows))
+
+    def _count_wasted(self, rows: int) -> None:
+        if rows:
+            self.stats["decode_wasted_rows"] += rows
+            self._m_wasted_rows.inc(rows)
+
+    def _fit_bucket(self) -> None:
+        """The bucketed cache's dispatch-time booking: the bucket must
+        hold every decoding row's next write position. A decoding row
+        holds cache_index == _len - 1 (prefill sets idx=l with _len=l+1;
+        both advance together as a step is enqueued), so the next step
+        writes position _len - 1 and needs capacity exactly _len."""
+        if not self._decoding.any():
+            return
+        try:
+            self._ensure_bucket(int(self._len[self._decoding].max()))
+        except CacheGrowError as e:
+            # Degrade, don't die: rows that NEED the larger bucket are
+            # retired typed ("error", carrying the tokens the host has:
+            # one in flight is dropped); rows still inside the current
+            # bucket keep decoding — a capacity failure at high occupancy
+            # costs the big requests, never the whole batch.
+            from frl_distributed_ml_scaffold_tpu.utils.logging import (
+                get_logger,
+            )
+
+            victims = [
+                s for s in np.flatnonzero(self._decoding)
+                if self._len[s] > self.bucket
+            ]
+            get_logger().warning(
+                "serving: cache grow failed (%s); retiring %d slot(s) "
+                "needing the larger bucket, %d keep decoding",
+                e, len(victims), int(self._active.sum()) - len(victims),
+            )
+            for s in victims:
+                self._retire(int(s), "error")
+
+    def _enqueue(self, ahead: int) -> _StepAhead:
+        """Enqueue one decode program over the rows that are decoding and
+        book what its dispatch already tells (``step``'s `dispatch`
+        span): the lengths advance, and a row whose budget this step
+        fills is in no later step. Nothing here waits on a fetch: the
+        tokens come from the device, the key is split in the program."""
+        rows = [(int(s), self._req[s]) for s in np.flatnonzero(self._decoding)]
+        attrs = {"bucket": self.bucket, **self._kind_attrs(), "ahead": ahead}
+        if self.paged:
+            program, key, fn = "paged_decode", None, self._paged_decode_fn()
+        else:
+            program, key, fn = (
+                "decode", self.bucket, self._decode_fn(self.bucket))
+        self._tok_dev, out, self.cache, self._rng = self._call(
+            program, key, fn,
+            self.params,
+            self.cache,
+            self._last_tokens(),
+            self._rng,
+        )
+        out.copy_to_host_async()
+        for slot, req in rows:
+            self._len[slot] += 1
+            if self._len[slot] - req.prompt.size >= req.max_new_tokens:
+                # Death by length is a count: the row is not in the step
+                # after its last. It stays active, and owns its blocks,
+                # until that last token is on the host.
+                self._decoding[slot] = False
                 if self.paged:
-                    program, key, fn = (
-                        "paged_decode", None, self._paged_decode_fn()
-                    )
-                else:
-                    program, key, fn = (
-                        "decode", self.bucket, self._decode_fn(self.bucket)
-                    )
-                nxt, self.cache = self._call(
-                    program, key, fn,
-                    self.params,
-                    self.cache,
-                    jnp.asarray(self._last_tok),
-                    sub,
-                )
+                    self._tables[slot, :] = 0
+                    if self.window:
+                        self._wtables[slot, :] = 0
+                    self._tables_dirty = True
+        return _StepAhead(out, rows, attrs)
+
+    def _decode(self, cur: _StepAhead | None, *, look_ahead: bool) -> None:
+        """One `decode` span and its `emit_tokens`: `dispatch` enqueues
+        what is not on the device yet — ``cur`` itself where nothing was
+        in flight (the engine stood empty, or was drained), then the step
+        ahead — and `fetch` waits for ``cur``'s tokens, which are then
+        emitted to the requests they were computed for."""
+        lane = {"trace": self._engine_trace, "parent": self._step_span}
+        t0 = time.perf_counter()
+        # One engine-lane span per slot-array decode program FETCHED...
+        with self._span("decode", **lane) as decode_span:
+            # `dispatch` returns when the programs are enqueued; `fetch`
+            # is where the host waits for the device.
+            with self._span("dispatch"), self._trace_ctx():
+                if cur is None:
+                    cur = self._enqueue(ahead=0)
+                    if look_ahead:  # the step ahead's half of the booking
+                        (self._append_blocks({}) if self.paged
+                         else self._fit_bucket())
+                if look_ahead and self._decoding.any():
+                    self._inflight = self._enqueue(ahead=1)
             with self._span("fetch"):
-                nxt = np.asarray(jax.device_get(nxt))
+                out = np.asarray(jax.device_get(cur.out))
+            rows = self._owed(cur)
+            decode_span.set(active=len(rows), **cur.attrs)
             if self.mixed:
-                nxt, (touched, pairs) = nxt[:-2], nxt[-2:]
+                out, (touched, pairs) = out[:-2], out[-2:]
                 decode_span.set(
                     experts_touched=int(touched), expert_pairs=int(pairs))
         dt = time.perf_counter() - t0
-        with self._span("emit_tokens"):
-            self._emit_decoded(nxt, n_active, t0, dt)
+        self._count_wasted(len(cur.rows) - len(rows))
+        if cur.attrs["ahead"]:
+            self.stats["decode_ahead"] += 1
+            self._m_ahead.inc()
+        with self._span("emit_tokens", **lane):
+            self._emit_decoded(cur.attrs["bucket"], rows, out, t0, dt)
 
     def _append_blocks(self, drafts: dict[int, np.ndarray]) -> int:
-        """Paged growth (``step``'s `append_blocks` span): a row crossing
-        a block boundary APPENDS one reserved block to its table — a
-        host-side int write plus a table push, never a device-side cache
-        clone. The reservation made at admission guarantees a free
-        block, so the only failure left is the injected serve.grow fault
-        (kept on the same degrade-per-row contract as bucketed growth:
-        the crossing row retires typed, the batch lives). A speculating
-        row additionally covers its draft write positions (idx .. idx +
+        """Paged growth, the dispatch half of a step's booking (``step``'s
+        `append_blocks` span): a decoding row crossing a block boundary
+        APPENDS one reserved block to its table — a host-side int write
+        plus a table push, never a device-side cache clone. The
+        reservation made at admission guarantees a free block, so the
+        only failure left is the injected serve.grow fault (kept on the
+        same degrade-per-row contract as bucketed growth: the crossing
+        row retires typed, the batch lives). A speculating row
+        additionally covers its draft write positions (idx .. idx +
         n_drafts — within the worst-case reservation because drafts are
         capped at budget - 1); rejected drafts hand their tail blocks
         back after the verify step. Returns the blocks appended."""
         appended = 0
-        for slot in np.flatnonzero(self._active):
+        for slot in np.flatnonzero(self._decoding):
             extra = len(drafts.get(int(slot), ()))
             need = (
                 int(self._len[slot]) - 1 + extra
@@ -2784,11 +3001,11 @@ class ServingEngine:
         if self.window:
             appended += self._slide_windows()
         self._set_pool_gauges()
-        if self._tables_dirty and self._active.any():
+        if self._tables_dirty and self._decoding.any():
             self.cache = {
                 **self.cache,
-                "block_tables": jnp.asarray(self._tables),
-                **({"block_tables_sliding": jnp.asarray(self._wtables)}
+                "block_tables": jnp.asarray(self._tables.copy()),
+                **({"block_tables_sliding": jnp.asarray(self._wtables.copy())}
                    if self.window else {}),
             }
             self._tables_dirty = False
@@ -2804,7 +3021,7 @@ class ServingEngine:
         1`` sliding blocks, whatever its context. Returns the blocks
         appended."""
         appended = 0
-        for slot in np.flatnonzero(self._active):
+        for slot in np.flatnonzero(self._decoding):
             p = int(self._len[slot]) - 1  # this step's write position
             held = self._wslot_blocks[slot]
             first = self._window_first_block(p)
@@ -2843,7 +3060,7 @@ class ServingEngine:
         them that is walked is this over that)."""
         if not self.paged:
             return {}
-        attended = self._len[self._active]  # p + 1 with p = len - 1
+        attended = self._len[self._decoding]  # p + 1 with p = len - 1
         if not self.mixed:
             return {"kv_blocks_live": int(
                 (-(-attended // self.block_size)).sum())}
@@ -2856,31 +3073,26 @@ class ServingEngine:
             )
         return out
 
-    def _emit_decoded(self, nxt, n_active: int, t0: float, dt: float) -> None:
-        """The host half of a decode step (``step``'s `emit_tokens`
-        span): counters, then per live row the token's bookkeeping, its
-        `decode_tick`, and whatever retires it."""
-        self.stats[
-            "decode_paged" if self.paged else f"decode_{self.bucket}"
-        ] += 1
+    def _emit_decoded(self, bucket: int, rows, nxt, t0: float, dt: float) -> None:
+        """The fetch half of a decode step's booking (``step``'s
+        `emit_tokens` span): counters, then per row that is still owed
+        its token the token's bookkeeping, its `decode_tick`, and
+        whatever retires it."""
+        self.stats["decode_paged" if self.paged else f"decode_{bucket}"] += 1
         self.stats["decode_steps"] += 1
         # Slot-level invocation accounting (ISSUE 11): a plain step is
-        # one invocation per active slot, emitting one token each — the
+        # one invocation per live slot, emitting one token each — the
         # denominator serve_bench's decode-invocations-per-token column
         # (and the speculative reduction ratio) reads from.
-        self.stats["slot_steps"] += n_active
-        self.stats["step_tokens"] += n_active
+        self.stats["slot_steps"] += len(rows)
+        self.stats["step_tokens"] += len(rows)
         self._m_decodes.inc()
         self.watchdog.beat()
         self._sample_hbm()
 
-        for slot in range(self.num_slots):
-            if not self._active[slot]:
-                continue
-            req = self._req[slot]
+        for slot, req in rows:
             tok = int(nxt[slot])
             self._tokens[slot].append(tok)
-            self._len[slot] += 1
             self._latency[slot].append(dt)
             self._tok_times[slot].append(
                 time.perf_counter() - req.t_submit

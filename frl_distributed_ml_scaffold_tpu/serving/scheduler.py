@@ -914,6 +914,11 @@ class DisaggServingEngine:
                 continue
             slot = self._free_slot()
             if slot is None and pkg.spec.slo_class == "latency":
+                # The victim is picked from slot state: the decode step
+                # in flight lands first (it may free a slot by itself).
+                self.decode._drain_inflight()
+                slot = self._free_slot()
+            if slot is None and pkg.spec.slo_class == "latency":
                 victims = self._preemptible_slots()
                 if victims:
                     slot = victims[0]

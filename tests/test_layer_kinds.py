@@ -358,6 +358,31 @@ def test_spans_and_gauges_of_the_two_kinds(fp32):
     assert eng.stats["window_blocks_released"] >= 1
 
 
+def test_pools_by_layer_kind_land_every_step_for_now(fp32):
+    """Over pools kept by layer kind the engine does not look ahead yet
+    (``ServingEngine._looks_ahead``; PERF.md section 7 says what waits for
+    it): every step starts from the host's tokens, so no `decode` span is
+    `ahead`, nothing is ever in flight between two steps and no row is
+    computed in vain."""
+    model, params, _ = fp32
+    registry, tracer = MetricsRegistry(), Tracer(capacity=100_000)
+    eng = ServingEngine(model, params, num_slots=2, kv_block_size=BLOCK,
+                        temperature=0.0, telemetry=registry, tracer=tracer)
+    assert not eng._looks_ahead
+    eng.submit(np.arange(30) % VOCAB, 9)
+    eng.submit(np.arange(7) % VOCAB, 6)
+    while eng.pending:
+        eng.step()
+        assert eng._inflight is None
+    decodes = [s for s in tracer.spans() if s["name"] == "decode"]
+    assert len(decodes) == eng.stats["decode_steps"] == 8
+    assert [s["ahead"] for s in decodes] == [0] * 8
+    assert sum(s["active"] for s in decodes) == 8 + 5
+    snap = registry.snapshot()
+    assert snap.get("serve_decode_ahead_total", 0) == 0
+    assert snap.get("serve_decode_wasted_rows_total", 0) == 0
+
+
 # ------------------------------------------------------------ expert layer
 
 

@@ -498,8 +498,9 @@ def test_paged_engine_tells_the_kernel_which_rows_are_dead(gpt, monkeypatch):
     row's cursor runs on, a never-used row's too — so the model reads
     death from the table it holds (a row whose table starts at the trash
     block 0) and hands the paged kernel length 0 for it: what reaches
-    ``paged_verify_attention`` is the host's ``_len`` for an active slot
-    and 0 for every other, in every step. The `decode` span's
+    ``paged_verify_attention`` is the host's ``_len`` for a slot the step
+    holds (``_decoding``: a row whose last step is in flight is in no
+    later one) and 0 for every other, in every step. The `decode` span's
     ``kv_blocks_live`` is the host's own count of the blocks under those
     lengths."""
     import importlib
@@ -532,7 +533,7 @@ def test_paged_engine_tells_the_kernel_which_rows_are_dead(gpt, monkeypatch):
     def recording(program, key, fn, *args):
         if program == "paged_decode":
             told.append(tuple(
-                int(n) if a else 0 for n, a in zip(eng._len, eng._active)
+                int(n) if a else 0 for n, a in zip(eng._len, eng._decoding)
             ))
         return call(program, key, fn, *args)
 
@@ -1889,7 +1890,7 @@ def test_disagg_sequential_latency_after_preemption_no_livelock(gpt):
 ENGINE_PROGRAMS = (
     "decode", "prefill", "graft", "grow",
     "paged_decode", "prefill_seeded", "seed", "paged_graft", "init_cache",
-    "verify", "rewind", "draft",
+    "verify", "rewind", "draft", "place_token",
 )
 
 

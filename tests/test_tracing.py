@@ -407,6 +407,18 @@ def test_spans_the_benchmark_reads_keep_their_fields(gpt):
         # Every token after a request's first comes out of one decode span.
         n_ticks = sum(len(c.tokens) - c.prompt_len - 1 for c in done.values())
         assert sum(s["active"] for s in decodes) == n_ticks
+        # A `decode` span describes the step whose tokens it FETCHES (with
+        # one step of lookahead its `dispatch` enqueues another): `active`
+        # is the count of tokens its own `emit_tokens` delivers — the ring
+        # is in order of finishing, so those ticks lie between the two.
+        for i, s in enumerate(spans):
+            if s["name"] == "decode":
+                rest = spans[i + 1:]
+                emit = next(
+                    k for k, r in enumerate(rest) if r["name"] == "emit_tokens")
+                assert s["active"] == sum(
+                    r["name"] == "decode_tick" for r in rest[:emit])
+                assert s["ahead"] in (0, 1)
     finally:
         eng.close()
 
