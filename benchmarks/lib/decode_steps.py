@@ -15,10 +15,17 @@ from __future__ import annotations
 import bisect
 import re
 
-from metrics.decode_program_device_ms import own_xplane
-
 ALIGN_OVER = 8  # steps whose start gaps are compared
 ALIGN_WITHIN_S = 1e-3
+
+
+def program_runs(raw: dict, tr: dict, module: str):
+    """(first device's ops, [(start, end)] of each run inside the traced part
+    of the program whose module name matches `module`)."""
+    dev = next(iter(raw["devices"].values()))
+    named = re.compile(module)
+    return dev["ops"], [(a, b) for name, a, b in dev["modules"]
+                        if named.search(name) and a >= tr["t0"] and b <= tr["t1"]]
 
 
 def _aligned(host, spans):
@@ -33,34 +40,45 @@ def _aligned(host, spans):
     return at if best is not None and best < ALIGN_WITHIN_S else None
 
 
-_XPLANE: dict = {}  # the run's own trace, parsed once for every reader of a run
-
-
-def xplane_of(tr):
-    """`own_xplane(tr)`, kept: a run has one trace, five readers want its
-    modules, and parsing the file takes a minute at this size."""
-    key = tr["t1"]
-    if key not in _XPLANE:
-        _XPLANE.clear()
-        _XPLANE[key] = own_xplane(tr)
-    return _XPLANE[key]
+def pair(runs, host, spans) -> tuple[list, list]:
+    """Each run of the decode program with the `decode` span that FETCHES
+    its tokens: ([span], [run]). `host[k]` (start, end) is `spans[k]` on
+    the profiler's clock. A span whose `ahead` is 0 enqueued its own step:
+    the first run that begins inside it is that step. Any other run was
+    enqueued ahead, in the last span begun before it: the next span fetches
+    it where that span's `ahead` is 1; where it is 0 the step was dropped
+    unfetched (every row had ended), and the run is left out. Where no span
+    is ahead this is the span each run began in, as a step's single run
+    begins inside its own span."""
+    starts = [a for a, _ in host]
+    taken = set()
+    steps, kept = [], []
+    for a, b in runs:
+        k = bisect.bisect_right(starts, a) - 1  # the last span begun before the run
+        if k >= 0 and a <= host[k][1] and not spans[k].get("ahead", 0) and k not in taken:
+            at = k
+        elif k + 1 < len(spans) and spans[k + 1].get("ahead", 0) and k + 1 not in taken:
+            at = k + 1
+        else:
+            continue
+        taken.add(at)
+        steps.append(spans[at])
+        kept.append((a, b))
+    return steps, kept
 
 
 def traced(ctx, spec):
-    """{"steps": [the `decode` span of each run of the decode program inside
-    the traced part], "runs": [(start, end)], "ops": [the device's ops inside
-    those runs]}, or None where the context has no trace, the program no such
-    module or the spans no such counts."""
+    """{"steps": [the `decode` span that fetches each run of the decode
+    program inside the traced part], "runs": [(start, end)], "ops": [the
+    device's ops inside those runs]}, or None where the context has no
+    parsed trace, the program no such module or the spans no such counts."""
     tr = ctx.get("trace")
     if not tr or "t1" not in tr:
         return None
-    raw = xplane_of(tr)
+    raw = tr.get("xplane")
     if raw is None:
         return None
-    dev = next(iter(raw["devices"].values()))
-    named = re.compile(spec["module"])
-    runs = [(a, b) for name, a, b in dev["modules"]
-            if named.search(name) and a >= tr["t0"] and b <= tr["t1"]]
+    ops, runs = program_runs(raw, tr, spec["module"])
     spans = sorted((s for s in ctx.get("spans", ()) if s["name"] == "decode"),
                    key=lambda s: s["t0_s"])
     host = [(a, b) for name, a, b in raw["host"] if name == "decode" and b > tr["t0"]]
@@ -69,16 +87,9 @@ def traced(ctx, spec):
     first = _aligned(host, spans)
     if first is None:
         return None
-    starts = [a for a, _ in host]
-    steps, kept = [], []
-    for a, b in runs:
-        k = bisect.bisect_right(starts, a) - 1  # the host span the run began in
-        if k >= 0 and a <= host[k][1]:
-            steps.append(spans[first + k])
-            kept.append((a, b))
+    steps, kept = pair(runs, host, spans[first:])
     if not steps:
         return None
-    ops = dev["ops"]
     op_starts = [e[1] for e in ops]
     inside = []
     for a, b in kept:

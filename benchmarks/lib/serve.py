@@ -355,7 +355,9 @@ def run(cell: dict, args, dev: dict, t_start: float, peaks: dict | None) -> dict
     }
     device = dict(dev)
     if prof:
+        t_reduce = time.perf_counter()
         ctx["trace"] = prof.reduce(tracer, res)
+        ctx["trace"]["reduce_s"] = time.perf_counter() - t_reduce
         device["busy_s"] = ctx["trace"]["busy_s"]
         device["window_s"] = ctx["trace"]["window_s"]
     device["memory_peak_bytes"] = memory_peak_bytes()
@@ -397,7 +399,6 @@ class Profiled:
         self.dir, self.start_s, self.for_s = log_dir, start_s, for_s
         self.state = "before"
         self.mark_pc = None
-        self.raw = None
 
     def on_time(self, now: float) -> None:
         import jax
@@ -422,7 +423,7 @@ class Profiled:
 
     def reduce(self, tracer, res: dict) -> dict:
         t_zero = res["t_zero"]
-        self.raw = raw = tracelib.read_xplane(tracelib.find_xplane(self.dir))
+        raw = tracelib.read_xplane(tracelib.find_xplane(self.dir))
         marks = [e for e in raw["host"] if e[0] == SYNC_MARK]
         if not marks:
             raise RuntimeError("the trace holds no clock mark")
@@ -433,6 +434,7 @@ class Profiled:
         ops = next(iter(raw["devices"].values()))["ops"]
         t1 = max(e[2] for e in ops)
         out = tracelib.reduce(raw, t0, t1, extra_spans=spans)
+        out["xplane"] = raw  # parsed once: the readers of program runs take it from here
         # K and V positions the decode steps inside the traced part attended
         # over. Token j >= 1 of a request comes from a decode step over its
         # prompt and its j earlier tokens (token 0 comes from the prefill).

@@ -1,6 +1,7 @@
-"""Reduction of a profiler trace to three things: the device's busy union,
-the time of events whose name matches a pattern, and the host span under each
-idle gap. Times are seconds on the trace's own clock.
+"""Reduction of a profiler trace to the device's busy union (over a window,
+or inside each run of a program), the time of events whose name matches a
+pattern, and the host span under each idle gap; each linear in the trace.
+Times are seconds on the trace's own clock.
 
 `read_xplane` turns the profiler's file into plain lists (with nothing but
 JAX); everything below it works on those lists, so the tests run the same
@@ -9,6 +10,7 @@ arithmetic on the small recorded trace under benchmarks/recorded/.
 
 from __future__ import annotations
 
+import bisect
 import glob
 import os
 import re
@@ -146,17 +148,42 @@ def top_ops(ops, t0: float, t1: float, k: int = 10):
     return [[n, s] for n, s in sorted(acc.items(), key=lambda kv: -kv[1])[:k]]
 
 
+def busy_in_runs(ops, runs) -> list[float]:
+    """`total(busy_union(ops, a, b))` for each (a, b) of `runs`, reading only
+    the slice of the ops (in start order) that can reach the run: from the
+    first op by which some op has ended after `a`, to the first that starts
+    at or after `b`. Every op outside that slice fails `clip`'s test, so each
+    run's merged intervals, and its seconds, are the same to the last bit."""
+    ops = sorted(ops, key=lambda e: e[1])
+    starts = [a for _, a, _ in ops]
+    reach, hi = [], float("-inf")  # running maximum of the ends, in start order
+    for _, _, b in ops:
+        hi = max(hi, b)
+        reach.append(hi)
+    return [total(busy_union(ops[bisect.bisect_right(reach, a):bisect.bisect_left(starts, b)],
+                             a, b))
+            for a, b in runs]
+
+
 def attribute_gaps(gaps, host_spans, k: int = 10):
     """Each idle gap goes to the shortest host span that covers at least half
     of it (so a child wins over its parent), else to the span that overlaps
-    it most, else to "no_host_span". Returns [[name, seconds], ...]."""
+    it most, else to "no_host_span". Returns [[name, seconds], ...].
+
+    `gaps` come in time order and do not overlap, as `idle_gaps` gives them:
+    a sweep then keeps the spans open at the gap (begun before its end, not
+    ended by its start) and visits only those, in their start order, so the
+    cost is linear in gaps and spans times the spans open at once."""
     acc: dict[str, float] = {}
     spans = sorted(host_spans, key=lambda s: s[1])
+    nxt, open_ = 0, []
     for a, b in gaps:
+        while nxt < len(spans) and spans[nxt][1] < b:
+            open_.append(spans[nxt])
+            nxt += 1
+        open_ = [s for s in open_ if s[2] > a]
         covering, most = None, None
-        for name, sa, sb in spans:
-            if sa >= b:
-                break
+        for name, sa, sb in open_:
             ov = min(b, sb) - max(a, sa)
             if ov <= 0:
                 continue

@@ -49,17 +49,21 @@ def serve_schedule(mix: dict, seconds: float, seed: int, vocab: int,
     """Open-loop requests: due time (s, 0 = the window's opening), prompt
     token ids, output length; sorted by due time. The ramp-in (due before 0)
     and the window are drawn apart, so that the requests DUE IN THE WINDOW are
-    the same multiset of gaps and lengths for every seed, in another order."""
+    the same multiset of gaps and lengths for every seed, in another order.
+    A mix that gives `order_seed` draws that order from it, the same in every
+    run, and the run's seed draws the token ids alone."""
     rate = float(mix["rate_per_s"] if rate is None else rate)
     ramp = float(mix.get("ramp_s", 0.0))
     rng = np.random.default_rng([int(seed), 0x5EED])
+    order = (np.random.default_rng([int(mix["order_seed"]), 0x0DE5]) if "order_seed" in mix
+             else rng)
     parts = []
     n_ramp = int(round(rate * ramp))
     if n_ramp:
-        gaps, prompts, outputs = _part(mix, n_ramp, rate, rng)
+        gaps, prompts, outputs = _part(mix, n_ramp, rate, order)
         due = -np.cumsum(gaps[::-1])[::-1]  # the ramp's last arrival is one gap before 0
         parts.append((due, prompts, outputs))
-    gaps, prompts, outputs = _part(mix, max(1, int(round(rate * seconds))), rate, rng)
+    gaps, prompts, outputs = _part(mix, max(1, int(round(rate * seconds))), rate, order)
     parts.append((np.cumsum(gaps) - gaps[0], prompts, outputs))
     out = []
     for due, prompts, outputs in parts:

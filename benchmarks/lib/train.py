@@ -226,8 +226,9 @@ def run(cell: dict, args, dev: dict, t_start: float, peaks: dict | None) -> dict
             state, _ = trainer.fit(state, num_steps=last + TRACED_STEPS)
         finally:
             jax.profiler.stop_trace()
-        raw = tracelib.read_xplane(tracelib.find_xplane(prof_dir))
-        ctx["trace"] = traced_steps(raw)
+        t_reduce = time.perf_counter()
+        ctx["trace"] = traced_steps(tracelib.read_xplane(tracelib.find_xplane(prof_dir)))
+        ctx["trace"]["reduce_s"] = time.perf_counter() - t_reduce
         device["busy_s"] = ctx["trace"]["busy_s"]
         device["window_s"] = ctx["trace"]["window_s"]
     device["memory_peak_bytes"] = memory_peak_bytes()

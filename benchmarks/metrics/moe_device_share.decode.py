@@ -10,5 +10,5 @@ def read(ctx, spec):
     if not got:
         return None
     secs, count = decode_steps.matched_seconds(got["ops"], spec["patterns"])
-    busy = sum(tracelib.total(tracelib.busy_union(got["ops"], a, b)) for a, b in got["runs"])
+    busy = sum(tracelib.busy_in_runs(got["ops"], got["runs"]))
     return 100.0 * secs / busy if count and busy else None

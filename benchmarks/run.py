@@ -112,8 +112,14 @@ def main(argv=None) -> int:
         return 0
     res = driver.run(cell, args, dev, T_START, peaks)
 
+    tr = res["ctx"].get("trace")
     if args.trace:
+        t_read = time.perf_counter()
         metrics = per_layer(cell, res["ctx"])
+        if tr:
+            read_s = time.perf_counter() - t_read
+            common.log(f"trace reduction: {tr['reduce_s'] + read_s:.2f} s (parse and reduce "
+                       f"{tr['reduce_s']:.2f} s, the per-layer readers {read_s:.2f} s)")
     else:
         # The driver of the cell's kind reads every end-to-end number it can;
         # the line carries those that BENCHMARK.json lists for this cell.
@@ -132,7 +138,6 @@ def main(argv=None) -> int:
         "metrics": metrics,
         "device": res["device"],
     }
-    tr = res["ctx"].get("trace")
     if args.trace and tr:
         line["breakdown"] = {"device_ops": tr["device_ops"], "idle_gaps": tr["idle_gaps"]}
     line["compared"] = res["compared"]

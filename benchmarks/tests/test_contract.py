@@ -147,6 +147,23 @@ def test_schedule_has_the_same_work_for_every_seed_in_another_order():
     assert all((x["prompt"] == y["prompt"]).all() for x, y in zip(a, again))
 
 
+@pytest.mark.parametrize("name", ["chat-steady", "conv-mixed-steady"])
+def test_a_mix_with_an_order_seed_has_one_order_for_every_seed(name):
+    """`order_seed` fixes which request comes when; the run's seed still
+    draws the token ids. Without it the order follows the run's seed."""
+    mix = dict(common.load_json("traffic", name + ".json"), order_seed=7)
+    a = serve_schedule(mix, 10.0, 1, 50257)
+    b = serve_schedule(mix, 10.0, 2**31 + 11, 50257)
+    shape = lambda s: [(r["due_s"], len(r["prompt"]), r["max_new"]) for r in s]
+    assert shape(a) == shape(b)
+    assert any((x["prompt"] != y["prompt"]).any() for x, y in zip(a, b))
+    c = serve_schedule(dict(mix, order_seed=8), 10.0, 1, 50257)
+    assert shape(c) != shape(a)
+    free = {k: v for k, v in mix.items() if k != "order_seed"}
+    assert shape(serve_schedule(free, 10.0, 1, 50257)) != shape(
+        serve_schedule(free, 10.0, 2**31 + 11, 50257))
+
+
 def test_open_loop_times_from_the_due_moment_and_reports_lateness():
     """A generator that runs late: TTFT is taken from when the request was
     due, and the lateness is reported."""

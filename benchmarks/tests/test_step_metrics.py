@@ -83,25 +83,57 @@ def raw_trace():
     return {"devices": {0: {"ops": ops, "modules": modules}}, "host": []}
 
 
-def test_decode_program_time_is_the_busy_union_inside_its_runs(monkeypatch, tmp_path):
+def test_decode_program_time_is_the_busy_union_inside_its_runs():
     spec = SPECS["decode_program_device_ms"]
     reader = run.reader_for(spec)
-    mod = reader.__globals__
-    monkeypatch.setitem(mod, "own_xplane", lambda tr: raw_trace())
-    tr = {"t0": 0.5, "t1": 9.9}
-    # Runs 1 and 2 lie inside the traced part: busy 2.0 s and 0.75 s.
+    # The trace the run's reduction parsed. Runs 1 and 2 lie inside the
+    # traced part: busy 2.0 s and 0.75 s.
+    tr = {"t0": 0.5, "t1": 9.9, "xplane": raw_trace()}
     assert reader({"trace": tr}, spec) == pytest.approx(1e3 * (2.0 + 0.75) / 2)
     # No run of that module (the parent calls every program jit_fn): nothing.
     raw = raw_trace()
     for m in raw["devices"][0]["modules"]:
         m[0] = "jit_fn(1)"
-    monkeypatch.setitem(mod, "own_xplane", lambda tr: raw)
-    assert reader({"trace": tr}, spec) is None
-    monkeypatch.undo()
-    # No trace of this run on disk, or no traced run at all: nothing.
-    monkeypatch.setitem(mod, "ROOT", str(tmp_path))
-    assert reader({"trace": tr}, spec) is None
+    assert reader({"trace": dict(tr, xplane=raw)}, spec) is None
+    # No parsed trace, or no traced run at all: nothing.
+    assert reader({"trace": {"t0": 0.5, "t1": 9.9}}, spec) is None
     assert reader({"trace": None}, spec) is None and reader({}, spec) is None
+
+
+def old_program_ms(raw, tr, module):
+    """The reader before the per-run slices, verbatim after the parse: the oracle."""
+    import re
+
+    from lib import trace as tracelib
+
+    dev = next(iter(raw["devices"].values()))
+    named = re.compile(module)
+    runs = [(a, b) for name, a, b in dev["modules"]
+            if named.search(name) and a >= tr["t0"] and b <= tr["t1"]]
+    if not runs:
+        return None
+    busy = sum(tracelib.total(tracelib.busy_union(dev["ops"], a, b)) for a, b in runs)
+    return 1e3 * busy / len(runs)
+
+
+@pytest.mark.parametrize("seed", [5, 6, 2**31 + 3])
+def test_program_time_reads_what_the_old_reader_read(seed):
+    from test_reduction import random_trace
+
+    ops, runs, _ = random_trace(seed)
+    modules = [["jit_serve_paged_decode(1)" if i % 3 else "jit_serve_paged_graft(2)", a, b]
+               for i, (a, b) in enumerate(runs)]
+    raw = {"devices": {0: {"ops": ops, "modules": modules}}, "host": []}
+    for name in ("decode_program_device_ms", "graft_program_device_ms"):
+        spec = common.load_json("metrics", name + ".json")
+        for t0, t1 in ((0.0, 1.0), (0.2, 0.7)):
+            tr = {"t0": t0, "t1": t1, "xplane": raw}
+            got = run.reader_for(spec)({"trace": tr}, spec)
+            assert got is not None and got == old_program_ms(raw, tr, spec["module"])
+    raw = raw_trace()
+    tr = {"t0": 0.5, "t1": 9.9, "xplane": raw}
+    assert read("decode_program_device_ms", {"trace": tr}) == old_program_ms(
+        raw, tr, SPECS["decode_program_device_ms"]["module"])
 
 
 def test_kernel_time_splits_forward_from_backward():
