@@ -55,8 +55,7 @@ of any length mix freely inside those shapes:
   token, eos, deadline) at the fetch. At most ONE step is ahead, and
   whatever reads or rewrites slot state outside ``step`` first lands it
   (``_drain_inflight``). A speculating engine proposes from host tokens
-  and does not look ahead; nor, YET, does one whose pools are kept by
-  layer kind (``_looks_ahead``).
+  and is the one engine that does not look ahead (``_looks_ahead``).
 
 Everything here is host logic around jitted pure functions; under a live
 mesh (captured at construction) the same loop serves model-sharded caches
@@ -561,14 +560,8 @@ class ServingEngine:
             )
         # One step of lookahead is what a plain decode step does. A
         # speculating engine proposes from the host's tokens, so it lands
-        # every step before the next. Over pools kept by layer kind the
-        # engine lands every step too, FOR NOW: the path is the same and
-        # was measured (PERF.md section 6, PR 34), but the benchmark's
-        # traced reduction grows with the square of the step rate and does
-        # not end inside its time limit at the rate lookahead gives the
-        # one cell that serves such a model (PERF.md section 7: the two
-        # files and the edits; then this condition goes).
-        self._looks_ahead = self.spec_mode == "off" and not self.mixed
+        # every step before the next.
+        self._looks_ahead = self.spec_mode == "off"
         self.spec_k = int(speculate_k)
         self.spec_ngram_max = int(speculate_ngram_max)
         self.spec_window = int(speculate_window)
@@ -2677,7 +2670,9 @@ class ServingEngine:
             # prefill-once cache), drop the unexercised reservation, and
             # point the table row at the trash block so this row's
             # writes in the shared decode program can never land in a
-            # freed — possibly reallocated — block.
+            # freed — possibly reallocated — block. (A step in flight
+            # still holds the old row and writes its blocks once more,
+            # but it is enqueued ahead of their next writer.)
             for bid in self._slot_blocks[slot]:
                 self._deref(bid)
             self._reserved_future -= int(self._slot_future[slot])
@@ -3026,6 +3021,10 @@ class ServingEngine:
             held = self._wslot_blocks[slot]
             first = self._window_first_block(p)
             for j in [j for j in held if j < first]:
+                # The step in flight may still read this block. It is free
+                # at once all the same: whatever writes it next (this
+                # step, a later one, an admission's graft) is enqueued
+                # behind the step in flight and takes the pool from it.
                 self._wfree.append(held.pop(j))
                 self._wtables[slot, j % self.window_places] = 0
                 self._tables_dirty = True

@@ -358,29 +358,59 @@ def test_spans_and_gauges_of_the_two_kinds(fp32):
     assert eng.stats["window_blocks_released"] >= 1
 
 
-def test_pools_by_layer_kind_land_every_step_for_now(fp32):
-    """Over pools kept by layer kind the engine does not look ahead yet
-    (``ServingEngine._looks_ahead``; PERF.md section 7 says what waits for
-    it): every step starts from the host's tokens, so no `decode` span is
-    `ahead`, nothing is ever in flight between two steps and no row is
-    computed in vain."""
-    model, params, _ = fp32
-    registry, tracer = MetricsRegistry(), Tracer(capacity=100_000)
-    eng = ServingEngine(model, params, num_slots=2, kv_block_size=BLOCK,
-                        temperature=0.0, telemetry=registry, tracer=tracer)
-    assert not eng._looks_ahead
-    eng.submit(np.arange(30) % VOCAB, 9)
-    eng.submit(np.arange(7) % VOCAB, 6)
-    while eng.pending:
-        eng.step()
-        assert eng._inflight is None
-    decodes = [s for s in tracer.spans() if s["name"] == "decode"]
-    assert len(decodes) == eng.stats["decode_steps"] == 8
-    assert [s["ahead"] for s in decodes] == [0] * 8
-    assert sum(s["active"] for s in decodes) == 8 + 5
-    snap = registry.snapshot()
-    assert snap.get("serve_decode_ahead_total", 0) == 0
-    assert snap.get("serve_decode_wasted_rows_total", 0) == 0
+def test_pools_by_layer_kind_look_ahead(fp32):
+    """Over pools kept by layer kind the engine looks ahead like every engine
+    that does not speculate: every `decode` span but the first is `ahead`,
+    and the greedy tokens are the reference's. Two rows slide past the
+    window, so a sliding block goes back while the step before, which may
+    still read it, is in flight, and the next step takes it at once: the
+    blocks given back, and both free lists after ``close``, are those of an
+    engine that lands every step."""
+    model, params, sizes = fp32
+    rng = np.random.default_rng(38)
+    requests = [(rng.integers(0, VOCAB, size=n), k) for n, k in [(30, 20), (7, 25)]]
+    runs = {}
+    for ahead in (True, False):
+        registry, tracer = MetricsRegistry(), Tracer(capacity=100_000)
+        eng = ServingEngine(model, params, num_slots=2, kv_block_size=BLOCK,
+                            temperature=0.0, telemetry=registry, tracer=tracer)
+        assert eng._looks_ahead
+        eng._looks_ahead = ahead  # the engine that lands every step
+        ids = [eng.submit(p, k) for p, k in requests]
+        done, released_in_flight = {}, 0
+        while eng.pending:
+            in_flight = eng._inflight is not None
+            before = eng.stats["window_blocks_released"]
+            done.update((c.id, c) for c in eng.step())
+            released_in_flight += in_flight and (
+                eng.stats["window_blocks_released"] > before)
+        eng.close()
+        done.update((c.id, c) for c in eng.run())
+        decodes = [s for s in tracer.spans() if s["name"] == "decode"]
+        runs[ahead] = dict(
+            tokens=[done[i].tokens for i in ids],
+            ahead=[s["ahead"] for s in decodes],
+            released_in_flight=released_in_flight,
+            released=registry.snapshot()["serve_window_blocks_released_total"],
+            free=sorted(eng._free), wfree=sorted(eng._wfree),
+        )
+    ahead, landed = runs[True], runs[False]
+    assert ahead["ahead"] == [0] + [1] * (len(ahead["ahead"]) - 1)
+    # The longer request's first token comes from its prefill.
+    assert len(ahead["ahead"]) == len(landed["ahead"]) == 24
+    assert landed["ahead"] == [0] * 24 and landed["released_in_flight"] == 0
+    assert ahead["released_in_flight"] > 0
+    for (prompt, k), got, other in zip(requests, ahead["tokens"], landed["tokens"]):
+        np.testing.assert_array_equal(got, other)
+        assert got.size == prompt.size + k
+        # Greedy: each token is the argmax of the reference's logits at the
+        # position before it.
+        want = reference_logits(params, got[None, :-1], sizes)[0]
+        np.testing.assert_array_equal(
+            got[prompt.size:], want[prompt.size - 1:].argmax(-1))
+    assert ahead["released"] == landed["released"] > 0
+    assert ahead["free"] == landed["free"]
+    assert ahead["wfree"] == landed["wfree"]
 
 
 # ------------------------------------------------------------ expert layer

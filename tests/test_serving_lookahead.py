@@ -9,6 +9,9 @@ before it fetches step n.
   before the fetch of step n;
 - the drain rule: whatever reads or rewrites slot state outside ``step``
   lands the step in flight first, and every id still resolves exactly once.
+
+The first two hold over the paged pool, the bucketed cache and pools kept by
+layer kind, where a sliding layer's blocks go back while a step is in flight.
 """
 
 from __future__ import annotations
@@ -38,16 +41,39 @@ TINY = dict(
     vocab_size=64, num_layers=2, num_heads=4, hidden_dim=64, seq_len=64,
     dropout=0.0,
 )
+#: A full layer and one that attends a window of 8 positions, shorter than
+#: most requests here: a window's blocks are given back as a row decodes.
+KINDS = dict(TINY, layer_types=("full_attention", "sliding_attention"),
+             sliding_window=8)
 CACHES = pytest.mark.parametrize(
-    "cache_kw", [{"kv_block_size": 8}, {}], ids=["paged", "bucketed"]
+    "served", ["paged", "bucketed", "kinds"], indirect=True
 )
+
+
+def _built(cfg):
+    model = GPT(GPTConfig(**cfg), FP32)
+    tokens = jax.random.randint(jax.random.key(1), (2, 8), 0, 64)
+    return model, jit_init(model, tokens, train=False)["params"]
 
 
 @pytest.fixture(scope="module")
 def gpt():
-    model = GPT(GPTConfig(**TINY), FP32)
-    tokens = jax.random.randint(jax.random.key(1), (2, 8), 0, 64)
-    return model, jit_init(model, tokens, train=False)["params"]
+    return _built(TINY)
+
+
+@pytest.fixture(scope="module")
+def kinds():
+    return _built(KINDS)
+
+
+@pytest.fixture
+def served(request, gpt):
+    """A case of ``CACHES``: the model, its parameters and the engine's
+    cache arguments."""
+    if request.param == "kinds":
+        return (*request.getfixturevalue("kinds"), {"kv_block_size": 4})
+    return (*gpt, {"paged": {"kv_block_size": 8}, "bucketed": {}}[
+        request.param])
 
 
 def _greedy(model, params, prompt, n_new, eos=None):
@@ -95,12 +121,12 @@ def _run_staggered(eng, work):
 
 
 @CACHES
-def test_row_ended_by_eos_loses_only_its_extra_token(gpt, cache_kw):
+def test_row_ended_by_eos_loses_only_its_extra_token(served):
     """A row whose token is eos has run one step too many by the time the
     host knows: that row of the step in flight is dropped (counted), its
     slot and blocks are taken again by the next admission, and every
     request still equals ``generate()`` cut at its eos."""
-    model, params = gpt
+    model, params, cache_kw = served
     work = _staggered(34)
     # An eos that ends the first request early: its third greedy token.
     p0, n0 = work[0]
@@ -129,16 +155,21 @@ def test_row_ended_by_eos_loses_only_its_extra_token(gpt, cache_kw):
     if cache_kw:
         assert len(eng._free) == eng.pool_blocks - 1 or eng._prefix_cache
         assert eng._reserved_future == 0
+    if eng.mixed:
+        # Every sliding block is back, those of the rows eos ended too.
+        assert eng.stats["window_blocks_released"] > 0
+        assert sorted(eng._wfree) == list(range(1, eng.window_pool_blocks))
+        assert not eng._wtables.any()
     eng.close()
 
 
 @CACHES
-def test_every_step_but_the_first_is_enqueued_ahead(gpt, cache_kw, monkeypatch):
+def test_every_step_but_the_first_is_enqueued_ahead(served, monkeypatch):
     """With ``eos_id`` None a row's death is a count, so nothing is
     wasted; every `decode` span but the first after an engine that stood
     empty says ``ahead == 1``; and the decode program of step n+1 is
     called before ``jax.device_get`` fetches step n."""
-    model, params = gpt
+    model, params, cache_kw = served
     work = _staggered(43)
     tracer = Tracer(capacity=100_000)
     eng = ServingEngine(
@@ -154,8 +185,12 @@ def test_every_step_but_the_first_is_enqueued_ahead(gpt, cache_kw, monkeypatch):
             order.append(("enqueue", out[1]))  # (kept: ids stay apart)
         return out
 
+    # Over pools by layer kind the expert layers' two counts come back
+    # behind the tokens.
+    fetched_shape = (eng.num_slots + 2 * eng.mixed,)
+
     def fetching(x):
-        if isinstance(x, jax.Array) and x.shape == (eng.num_slots,):
+        if isinstance(x, jax.Array) and x.shape == fetched_shape:
             order.append(("fetch", x))
         return get(x)
 
@@ -195,6 +230,7 @@ def test_every_step_but_the_first_is_enqueued_ahead(gpt, cache_kw, monkeypatch):
         assert i < at
         if k + 1 < len(enq):
             assert enq[k + 1] < at, f"step {k + 1} was not enqueued ahead"
+    assert not eng.mixed or eng.stats["window_blocks_released"] > 0
     eng.close()
 
 
